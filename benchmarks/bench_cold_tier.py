@@ -114,10 +114,6 @@ def main():
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    env_platforms = os.environ.get("JAX_PLATFORMS")
-    if env_platforms and jax.config.jax_platforms != env_platforms:
-        jax.config.update("jax_platforms", env_platforms)
-
     from glt_tpu.parallel import multihost
     from glt_tpu.parallel.dist_feature import (
         HostColdStore,

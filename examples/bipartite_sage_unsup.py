@@ -69,6 +69,9 @@ class TwoTowerSAGE(nn.Module):
 
 
 def main():
+    from glt_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=5)
     ap.add_argument("--batch-size", type=int, default=128)

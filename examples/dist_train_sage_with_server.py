@@ -89,6 +89,9 @@ def trainer_proc(rank, world, addr, scale, epochs, batch_size,
 
 
 def main():
+    from glt_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--servers", type=int, default=2)
     ap.add_argument("--scale", type=float, default=0.02)

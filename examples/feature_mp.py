@@ -43,6 +43,9 @@ def worker(rank, handle, q):
 
 
 def main():
+    from glt_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     from glt_tpu.data import share_dataset
 
     ds = build()

@@ -21,6 +21,9 @@ import numpy as np
 
 
 def main():
+    from glt_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", required=True)
     ap.add_argument("--num-parts", type=int, default=4)

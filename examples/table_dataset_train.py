@@ -51,6 +51,9 @@ def synthetic_tables(n=2000, deg=8, classes=6, seed=0):
 
 
 def main():
+    from glt_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     import jax
     import optax
 

@@ -23,6 +23,9 @@ from glt_tpu.typing import reverse_edge_type
 
 
 def main():
+    from glt_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=1.0)
     ap.add_argument("--epochs", type=int, default=5)

@@ -62,9 +62,9 @@ AMBIGUOUS_METHOD_NAMES = frozenset({
     # jax.random.split / str.split / np.split: binding a project class's
     # .split to these call sites invented host-sync effects (PR 9).
     "split", "submit",
-    # pl.load / pl.store inside Pallas kernels: binding a project
-    # class's .load (DistDataset.load) to the kernel's masked-memory-op
-    # call sites invented a host-sync chain out of coincidence (PR 10).
+    # pltpu.load / pltpu.store inside Pallas kernels: binding a project
+    # class's .load (DistDataset.load) to a kernel's masked-memory-op
+    # call site invents a host-sync chain out of coincidence (PR 10).
     "load", "store",
 })
 

@@ -97,9 +97,9 @@ class NodeLoader:
         with the measured winner.  The probe is built at THIS sampler's
         ``node_capacity``, so an occupancy-capped loader sweeps its own
         (smaller) shape instead of inheriting a full-cap winner whose
-        tile/padding choice may lose there (the BENCH_r05
-        ``gather_ms_capped`` inversion).  No-op off TPU and for
-        tiered/absent features (their gathers are host-side stages)."""
+        tile/padding choice may lose there.  No-op off TPU, for widths
+        outside the kernel's gate (config 1's 100) and for tiered/absent
+        features (their gathers are host-side stages)."""
         feat = self.data.get_node_feature() if self.data is not None else None
         cap = getattr(self.sampler, "node_capacity", None)
         if (feat is None or cap is None
@@ -122,7 +122,9 @@ class NodeLoader:
         (a capped hop width is its own key, never the full-cap
         winner's).  No-op off TPU — ``autotune_sample`` pins 'xla'
         there, so CPU runs resolve the seam honestly — and for samplers
-        without the hop-width protocol."""
+        without the hop-width protocol.  On a TPU the sweep is ruled
+        out while ``sample_pallas.TPU_REFUSAL`` stands; the table
+        records the reason per hop."""
         sampler = self.sampler
         graph = getattr(sampler, "graph", None)
         widths = getattr(sampler, "_widths", None)
@@ -240,12 +242,8 @@ class NodeLoader:
         if not self._overflow_checked() or not out.metadata:
             return
         flag = out.metadata.get("overflow")
-        copy_async = getattr(flag, "copy_to_host_async", None)
-        if copy_async is not None:
-            try:
-                copy_async()
-            except Exception:  # pragma: no cover - backend w/o async copy
-                pass
+        if flag is not None:
+            flag.copy_to_host_async()
 
     def _maybe_refetch_overflow(self, out):
         """Strict overflow fallback: re-sample a flagged batch through the

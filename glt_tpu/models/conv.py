@@ -14,10 +14,9 @@ are batched over the padded node dimension so shapes are static.
 Mixed precision: every layer takes ``dtype`` (e.g. ``jnp.bfloat16``) — the
 COMPUTE dtype of its Dense matmuls only.  Params stay float32, the MXU
 accumulates in float32 natively, outputs are cast back to float32, and the
-gather/segment aggregation path is untouched (it is lane-tile-bound, not
-precision-bound — see BASELINE.md).  The reference's torch examples train
-in f32 (examples/train_sage_ogbn_products.py); bf16 matmuls are a
-TPU-native win the MXU makes free.
+gather/segment aggregation path is untouched.  The reference's torch
+examples train in f32 (examples/train_sage_ogbn_products.py); bf16 is
+the MXU's native input type.
 """
 from __future__ import annotations
 

@@ -87,14 +87,12 @@ def param_nbytes(params) -> int:
 def compiled_cost_bytes(fn, *args) -> Optional[float]:
     """XLA's own ``bytes accessed`` for ``fn(*args)`` where available.
 
-    ``fn`` must be a jitted callable.  Returns None when the backend /
-    jax version exposes no cost analysis — callers fall back to the
-    analytic model.  Never raises: attribution is advisory.
+    ``fn`` must be a jitted callable.  Returns None when the backend
+    exposes no cost analysis — callers fall back to the analytic model.
+    Never raises: attribution is advisory.
     """
     try:
         cost = fn.lower(*args).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):      # older jax: per-device
-            cost = cost[0] if cost else None
         if not isinstance(cost, dict):
             return None
         v = cost.get("bytes accessed")

@@ -1,18 +1,17 @@
-"""Continuous perf-regression tracking over the committed bench history.
+"""Perf-regression tracking over a history of bench snapshots.
 
-The repo carries one ``BENCH_r*.json`` snapshot per growth round — the
-regression signal nothing read until now (``overlap_speedup`` sat at
-0.97–0.99 for three rounds without anyone being told).  This module
-turns that history plus an optional fresh ``bench.py`` run into a
-markdown trend table and a direction-aware regress/improve verdict;
-``scripts/bench_compare.py`` is the CLI and CI (advisory job
-``bench-compare``) runs it on every push.
+This module turns a ``BENCH_r*.json`` history plus an optional fresh
+``bench.py`` run into a markdown trend table and a direction-aware
+regress/improve verdict; ``scripts/bench_compare.py`` is the CLI and CI
+(advisory job ``bench-compare``) runs it on every push.  No snapshot is
+committed today (the driver's record is ``PERF_LEDGER.jsonl``), so the
+default history is empty.
 
 Three ideas, all deliberately simple and stdlib-only:
 
 * **Direction awareness.**  ``*_ms`` down is good, ``*_gb_s`` /
   ``*_frac`` up is good; metrics with no inherent direction (capacity
-  choices, occupancy counts, tunnel weather) are tracked but never
+  choices, occupancy counts, host round-trip latency) are tracked but never
   verdicted.  :func:`direction` resolves explicit names first, then
   suffix/infix conventions.
 
@@ -127,7 +126,7 @@ EXPLICIT_DIRECTIONS: Dict[str, int] = {
     "hbm_fraction_measured": UP,
     "compile_count_epoch": DOWN,
     # Environment / configuration readings — not better or worse.
-    "tunnel_rtt_ms": NEUTRAL,
+    "host_roundtrip_ms": NEUTRAL,
     "dedup_ratio": NEUTRAL,
     "cap_fraction": NEUTRAL,
     "occupancy_p50": NEUTRAL,

@@ -135,8 +135,7 @@ def _make_fused_kernel(up: int, nbuf: int, chunk: int):
         # harmlessly (the XLA epilogue zeroes them).
         def copy_row(s, carry):
             iv = inv_ref[c * chunk + s]
-            row = pl.load(buf, (pl.ds(iv, 1), slice(None)))
-            pl.store(out_ref, (pl.ds(s, 1), slice(None)), row)
+            out_ref[pl.ds(s, 1), :] = buf[pl.ds(iv, 1), :]
             return carry
 
         lax.fori_loop(0, chunk, copy_row, None)
@@ -164,7 +163,7 @@ def _fused_gather(table, uidx, count, inv, interpret=False,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(bp // _CHUNK,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((_CHUNK, d), lambda c, *_: (c, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
@@ -229,11 +228,10 @@ def _make_fused_dequant_kernel(up: int, nbuf: int, chunk: int, mode: str):
 
         def copy_row(s, carry):
             iv = inv_ref[c * chunk + s]
-            row = pl.load(buf, (pl.ds(iv, 1), slice(None)))
-            row = row.astype(jnp.float32)
+            row = buf[pl.ds(iv, 1), :].astype(jnp.float32)
             if mode == "affine":
                 row = jnp.where(scale > 0.0, (row + kvec) * scale, zero)
-            pl.store(out_ref, (pl.ds(s, 1), slice(None)), row)
+            out_ref[pl.ds(s, 1), :] = row
             return carry
 
         lax.fori_loop(0, chunk, copy_row, None)
@@ -264,7 +262,7 @@ def _fused_gather_dq(table, sz, uidx, count, inv, interpret=False,
         num_scalar_prefetch=3,
         grid=(bp // _CHUNK,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((_SZ_ROWS, d), lambda c, *_: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
@@ -321,9 +319,16 @@ def fused_frontier(table: jnp.ndarray, ids: jnp.ndarray,
     uidx = jnp.where(uvalid, uniq, 0)
     if id2index is not None:
         uidx = jnp.take(id2index, uidx, axis=0, mode="clip")
-    use = (force in ("pallas", "interpret")
-           or (force == "auto" and jax.default_backend() == "tpu"))
     compressed = dequant is not None and dequant.is_compressed
+    # 'auto' engages the f32 kernel on a TPU.  The dequant kernel is out
+    # of it: Mosaic refuses the one-row load from the packed bf16/int8
+    # buffer at a dynamic sublane offset ("cannot statically prove that
+    # index in dimension 0 is a multiple of 8", TPU v5e, PR 21), so a
+    # compressed table takes the unfused arm unless 'pallas' is forced,
+    # which raises that message.
+    use = (force in ("pallas", "interpret")
+           or (force == "auto" and jax.default_backend() == "tpu"
+               and not compressed))
     if use and fused_frontier_supported(table, ids, vmem_budget):
         if compressed:
             mode = "affine" if dequant.codec == "int8" else "widen"

@@ -24,6 +24,11 @@ from __future__ import annotations
 # parameter point against.
 VMEM_BYTES = 16 * 2**20
 
+# Scalar memory: where PrefetchScalarGridSpec operands land.  The v5e
+# compiler reports the capacity itself when a kernel overruns it ("Used
+# 2.13M of 1.00M smem", gather_rows_pallas at 139,264 ids).
+SMEM_BYTES = 1 * 2**20
+
 # Last-dimension register width: every VMEM tile is LANE lanes wide,
 # and narrower last dims are padded up to it.
 LANE = 128
@@ -48,3 +53,13 @@ def sublane_min(itemsize: int) -> int:
     """Smallest legal sublane tile dim for an ``itemsize``-byte dtype
     (f32 8, bf16 16, int8/fp8 32 — pallas_guide.md)."""
     return max(SUBLANE_F32, 32 // max(int(itemsize), 1))
+
+
+def compiler_message(exc: BaseException, limit: int = 400) -> str:
+    """One line naming what a compiler refused: ``Type: message`` with
+    whitespace collapsed and the tail (Mosaic appends the kernel's
+    serialized body) cut at ``limit``.  The form the autotune tables and
+    ``chip_smoke.py`` record a refused kernel in."""
+    text = " ".join(f"{type(exc).__name__}: {exc}".split())
+    text = text.split(" ... additional diagnostics were skipped")[0]
+    return text if len(text) <= limit else text[:limit] + "..."

@@ -315,9 +315,8 @@ def make_scanned_dist_train_step(
     fwd/bwd, gradient ``pmean``, optimizer update — under ``lax.scan``
     INSIDE one ``shard_map`` program, so intermediate ids and the
     updated replicated state never round-trip through host dispatch
-    between batches.  BENCH_r05 measured the serial dist step at
-    62.6 ms vs 51.9 ms single-device — most of the gap is per-batch
-    dispatch + state re-feed that the scan amortises across ``G``.
+    between batches: the per-batch dispatch + state re-feed of the
+    serial dist step is paid once per ``G``.
 
     Returns ``step(state, seeds_blk [G, S, B], key) -> (state,
     losses [G], accs [G])``.  Per-slot keys follow the homo scan

@@ -135,9 +135,19 @@ def shard_feature(feature: np.ndarray, num_shards: int,
 
 
 def put_sharded(sharded, mesh: jax.sharding.Mesh, axis: str):
-    """Place the leading (shard) axis of every array field on ``axis``."""
+    """Place the leading (shard) axis of every array field on ``axis``.
+
+    ``sharded`` is a :class:`ShardedGraph` / :class:`ShardedFeature`, or
+    one bare ``[S, ...]`` array (the label block).  :func:`shard_graph`
+    and :func:`shard_feature` build their arrays on the default device;
+    a step fed those re-shards the whole graph and feature table from
+    that one device on every call, so place them once, here, before
+    building the step.
+    """
     spec = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec(axis))
+    if isinstance(sharded, (jnp.ndarray, np.ndarray)):
+        return jax.device_put(sharded, spec)
 
     def place(x):
         if isinstance(x, jnp.ndarray) and x.ndim >= 1:

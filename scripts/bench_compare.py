@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
-"""Compare the committed bench history (plus an optional fresh run)
-and emit a markdown trend report with a regress/improve verdict.
+"""Compare a bench history (plus an optional fresh run) and emit a
+markdown trend report with a regress/improve verdict.  No record is
+committed today, so the default glob is empty: that prints "no history"
+and exits 0.
 
     python scripts/bench_compare.py                      # history only
     GLT_BENCH_OUT=fresh.json python bench.py
@@ -60,6 +62,10 @@ def main(argv=None) -> int:
         label = os.path.splitext(os.path.basename(path))[0]
         label = label.replace("BENCH_", "")
         runs.append((label, metrics))
+    if not runs:
+        print(f"no history (glob {args.history!r} matched no bench "
+              f"snapshot): nothing to compare")
+        return 0
     if args.fresh:
         metrics = load_bench_metrics(args.fresh)
         if metrics is None:

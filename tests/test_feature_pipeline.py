@@ -144,8 +144,7 @@ class TestAutotuneTable:
     def test_keyed_by_exact_shape(self):
         """The decision table keys include the exact batch size: an
         occupancy-capped gather shape gets its OWN entry instead of
-        inheriting the full-cap winner (the BENCH_r05 gather_ms_capped
-        inversion this round fixes).  Off-TPU both pin 'xla' with an
+        inheriting the full-cap winner.  Off-TPU both pin 'xla' with an
         empty sweep."""
         from glt_tpu.ops import gather_pallas as gp
 

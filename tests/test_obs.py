@@ -536,27 +536,27 @@ class TestRoofline:
         r = peak_hbm_gb_s()
         assert r == {"gb_s": 1228.0, "source": "env"}
 
-    def test_peak_hbm_bad_env_falls_through(self, monkeypatch):
+    def test_peak_hbm_bad_env_raises(self, monkeypatch):
         from glt_tpu.obs.roofline import peak_hbm_gb_s
 
         monkeypatch.setenv("GLT_HBM_GBPS", "not-a-number")
-        r = peak_hbm_gb_s()
-        assert r["source"] != "env"
-        assert r["gb_s"] > 0
+        with pytest.raises(ValueError):
+            peak_hbm_gb_s()
 
-    def test_peak_hbm_resolves_without_env(self, monkeypatch):
-        # On CPU the device_kind table has no row -> conservative v5e
-        # default; on a real TPU the kind resolves.  Either way: a
-        # positive number with a named source, never an exception.
-        from glt_tpu.obs.roofline import DEFAULT_HBM_GB_S, peak_hbm_gb_s
+    def test_peak_hbm_unknown_kind_raises(self, monkeypatch):
+        # The CPU's device_kind has no row: an unknown device is an
+        # error that names the kind, never a v5e default.
+        import jax
+
+        from glt_tpu.obs.roofline import peak_bf16_tflops, peak_hbm_gb_s
 
         monkeypatch.delenv("GLT_HBM_GBPS", raising=False)
-        r = peak_hbm_gb_s()
-        assert r["gb_s"] > 0
-        assert r["source"].startswith("device_kind:") \
-            or r["source"] == "default_v5e"
-        if r["source"] == "default_v5e":
-            assert r["gb_s"] == DEFAULT_HBM_GB_S
+        kind = jax.devices()[0].device_kind
+        with pytest.raises(LookupError, match=kind):
+            peak_hbm_gb_s()
+        with pytest.raises(LookupError, match=kind):
+            peak_bf16_tflops(kind)
+        assert peak_bf16_tflops("TPU v5 lite") == 197.0
 
     def test_peak_hbm_device_kind_table(self):
         from glt_tpu.obs.roofline import DEVICE_HBM_GB_S

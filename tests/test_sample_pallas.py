@@ -160,8 +160,7 @@ def test_bin_width_alignment():
 def test_autotune_exact_shape_keys_and_cpu_pins_xla():
     # Off-TPU, the sweep must pin 'xla' (honest resolution) while still
     # keying by the EXACT (batch, fanout, dtype) — two batch sizes are
-    # two table entries, never one shared winner (the BENCH_r05
-    # capped-shape inversion, structurally excluded from day one).
+    # two table entries, never one shared winner.
     reset_autotune()
     try:
         indptr, indices, _ = _power_law_csr(n=100, hub_deg=120)
