@@ -983,6 +983,10 @@ class SpanInTracedCode(Rule):
 
     ``.at[i].set(v)`` and other non-obs receivers never match: the
     receiver must trace back to an obs import or an obs-built name.
+
+    ``glt_tpu.obs.scopes`` is the one part of obs made for traced code
+    (device scopes: ``jax.named_scope`` under the ``glt.`` taxonomy, kept
+    by the compiled program as metadata) and never matches.
     """
     name = "span-in-traced-code"
     code = "GLT010"
@@ -991,6 +995,7 @@ class SpanInTracedCode(Rule):
                    "function (host side effects vanish under trace)")
 
     _OBS_PREFIX = "glt_tpu.obs"
+    _DEVICE_SCOPES = "glt_tpu.obs.scopes"
     _METHODS = {"inc", "observe", "set", "time", "fence"}
 
     def check(self, module: ModuleInfo, project=None) -> List[Finding]:
@@ -1010,7 +1015,9 @@ class SpanInTracedCode(Rule):
     def _is_obs_path(self, dotted: Optional[str]) -> bool:
         return bool(dotted) and (
             dotted == self._OBS_PREFIX
-            or dotted.startswith(self._OBS_PREFIX + "."))
+            or dotted.startswith(self._OBS_PREFIX + ".")) and not (
+            dotted == self._DEVICE_SCOPES
+            or dotted.startswith(self._DEVICE_SCOPES + "."))
 
     def _instrument_names(self, module: ModuleInfo) -> Set[str]:
         """Names (plain or ``self.x`` dotted) assigned from an obs

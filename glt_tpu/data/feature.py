@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.scopes import scoped
 from .feature_cache import cache_init, cache_insert, cache_lookup
 
 _I32_MAX = np.iinfo(np.int32).max
@@ -234,6 +235,7 @@ class Feature:
         if self._stager is not None:
             self._stager.close()
 
+    @scoped("glt.gather.feat")
     def _gather_hot_impl(self, hot, id2index, ids):
         from ..ops.dedup_gather import dedup_gather_rows
         from ..ops.gather_pallas import gather_rows

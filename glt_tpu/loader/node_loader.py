@@ -260,12 +260,18 @@ class NodeLoader:
             return out
         import jax
 
-        if not bool(np.asarray(jax.device_get(out.metadata["overflow"]))):
+        # The one place next() can block on the device: the flag is an
+        # output of the sample program.
+        with _span("loader.overflow_wait"):
+            flagged = bool(np.asarray(
+                jax.device_get(out.metadata["overflow"])))
+        if not flagged:
             return out
         self.overflow_batches += 1
         _M_OVERFLOW.inc()
-        return self.sampler.full_capacity_sibling().sample_from_nodes(
-            NodeSamplerInput(out.batch))
+        with _span("loader.overflow_replay"):
+            return self.sampler.full_capacity_sibling().sample_from_nodes(
+                NodeSamplerInput(out.batch))
 
     # -- collate (cf. node_loader.py:85 ``_collate_fn``) -------------------
     def _collate_fn(self, out, num_seeds: int) -> Batch:

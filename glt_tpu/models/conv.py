@@ -26,12 +26,15 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..obs.scopes import scoped
+
 
 def _mm_dtype(dtype):
     """Resolve a layer's matmul compute dtype (None = full f32)."""
     return None if dtype is None else jnp.dtype(dtype)
 
 
+@scoped("glt.model.agg")
 def scatter_sum(msgs: jnp.ndarray, dst: jnp.ndarray, num_nodes: int,
                 mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Sum messages into destination slots; -1/masked edges go to a spill row."""
@@ -42,6 +45,7 @@ def scatter_sum(msgs: jnp.ndarray, dst: jnp.ndarray, num_nodes: int,
     return jax.ops.segment_sum(msgs, seg, num_segments=num_nodes + 1)[:num_nodes]
 
 
+@scoped("glt.model.agg")
 def scatter_mean(msgs: jnp.ndarray, dst: jnp.ndarray, num_nodes: int,
                  mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     if mask is None:
@@ -53,6 +57,7 @@ def scatter_mean(msgs: jnp.ndarray, dst: jnp.ndarray, num_nodes: int,
     return s / jnp.maximum(cnt, 1)[:, None]
 
 
+@scoped("glt.model.agg")
 def segment_softmax(scores: jnp.ndarray, seg: jnp.ndarray, num_segments: int,
                     mask: jnp.ndarray) -> jnp.ndarray:
     """Numerically-stable softmax over edges grouped by destination.
@@ -87,14 +92,16 @@ class SAGEConv(nn.Module):
     def __call__(self, x, edge_index, edge_mask):
         num_nodes = x.shape[0]
         src, dst = edge_index[0], edge_index[1]
-        msgs = jnp.take(x, jnp.clip(src, 0, num_nodes - 1), axis=0)
+        with jax.named_scope("glt.model.msg"):
+            msgs = jnp.take(x, jnp.clip(src, 0, num_nodes - 1), axis=0)
         agg = scatter_mean(msgs, dst, num_nodes, edge_mask)
         dt = _mm_dtype(self.dtype)
-        out = (nn.Dense(self.out_features, use_bias=self.use_bias,
-                        dtype=dt, name="lin_self")(x)
-               + nn.Dense(self.out_features, use_bias=False,
-                          dtype=dt, name="lin_nbr")(agg))
-        return out if dt is None else out.astype(jnp.float32)
+        with jax.named_scope("glt.model.dense"):
+            out = (nn.Dense(self.out_features, use_bias=self.use_bias,
+                            dtype=dt, name="lin_self")(x)
+                   + nn.Dense(self.out_features, use_bias=False,
+                              dtype=dt, name="lin_nbr")(agg))
+            return out if dt is None else out.astype(jnp.float32)
 
 
 class GATConv(nn.Module):
@@ -113,23 +120,27 @@ class GATConv(nn.Module):
         src_c = jnp.clip(src, 0, num_nodes - 1)
         dst_c = jnp.clip(dst, 0, num_nodes - 1)
 
-        z = nn.Dense(h * f, use_bias=False, dtype=_mm_dtype(self.dtype),
-                     name="lin")(x).astype(jnp.float32).reshape(
-            num_nodes, h, f)
-        att_src = self.param("att_src", nn.initializers.glorot_uniform(),
-                             (h, f))
-        att_dst = self.param("att_dst", nn.initializers.glorot_uniform(),
-                             (h, f))
-        alpha_src = (z * att_src).sum(-1)   # [N, h]
-        alpha_dst = (z * att_dst).sum(-1)
+        with jax.named_scope("glt.model.dense"):
+            z = nn.Dense(h * f, use_bias=False,
+                         dtype=_mm_dtype(self.dtype),
+                         name="lin")(x).astype(jnp.float32).reshape(
+                num_nodes, h, f)
+            att_src = self.param("att_src",
+                                 nn.initializers.glorot_uniform(), (h, f))
+            att_dst = self.param("att_dst",
+                                 nn.initializers.glorot_uniform(), (h, f))
+            alpha_src = (z * att_src).sum(-1)   # [N, h]
+            alpha_dst = (z * att_dst).sum(-1)
 
-        e = alpha_src[src_c] + alpha_dst[dst_c]          # [E, h]
-        e = nn.leaky_relu(e, self.negative_slope)
+        with jax.named_scope("glt.model.msg"):
+            e = alpha_src[src_c] + alpha_dst[dst_c]          # [E, h]
+            e = nn.leaky_relu(e, self.negative_slope)
         # Per-head softmax over incoming edges of each destination.
         alpha = jax.vmap(
             lambda s: segment_softmax(s, dst, num_nodes, edge_mask),
             in_axes=1, out_axes=1)(e)                    # [E, h]
-        msgs = z[src_c] * alpha[:, :, None]              # [E, h, f]
+        with jax.named_scope("glt.model.msg"):
+            msgs = z[src_c] * alpha[:, :, None]          # [E, h, f]
         out = scatter_sum(msgs.reshape(-1, h * f), dst, num_nodes,
                           edge_mask).reshape(num_nodes, h, f)
         if self.concat:

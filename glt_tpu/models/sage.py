@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Any, Sequence
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -33,6 +34,8 @@ class GraphSAGE(nn.Module):
             x = SAGEConv(dim, dtype=self.dtype,
                          name=f"conv{i}")(x, edge_index, edge_mask)
             if not last:
-                x = nn.relu(x)
-                x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
+                with jax.named_scope("glt.model.dense"):
+                    x = nn.relu(x)
+                    x = nn.Dropout(self.dropout_rate,
+                                   deterministic=not train)(x)
         return x

@@ -31,6 +31,7 @@ from ..obs import device as _device
 from ..obs import flight as _flight
 from ..obs import metrics as _metrics
 from ..obs import profiler as _profiler
+from ..obs.scopes import scoped
 from ..obs.trace import span as _span
 from ..typing import PADDING_ID
 
@@ -62,6 +63,7 @@ def create_train_state(model, rng, sample_batch, tx) -> TrainState:
                       step=jnp.zeros((), jnp.int32))
 
 
+@scoped("glt.step.loss")
 def seed_cross_entropy(logits, y, batch_size: int, node_mask):
     """Mean CE over valid seed rows (first ``batch_size`` slots)."""
     sl = logits[:batch_size]
@@ -92,8 +94,10 @@ def make_train_step(model, tx, batch_size: int,
 
         (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("glt.step.update"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss, acc
 
     return train_step
@@ -127,20 +131,22 @@ def make_gather_xy(id2index=None, dedup: bool = False,
         ids = out.node
         valid = ids >= 0
         gid = jnp.where(valid, ids, 0)
-        if fused != "off":
-            x = fused_frontier(rows_arg, ids, id2index=id2index,
-                               force=fused).features
-        elif dedup:
-            x = dedup_gather_rows(rows_arg, ids, id2index=id2index,
-                                  force=force)
-        else:
-            ridx = (gid if id2index is None
-                    else jnp.take(id2index, gid, axis=0, mode="clip"))
-            x = gather_rows(rows_arg, ridx, force=force)
-            x = jnp.where(valid[:, None], x, 0)
-        y = jnp.where(valid,
-                      jnp.take(labels_arg, gid, axis=0, mode="clip"),
-                      PADDING_ID)
+        with jax.named_scope("glt.gather.feat"):
+            if fused != "off":
+                x = fused_frontier(rows_arg, ids, id2index=id2index,
+                                   force=fused).features
+            elif dedup:
+                x = dedup_gather_rows(rows_arg, ids, id2index=id2index,
+                                      force=force)
+            else:
+                ridx = (gid if id2index is None
+                        else jnp.take(id2index, gid, axis=0, mode="clip"))
+                x = gather_rows(rows_arg, ridx, force=force)
+                x = jnp.where(valid[:, None], x, 0)
+        with jax.named_scope("glt.gather.label"):
+            y = jnp.where(valid,
+                          jnp.take(labels_arg, gid, axis=0, mode="clip"),
+                          PADDING_ID)
         return x, y
 
     return gather_xy
@@ -164,7 +170,6 @@ def make_cached_gather_xy(id2index=None, force: str = "auto"):
 
     def gather_xy(cache, rows_arg, labels_arg, out):
         ids = out.node.astype(jnp.int32)
-        uniq, inv, _ = unique_first_occurrence(ids)
 
         def fetch(fids):
             v = fids >= 0
@@ -174,14 +179,17 @@ def make_cached_gather_xy(id2index=None, force: str = "auto"):
             return jnp.where(v[:, None],
                              gather_rows(rows_arg, fidx, force), 0)
 
-        cache, urows = cache_gather(cache, uniq, fetch, force=force)
-        x = jnp.take(urows, jnp.clip(inv, 0, inv.shape[0] - 1), axis=0)
-        x = jnp.where((inv >= 0)[:, None], x, 0)
+        with jax.named_scope("glt.gather.feat"):
+            uniq, inv, _ = unique_first_occurrence(ids)
+            cache, urows = cache_gather(cache, uniq, fetch, force=force)
+            x = jnp.take(urows, jnp.clip(inv, 0, inv.shape[0] - 1), axis=0)
+            x = jnp.where((inv >= 0)[:, None], x, 0)
         valid = ids >= 0
         gid = jnp.where(valid, ids, 0)
-        y = jnp.where(valid,
-                      jnp.take(labels_arg, gid, axis=0, mode="clip"),
-                      PADDING_ID)
+        with jax.named_scope("glt.gather.label"):
+            y = jnp.where(valid,
+                          jnp.take(labels_arg, gid, axis=0, mode="clip"),
+                          PADDING_ID)
         return cache, x, y
 
     return gather_xy
@@ -286,9 +294,10 @@ def make_scanned_node_train_step(model, tx, sampler, rows, labels,
                 loss_fn, has_aux=True)(st.params)
 
             def apply(s):
-                updates, opt_state = tx.update(grads, s.opt_state,
-                                               s.params)
-                params = optax.apply_updates(s.params, updates)
+                with jax.named_scope("glt.step.update"):
+                    updates, opt_state = tx.update(grads, s.opt_state,
+                                                   s.params)
+                    params = optax.apply_updates(s.params, updates)
                 return TrainState(params, opt_state, s.step + 1)
 
             # Fully-padded trailing batches (block padding) must be

@@ -4,16 +4,27 @@ The library-wide observability subsystem (docs/observability.md):
 
   * **Tracing** (:mod:`.trace`): nested host-side spans with explicit
     device fencing, exported as Chrome-trace/Perfetto JSON; summarize
-    with ``python -m glt_tpu.obs summarize trace.json``.
+    with ``python -m glt_tpu.obs summarize trace.json``.  Every span is
+    also a ``jax.profiler.TraceAnnotation("glt.<name>")``: a profiler
+    session sees the program's spans on the device's clock.
+  * **Device scopes** (:mod:`.scopes`): ``jax.named_scope`` under one
+    ``glt.<layer>.<stage>`` taxonomy, read back from a profiler trace
+    through the HLO metadata it carries (``chipbench/scopes.py``).
   * **Metrics** (:mod:`.metrics`): counters/gauges/histograms under one
     ``glt.*`` namespace with near-zero-cost no-op defaults; Prometheus
     text exposition serves the ``get_metrics`` op on ``DistServer``.
   * **Roofline** (:mod:`.roofline`): a measured device-memcpy bandwidth
     ceiling so ``gather_gb_s`` becomes an achieved-vs-peak fraction.
 
-Both tracing and metrics are OFF by default and cost roughly a global
-read + branch per call site when off.  Everything here is **host-side**:
-never call span()/inc() inside a jit-traced function (gltlint GLT010).
+Tracer and metrics are OFF by default and cost roughly a global read +
+branch (a span: one annotation object) per call site when off.  Two
+rules say where each tool goes: **host spans** (``span()``, ``inc()``
+and every other metric call) never inside a jit-traced function (gltlint
+GLT010: the call would run once, at trace time); **device scopes**
+(``jax.named_scope`` / :func:`.scopes.scoped`) only inside one, with a
+name from the table in :mod:`.scopes`.  Read both with ``python
+chipbench/scopes.py <trace dir>`` or ``xprof``'s ``hlo_stats`` tool
+(docs/observability.md "Device scopes").
 
 >>> from glt_tpu import obs
 >>> obs.metrics.enable()

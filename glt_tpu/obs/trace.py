@@ -5,24 +5,31 @@ span(...)`` blocks; the export loads directly into ``chrome://tracing``
 or https://ui.perfetto.dev, and ``python -m glt_tpu.obs summarize``
 renders a per-span aggregate table.
 
+One span system, two sinks.  Every ``span(name)`` also opens a
+``jax.profiler.TraceAnnotation("glt." + name)``, so a ``jax.profiler``
+session (``chipbench``'s traced run, ``obs.profiler.capture``) shows the
+program's own spans on the profiler's clock, next to the device's ops,
+whether or not a :class:`Tracer` is installed.  With no profiler session
+an annotation is a flag test in C++.
+
 Two rules make spans safe around jit:
 
   * **Host-side only.**  Never open a span (or touch a metric) inside a
     jit-traced function — the call runs once at trace time and vanishes
     from the compiled program.  gltlint GLT010 ``span-in-traced-code``
-    enforces this statically.
+    enforces this statically.  Inside traced code the tool is a device
+    scope (:mod:`glt_tpu.obs.scopes`).
   * **Explicit device fencing.**  jax dispatch is async, so a span
     around a jitted call measures *dispatch*, not execution.  Register
-    the call's outputs with ``span.fence(out)`` and the span's close
-    waits for them: ``jax.block_until_ready``, then a **host value
-    fetch** of each output (one element of a large one).  On the TPU
-    v5e both wait — ``chip_smoke.py`` times a matmul chain each way on
-    every run — so the fetch adds one host round trip, not correctness.
-    The span then records both the dispatch slice and the device wait
-    in ``args``.
+    the call's outputs with ``span.fence(out)`` and, with a tracer
+    installed, the span's close waits for them with
+    ``jax.block_until_ready`` (it waits on the TPU v5e:
+    ``chip_smoke.py`` checks that on every run).  The span then records
+    both the dispatch slice and the device wait in ``args``.
 
-When no tracer is installed, ``span()`` returns a shared no-op object —
-one module-global read per call, cheap enough to leave in hot loops.
+When no tracer is installed, ``span()`` returns an annotation-only span
+with the same surface (``fence`` and ``set`` do nothing): one small
+object per call, cheap enough to leave in hot loops.
 """
 from __future__ import annotations
 
@@ -33,29 +40,44 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-# Fetching a padded frontier or a feature block to the host would
-# distort the span; above this element count only one element is pulled
-# (its value still chains the whole computation).
-_FETCH_MAX_ELEMS = 4096
+#: Prefix of every span's name on the profiler's clock.
+ANNOTATION_PREFIX = "glt."
 
 
-def _device_fence(token_groups: List[Any]) -> None:
-    """Wait until every registered device value is actually computed."""
-    import jax
-    import numpy as np
+_annotation_span_cls = None
 
-    leaves: List[Any] = []
-    for tokens in token_groups:
-        leaves.extend(jax.tree_util.tree_leaves(tokens))
-    arrs = [a for a in leaves if isinstance(a, jax.Array)]
-    if not arrs:
-        return
-    jax.block_until_ready(arrs)
-    for a in arrs:
-        if getattr(a, "size", 0) <= _FETCH_MAX_ELEMS:
-            np.asarray(jax.device_get(a))
-        else:
-            np.asarray(jax.device_get(a.ravel()[0]))
+
+def _annotation_span(name: str):
+    """An annotation-only span: ``jax.profiler.TraceAnnotation`` with a
+    span's surface.  ``jax`` is imported on first use, as everywhere in
+    ``obs``."""
+    global _annotation_span_cls
+    if _annotation_span_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        class _AnnotationSpan(TraceAnnotation):
+            """Served while no tracer is installed: shows on a profiler
+            session's host plane, records nothing else."""
+
+            __slots__ = ()
+
+            span_id = None
+            trace_id = None
+
+            def fence(self, tokens):
+                return tokens
+
+            def set(self, **attrs):
+                pass
+
+            def link(self, trace_id, parent_span_id):
+                return self
+
+            def context(self):
+                return None
+
+        _annotation_span_cls = _AnnotationSpan
+    return _annotation_span_cls(ANNOTATION_PREFIX + name)
 
 
 def _gen_trace_id() -> str:
@@ -67,7 +89,7 @@ class Span:
     """One timed region; use as a context manager (see :func:`span`)."""
 
     __slots__ = ("_tracer", "name", "_attrs", "_t0_ns", "_tokens", "_depth",
-                 "span_id", "trace_id", "_parent_id")
+                 "span_id", "trace_id", "_parent_id", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -77,8 +99,10 @@ class Span:
         self.span_id: Optional[int] = None
         self.trace_id: Optional[str] = None
         self._parent_id: Optional[int] = None
+        self._annotation = _annotation_span(name)
 
     def __enter__(self) -> "Span":
+        self._annotation.__enter__()
         stack = self._tracer._stack()
         self._depth = len(stack)
         self.span_id = self._tracer._next_span_id()
@@ -134,8 +158,11 @@ class Span:
     def __exit__(self, exc_type, exc, tb) -> bool:
         dispatch_ns = time.perf_counter_ns() - self._t0_ns
         if self._tokens is not None and exc_type is None:
-            _device_fence(self._tokens)
+            import jax
+
+            jax.block_until_ready(self._tokens)
         end_ns = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -163,36 +190,6 @@ class Span:
             "args": args,
         })
         return False
-
-
-class _NullSpan:
-    """Shared no-op span served while no tracer is installed."""
-
-    __slots__ = ()
-
-    span_id = None
-    trace_id = None
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def fence(self, tokens):
-        return tokens
-
-    def set(self, **attrs):
-        pass
-
-    def link(self, trace_id, parent_span_id):
-        return self
-
-    def context(self):
-        return None
-
-
-_NULL_SPAN = _NullSpan()
 
 
 class Tracer:
@@ -466,7 +463,8 @@ def _install_crash_handlers() -> None:
 
 
 def span(name: str, **attrs):
-    """A span on the global tracer — the shared no-op when tracing is off.
+    """A span on the global tracer, and in every case an annotation
+    ``glt.<name>`` for a ``jax.profiler`` session to see.
 
     >>> with span("loader.sample_dispatch") as sp:
     ...     out = sampler.sample_from_nodes(inp)
@@ -474,7 +472,7 @@ def span(name: str, **attrs):
     """
     tracer = _current
     if tracer is None:
-        return _NULL_SPAN
+        return _annotation_span(name)
     return Span(tracer, name, attrs)
 
 

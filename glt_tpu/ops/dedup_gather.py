@@ -27,10 +27,12 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from ..obs.scopes import scoped
 from .gather_pallas import gather_rows
 from .unique import unique_first_occurrence
 
 
+@scoped("glt.gather.feat")
 def dedup_gather_rows(table: jnp.ndarray, ids: jnp.ndarray,
                       id2index: Optional[jnp.ndarray] = None,
                       force: str = "auto") -> jnp.ndarray:

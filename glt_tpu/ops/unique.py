@@ -23,6 +23,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.scopes import scoped
+
 _INT32_MAX = jnp.iinfo(jnp.int32).max
 
 
@@ -32,6 +34,7 @@ class UniqueResult(NamedTuple):
     count: jnp.ndarray    # [] int32 number of valid uniques
 
 
+@scoped("glt.sample.induce")
 def unique_first_occurrence(ids: jnp.ndarray) -> UniqueResult:
     """Deduplicate ``ids`` preserving first-occurrence order.
 
@@ -107,6 +110,7 @@ def dense_map_fits(num_nodes: int, budget_bytes: int = 1 << 30) -> bool:
     return num_nodes * 4 <= budget_bytes
 
 
+@scoped("glt.sample.induce")
 def dense_induce_init(num_nodes: int, capacity: int) -> DenseInduceState:
     """Fresh per-batch state (the analog of ``Inducer::Reset``,
     csrc/cpu/inducer.cc; allocating zeros is a ~4B/node memset)."""
@@ -129,6 +133,7 @@ _PROV_BASE = 1 << 25
 _LOCAL_BASE = 1 << 30
 
 
+@scoped("glt.sample.induce")
 def dense_induce(state: DenseInduceState, cand: jnp.ndarray
                  ) -> tuple:
     """Insert ``cand`` (negative = padding) into the cumulative unique
@@ -186,6 +191,7 @@ def dense_induce(state: DenseInduceState, cand: jnp.ndarray
     return DenseInduceState(seen, node_buf, count), local
 
 
+@scoped("glt.sample.induce")
 def dense_induce_final(state: DenseInduceState, cand: jnp.ndarray
                        ) -> tuple:
     """Last-hop :func:`dense_induce`: same contract, one fewer map op.
@@ -232,6 +238,7 @@ def dense_induce_final(state: DenseInduceState, cand: jnp.ndarray
     return DenseInduceState(seen, node_buf, count), local
 
 
+@scoped("glt.sample.induce")
 def relabel_by_reference(reference_ids: jnp.ndarray, query_ids: jnp.ndarray) -> jnp.ndarray:
     """Map each ``query_id`` to its position in ``reference_ids``.
 

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.scopes import scoped
 from ..ops.fused_frontier import fused_frontier as _fused_frontier
 from ..ops.unique import unique_first_occurrence
 from .dist_sampler import (HierarchicalRouting, Routing, _topology_choice,
@@ -55,6 +56,7 @@ def _dedup_scatter_back_1d(uvals: jnp.ndarray, inv: jnp.ndarray
     return jnp.where(inv >= 0, out, 0)
 
 
+@scoped("glt.gather.feat")
 def _request_rows(rows: jnp.ndarray, local: jnp.ndarray, ok: jnp.ndarray,
                   fused_frontier: str) -> jnp.ndarray:
     """Serving-side row fetch of every exchange: rows for the id
@@ -75,6 +77,7 @@ def _request_rows(rows: jnp.ndarray, local: jnp.ndarray, ok: jnp.ndarray,
     return jnp.where(ok[:, None], got, 0)
 
 
+@scoped("glt.route.exchange")
 def _exchange_ids(routing: Routing, num_shards: int, cap: int,
                   axis_name: str) -> jnp.ndarray:
     """The id request all-to-all of every exchange: row q of the result
@@ -122,9 +125,10 @@ def _return_payload(routing, payload, num_shards, b, axis_name):
     w = payload.shape[-1]
     if isinstance(routing, HierarchicalRouting):
         return hier_response(routing, payload, 0)
-    return lax.all_to_all(
-        payload.reshape(num_shards, b, w), axis_name, 0, 0,
-        tiled=False).reshape(num_shards * b, w)
+    with jax.named_scope("glt.route.exchange"):
+        return lax.all_to_all(
+            payload.reshape(num_shards, b, w), axis_name, 0, 0,
+            tiled=False).reshape(num_shards * b, w)
 
 
 def exchange_gather(
@@ -185,8 +189,9 @@ def exchange_gather(
     got = _request_rows(rows, local, ok, fused_frontier)
 
     resp = _return_payload(routing, got, num_shards, b, axis_name)
-    out = resp[jnp.clip(flat_plan.slot, 0, num_shards * b - 1)]
-    return jnp.where(flat_plan.valid[:, None], out, 0)
+    with jax.named_scope("glt.route.payload"):
+        out = resp[jnp.clip(flat_plan.slot, 0, num_shards * b - 1)]
+        return jnp.where(flat_plan.valid[:, None], out, 0)
 
 
 class TieredShardedFeature(NamedTuple):
@@ -431,25 +436,28 @@ def exchange_gather_xy(
         idx = jnp.where(staged_slots >= 0, staged_slots, gotx.shape[0])
         gotx = gotx.at[idx].set(staged_rows.astype(gotx.dtype),
                                 mode="drop")
-    goty = jnp.take(labels_col.astype(jnp.int32),
-                    jnp.where(oky, local, 0), mode="clip")
-    goty = jnp.where(oky, goty, 0)
+    with jax.named_scope("glt.gather.label"):
+        goty = jnp.take(labels_col.astype(jnp.int32),
+                        jnp.where(oky, local, 0), mode="clip")
+        goty = jnp.where(oky, goty, 0)
 
     if _use_fused(fused) and rows.dtype == jnp.float32:
-        ybits = lax.bitcast_convert_type(goty, jnp.float32)[:, None]
-        resp = _return_payload(
-            routing, jnp.concatenate([gotx, ybits], axis=-1),
-            num_shards, b, axis_name)
-        respx = resp[:, :d]
-        respy = lax.bitcast_convert_type(resp[:, d], jnp.int32)
+        with jax.named_scope("glt.route.payload"):
+            ybits = lax.bitcast_convert_type(goty, jnp.float32)[:, None]
+            packed = jnp.concatenate([gotx, ybits], axis=-1)
+        resp = _return_payload(routing, packed, num_shards, b, axis_name)
+        with jax.named_scope("glt.route.payload"):
+            respx = resp[:, :d]
+            respy = lax.bitcast_convert_type(resp[:, d], jnp.int32)
     else:
         respx = _return_payload(routing, gotx, num_shards, b, axis_name)
         respy = _return_payload(routing, goty[:, None], num_shards, b,
                                 axis_name)[:, 0]
 
-    slot = jnp.clip(flat_plan.slot, 0, num_shards * b - 1)
-    x = jnp.where(flat_plan.valid[:, None], respx[slot], 0)
-    y = jnp.where(flat_plan.valid, respy[slot], 0)
+    with jax.named_scope("glt.route.payload"):
+        slot = jnp.clip(flat_plan.slot, 0, num_shards * b - 1)
+        x = jnp.where(flat_plan.valid[:, None], respx[slot], 0)
+        y = jnp.where(flat_plan.valid, respy[slot], 0)
     return x, y
 
 

@@ -32,6 +32,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import metrics as _metrics
+from ..obs.scopes import scoped
 from ..obs.trace import span as _span
 from ..ops.neighbor_sample import _row_offsets_and_degrees, sample_neighbors
 from ..ops.unique import (
@@ -208,6 +209,7 @@ def _bucket_by_owner_onepass(ids: jnp.ndarray, owner: jnp.ndarray,
                    valid=valid & in_range & fits, dropped=dropped)
 
 
+@scoped("glt.route.bucket")
 def _bucket_by_owner(ids: jnp.ndarray, owner: jnp.ndarray, num_shards: int,
                      cap: int, route: str = "auto") -> Routing:
     """Group ids into per-owner rows of a static ``[S, cap]`` buffer.
@@ -229,6 +231,7 @@ def _bucket_by_owner(ids: jnp.ndarray, owner: jnp.ndarray, num_shards: int,
     return _bucket_by_owner_sort(ids, owner, num_shards, cap)
 
 
+@scoped("glt.route.bucket")
 def build_routing(ids: jnp.ndarray, nodes_per_shard: int, num_shards: int,
                   cap: Optional[int] = None,
                   route: str = "auto") -> Routing:
@@ -358,6 +361,7 @@ def _autotune_topology(b: int, mesh_shape, cap: int,
     return choice
 
 
+@scoped("glt.route.payload")
 def _bucket_payload(routing: Routing, payload: jnp.ndarray,
                     num_shards: int, cap: int) -> jnp.ndarray:
     """Scatter a payload array into the same bucket slots as its ids."""
@@ -506,13 +510,16 @@ def build_hier_routing(
     # ICI leg: land every local chip's bucket for owner (oh, my_chip) on
     # this device — slab[oh, q*cap + j] = chip q's j-th request for that
     # owner.
-    slab = lax.all_to_all(base.buckets.reshape(h, c, cap), chip_axis,
-                          1, 1, tiled=False).reshape(h, c * cap)
-    u = jax.vmap(unique_first_occurrence)(slab)
+    with jax.named_scope("glt.route.exchange"):
+        slab = lax.all_to_all(base.buckets.reshape(h, c, cap), chip_axis,
+                              1, 1, tiled=False).reshape(h, c * cap)
     hc = hier_request_cap(cap, c, nodes_per_shard, hier_load_factor)
-    uniq = u.uniques[:, :hc]
-    inv = jnp.where((u.inverse >= 0) & (u.inverse < hc), u.inverse, -1)
-    hier_dropped = jnp.sum(jnp.maximum(u.count - hc, 0)).astype(jnp.int32)
+    with jax.named_scope("glt.route.bucket"):
+        u = jax.vmap(unique_first_occurrence)(slab)
+        uniq = u.uniques[:, :hc]
+        inv = jnp.where((u.inverse >= 0) & (u.inverse < hc), u.inverse, -1)
+        hier_dropped = jnp.sum(
+            jnp.maximum(u.count - hc, 0)).astype(jnp.int32)
     return HierarchicalRouting(
         base=base, uniq=uniq, inv=inv, hier_dropped=hier_dropped,
         geom=HierGeom(num_hosts=h, chips_per_host=c, host_axis=host_axis,
@@ -523,8 +530,9 @@ def hier_requests(hr: HierarchicalRouting) -> jnp.ndarray:
     """DCN request leg: ``[H * hier_cap]`` host-unique ids addressed to
     this device (row ``qh`` came from host ``qh``'s same-chip peer)."""
     g = hr.geom
-    return lax.all_to_all(hr.uniq, g.host_axis, 0, 0,
-                          tiled=False).reshape(g.num_hosts * g.hier_cap)
+    with jax.named_scope("glt.route.exchange"):
+        return lax.all_to_all(hr.uniq, g.host_axis, 0, 0,
+                              tiled=False).reshape(g.num_hosts * g.hier_cap)
 
 
 def hier_response(hr: HierarchicalRouting, payload: jnp.ndarray,
@@ -540,14 +548,17 @@ def hier_response(hr: HierarchicalRouting, payload: jnp.ndarray,
     """
     g = hr.geom
     w = payload.shape[-1]
-    resp = lax.all_to_all(payload.reshape(g.num_hosts, g.hier_cap, w),
-                          g.host_axis, 0, 0, tiled=False)
-    safe = jnp.clip(hr.inv, 0, g.hier_cap - 1)
-    full = jnp.take_along_axis(resp, safe[..., None], axis=1)
-    full = jnp.where((hr.inv >= 0)[..., None], full, fill)
-    back = lax.all_to_all(
-        full.reshape(g.num_hosts, g.chips_per_host, g.cap, w),
-        g.chip_axis, 1, 1, tiled=False)
+    with jax.named_scope("glt.route.exchange"):
+        resp = lax.all_to_all(payload.reshape(g.num_hosts, g.hier_cap, w),
+                              g.host_axis, 0, 0, tiled=False)
+    with jax.named_scope("glt.route.payload"):
+        safe = jnp.clip(hr.inv, 0, g.hier_cap - 1)
+        full = jnp.take_along_axis(resp, safe[..., None], axis=1)
+        full = jnp.where((hr.inv >= 0)[..., None], full, fill)
+    with jax.named_scope("glt.route.exchange"):
+        back = lax.all_to_all(
+            full.reshape(g.num_hosts, g.chips_per_host, g.cap, w),
+            g.chip_axis, 1, 1, tiled=False)
     return back.reshape(g.num_hosts * g.chips_per_host * g.cap, w)
 
 
@@ -692,6 +703,7 @@ def exchange_one_hop(
     routing=None,
     mesh_shape: Optional[tuple] = None,
     hier_load_factor: Optional[float] = None,
+    hop: int = 1,
 ):
     """One distributed sampling hop; call inside ``shard_map``.
 
@@ -730,6 +742,9 @@ def exchange_one_hop(
         required for the hier topology when ``routing`` is not prebuilt.
       hier_load_factor: DCN-leg buffer bound (see
         :func:`hier_request_cap`); None = lossless.
+      hop: which hop of the caller's loop this is (from 1); names the
+        device scope ``glt.sample.hop<k>`` of the local neighbour read
+        and nothing else.
 
     Returns:
       ``(nbrs, eids, mask, dropped)``; first three ``[B, fanout]`` in seed
@@ -768,10 +783,11 @@ def exchange_one_hop(
         cap = int(remote_cap)
         # Local split: owner == my shard -> direct sample, no collective.
         is_local = owner == my_rank
-        local_ids = jnp.where(is_local, seeds - my_rank * nodes_per_shard,
-                              -1)
-        lout = sample_neighbors(indptr, indices, local_ids, fanout, key,
-                                edge_ids=edge_ids, key_by=key_by)
+        with jax.named_scope(f"glt.sample.hop{hop}"):
+            local_ids = jnp.where(
+                is_local, seeds - my_rank * nodes_per_shard, -1)
+            lout = sample_neighbors(indptr, indices, local_ids, fanout,
+                                    key, edge_ids=edge_ids, key_by=key_by)
         local_nbrs, local_eids = lout.nbrs, lout.eids
         remote_ids = jnp.where(is_local, PADDING_ID, seeds)
         if hier:
@@ -791,51 +807,58 @@ def exchange_one_hop(
     if hier:
         requests = hier_requests(plan)
     else:
-        requests = lax.all_to_all(
-            plan.buckets.reshape(num_shards, cap), axis_name, 0, 0,
-            tiled=False).reshape(num_shards * cap)
+        with jax.named_scope("glt.route.exchange"):
+            requests = lax.all_to_all(
+                plan.buckets.reshape(num_shards, cap), axis_name, 0, 0,
+                tiled=False).reshape(num_shards * cap)
 
     # Sample requested ids from the local CSR block (global -> local row).
-    local = jnp.where(requests >= 0,
-                      requests - my_rank * nodes_per_shard, -1)
-    local = jnp.where((local >= 0) & (local < nodes_per_shard), local, -1)
-    out = sample_neighbors(indptr, indices, local, fanout,
-                           jax.random.fold_in(key, 1), edge_ids=edge_ids,
-                           key_by=key_by)
+    with jax.named_scope(f"glt.sample.hop{hop}"):
+        local = jnp.where(requests >= 0,
+                          requests - my_rank * nodes_per_shard, -1)
+        local = jnp.where((local >= 0) & (local < nodes_per_shard),
+                          local, -1)
+        out = sample_neighbors(indptr, indices, local, fanout,
+                               jax.random.fold_in(key, 1),
+                               edge_ids=edge_ids, key_by=key_by)
 
     # Response exchange + unscatter (the stitch, stitch_sample_results.cu:57).
+    fuse = _use_fused(fused)
+    if hier or fuse:
+        with jax.named_scope("glt.route.payload"):
+            packed = jnp.concatenate([out.nbrs, out.eids], axis=-1)
     if hier:
         # The hier transport always packs neighbors + edge ids into one
         # payload (its legs are shared infrastructure); `fused` only
         # selects the flat path's collective shape.
-        resp = hier_response(
-            plan, jnp.concatenate([out.nbrs, out.eids], axis=-1),
-            fill=PADDING_ID)
+        resp = hier_response(plan, packed, fill=PADDING_ID)
         resp_nbrs, resp_eids = resp[:, :fanout], resp[:, fanout:]
-    elif _use_fused(fused):
+    elif fuse:
         # Neighbors and edge ids ride ONE [S, cap, 2*fanout] collective
         # (half the per-hop launches); the halves split back bit-exact.
-        resp = lax.all_to_all(
-            jnp.concatenate([out.nbrs, out.eids], axis=-1)
-            .reshape(num_shards, cap, 2 * fanout), axis_name, 0, 0,
-            tiled=False).reshape(num_shards * cap, 2 * fanout)
+        with jax.named_scope("glt.route.exchange"):
+            resp = lax.all_to_all(
+                packed.reshape(num_shards, cap, 2 * fanout), axis_name,
+                0, 0, tiled=False).reshape(num_shards * cap, 2 * fanout)
         resp_nbrs, resp_eids = resp[:, :fanout], resp[:, fanout:]
     else:
-        resp_nbrs = lax.all_to_all(
-            out.nbrs.reshape(num_shards, cap, fanout), axis_name, 0, 0,
-            tiled=False).reshape(num_shards * cap, fanout)
-        resp_eids = lax.all_to_all(
-            out.eids.reshape(num_shards, cap, fanout), axis_name, 0, 0,
-            tiled=False).reshape(num_shards * cap, fanout)
+        with jax.named_scope("glt.route.exchange"):
+            resp_nbrs = lax.all_to_all(
+                out.nbrs.reshape(num_shards, cap, fanout), axis_name, 0,
+                0, tiled=False).reshape(num_shards * cap, fanout)
+            resp_eids = lax.all_to_all(
+                out.eids.reshape(num_shards, cap, fanout), axis_name, 0,
+                0, tiled=False).reshape(num_shards * cap, fanout)
 
-    nbrs = jnp.where(flat_plan.valid[:, None],
-                     resp_nbrs[flat_plan.slot], PADDING_ID)
-    eids = jnp.where(flat_plan.valid[:, None],
-                     resp_eids[flat_plan.slot], PADDING_ID)
-    if local_nbrs is not None:
-        sel = is_local[:, None]
-        nbrs = jnp.where(sel, local_nbrs, nbrs)
-        eids = jnp.where(sel, local_eids, eids)
+    with jax.named_scope("glt.route.payload"):
+        nbrs = jnp.where(flat_plan.valid[:, None],
+                         resp_nbrs[flat_plan.slot], PADDING_ID)
+        eids = jnp.where(flat_plan.valid[:, None],
+                         resp_eids[flat_plan.slot], PADDING_ID)
+        if local_nbrs is not None:
+            sel = is_local[:, None]
+            nbrs = jnp.where(sel, local_nbrs, nbrs)
+            eids = jnp.where(sel, local_eids, eids)
     dropped = (flat_plan.dropped + plan.hier_dropped if hier
                else plan.dropped)
     return nbrs, eids, nbrs >= 0, dropped
@@ -857,6 +880,7 @@ def exchange_one_hop_ring(
     routing: Optional[Routing] = None,
     mesh_shape: Optional[tuple] = None,
     hier_load_factor: Optional[float] = None,
+    hop: int = 1,
 ):
     """Ring-pipelined variant of :func:`exchange_one_hop`.
 
@@ -874,7 +898,8 @@ def exchange_one_hop_ring(
     ``hier_load_factor`` are accepted for signature parity with
     :func:`exchange_one_hop` and ignored (on a 2-D mesh the ring rotates
     the combined axis; draws keep the 2-D per-id keying so it stays
-    comparable with the all-to-all paths).
+    comparable with the all-to-all paths).  ``hop`` names the device
+    scope of the local neighbour read, as in :func:`exchange_one_hop`.
     """
     del mesh_shape, hier_load_factor  # flat-only transport
     b = seeds.shape[0]
@@ -883,11 +908,13 @@ def exchange_one_hop_ring(
     key_by = "slot" if isinstance(axis_name, str) else "id"
 
     def local_sample(ids, k):
-        local = jnp.where(ids >= 0, ids - my * nodes_per_shard, -1)
-        local = jnp.where((local >= 0) & (local < nodes_per_shard), local, -1)
-        return sample_neighbors(indptr, indices, local, fanout,
-                                jax.random.fold_in(key, k),
-                                edge_ids=edge_ids, key_by=key_by)
+        with jax.named_scope(f"glt.sample.hop{hop}"):
+            local = jnp.where(ids >= 0, ids - my * nodes_per_shard, -1)
+            local = jnp.where((local >= 0) & (local < nodes_per_shard),
+                              local, -1)
+            return sample_neighbors(indptr, indices, local, fanout,
+                                    jax.random.fold_in(key, k),
+                                    edge_ids=edge_ids, key_by=key_by)
 
     if remote_cap is None:
         cap = b
@@ -908,6 +935,10 @@ def exchange_one_hop_ring(
     right = [(i, (i + 1) % num_shards) for i in range(num_shards)]
     fuse = _use_fused(fused)
 
+    def rotate(block):
+        with jax.named_scope("glt.route.exchange"):
+            return lax.ppermute(block, axis_name, right)
+
     # The request matrix and its answer buffers travel the ring together:
     # after k rotations shard i holds the matrix that originated at shard
     # i-k and serves ITS row i (the requests shard i-k addressed to i).
@@ -920,16 +951,17 @@ def exchange_one_hop_ring(
 
         def serve(reqs, ans, k):
             o = local_sample(jnp.take(reqs, my, axis=0), k)
-            return ans.at[my].set(
-                jnp.concatenate([o.nbrs, o.eids], axis=-1))
+            with jax.named_scope("glt.route.payload"):
+                return ans.at[my].set(
+                    jnp.concatenate([o.nbrs, o.eids], axis=-1))
 
         ans = serve(reqs, ans, 0)
         for k in range(1, num_shards):
-            reqs = lax.ppermute(reqs, axis_name, right)
-            ans = lax.ppermute(ans, axis_name, right)
+            reqs = rotate(reqs)
+            ans = rotate(ans)
             ans = serve(reqs, ans, k)
         if num_shards > 1:
-            ans = lax.ppermute(ans, axis_name, right)
+            ans = rotate(ans)
         ans = ans.reshape(num_shards * cap, 2 * fanout)
         resp_nbrs, resp_eids = ans[:, :fanout], ans[:, fanout:]
     else:
@@ -939,28 +971,30 @@ def exchange_one_hop_ring(
         def serve(reqs, ans_n, ans_e, k):
             incoming = jnp.take(reqs, my, axis=0)
             o = local_sample(incoming, k)
-            return ans_n.at[my].set(o.nbrs), ans_e.at[my].set(o.eids)
+            with jax.named_scope("glt.route.payload"):
+                return ans_n.at[my].set(o.nbrs), ans_e.at[my].set(o.eids)
 
         ans_n, ans_e = serve(reqs, ans_n, ans_e, 0)
         for k in range(1, num_shards):
-            reqs = lax.ppermute(reqs, axis_name, right)
-            ans_n = lax.ppermute(ans_n, axis_name, right)
-            ans_e = lax.ppermute(ans_e, axis_name, right)
+            reqs = rotate(reqs)
+            ans_n = rotate(ans_n)
+            ans_e = rotate(ans_e)
             ans_n, ans_e = serve(reqs, ans_n, ans_e, k)
         if num_shards > 1:
-            ans_n = lax.ppermute(ans_n, axis_name, right)
-            ans_e = lax.ppermute(ans_e, axis_name, right)
+            ans_n = rotate(ans_n)
+            ans_e = rotate(ans_e)
 
         resp_nbrs = ans_n.reshape(num_shards * cap, fanout)
         resp_eids = ans_e.reshape(num_shards * cap, fanout)
-    nbrs = jnp.where(routing.valid[:, None], resp_nbrs[routing.slot],
-                     PADDING_ID)
-    eids = jnp.where(routing.valid[:, None], resp_eids[routing.slot],
-                     PADDING_ID)
-    if local_nbrs is not None:
-        sel = is_local[:, None]
-        nbrs = jnp.where(sel, local_nbrs, nbrs)
-        eids = jnp.where(sel, local_eids, eids)
+    with jax.named_scope("glt.route.payload"):
+        nbrs = jnp.where(routing.valid[:, None], resp_nbrs[routing.slot],
+                         PADDING_ID)
+        eids = jnp.where(routing.valid[:, None], resp_eids[routing.slot],
+                         PADDING_ID)
+        if local_nbrs is not None:
+            sel = is_local[:, None]
+            nbrs = jnp.where(sel, local_nbrs, nbrs)
+            eids = jnp.where(sel, local_eids, eids)
     return nbrs, eids, nbrs >= 0, routing.dropped
 
 
@@ -1071,7 +1105,8 @@ def dist_sample_multi_hop(
             frontier, indptr, indices, edge_ids, nodes_per_shard,
             num_shards, f, keys[i], axis_name, remote_cap=remote_cap,
             route=route, fused=fused, routing=hop_routing,
-            mesh_shape=mesh_shape, hier_load_factor=hier_load_factor)
+            mesh_shape=mesh_shape, hier_load_factor=hier_load_factor,
+            hop=i + 1)
         dropped_total = dropped_total + dropped
 
         src_local = frontier_start + jnp.arange(w, dtype=jnp.int32)

@@ -245,9 +245,11 @@ def make_dist_train_step(
 
         (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params)
-        grads = lax.pmean(grads, axis_name)
-        loss = lax.pmean(loss, axis_name)
-        acc = lax.pmean(acc, axis_name)
+        # One scope for the three: XLA combines them into one all-reduce.
+        with jax.named_scope("glt.step.update"):
+            grads = lax.pmean(grads, axis_name)
+            loss = lax.pmean(loss, axis_name)
+            acc = lax.pmean(acc, axis_name)
         return loss, acc, grads
 
     shard_fn = jax.shard_map(
@@ -267,8 +269,10 @@ def make_dist_train_step(
                                     key)
 
         def apply(s):
-            updates, opt_state = tx.update(grads, s.opt_state, s.params)
-            params = optax.apply_updates(s.params, updates)
+            with jax.named_scope("glt.step.update"):
+                updates, opt_state = tx.update(grads, s.opt_state,
+                                               s.params)
+                params = optax.apply_updates(s.params, updates)
             return TrainState(params, opt_state, s.step + 1)
 
         # A fully-padded batch must not move a stateful optimizer or the
@@ -382,14 +386,16 @@ def make_scanned_dist_train_step(
 
             (loss, acc), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(st.params)
-            grads = lax.pmean(grads, axis_name)
-            loss = lax.pmean(loss, axis_name)
-            acc = lax.pmean(acc, axis_name)
+            with jax.named_scope("glt.step.update"):
+                grads = lax.pmean(grads, axis_name)
+                loss = lax.pmean(loss, axis_name)
+                acc = lax.pmean(acc, axis_name)
 
             def apply(s):
-                updates, opt_state = tx.update(grads, s.opt_state,
-                                               s.params)
-                params = optax.apply_updates(s.params, updates)
+                with jax.named_scope("glt.step.update"):
+                    updates, opt_state = tx.update(grads, s.opt_state,
+                                                   s.params)
+                    params = optax.apply_updates(s.params, updates)
                 return TrainState(params, opt_state, s.step + 1)
 
             # Fully-padded slots must not move a stateful optimizer or

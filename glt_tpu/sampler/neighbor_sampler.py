@@ -302,10 +302,11 @@ class NeighborSampler(BaseSampler):
         for i, f in enumerate(fanouts):
             w = widths[i]
             last = i + 1 == len(fanouts)
-            out = sample_neighbors(indptr, indices, frontier, f, keys[i],
-                                   edge_ids=edge_ids,
-                                   with_edge=self.with_edge,
-                                   force=self.sample_force)
+            with jax.named_scope(f"glt.sample.hop{i + 1}"):
+                out = sample_neighbors(indptr, indices, frontier, f,
+                                       keys[i], edge_ids=edge_ids,
+                                       with_edge=self.with_edge,
+                                       force=self.sample_force)
             # Seed-side local indices (position of frontier nodes in node_buf).
             src_local = frontier_start + jnp.arange(w, dtype=jnp.int32)
             src_local = jnp.where(frontier >= 0, src_local, PADDING_ID)

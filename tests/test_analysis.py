@@ -615,6 +615,21 @@ class TestSpanInTracedCode:
         """
         assert findings_for(src, "span-in-traced-code") == []
 
+    def test_negative_device_scopes_belong_in_traced_code(self):
+        src = """
+        import jax
+        from glt_tpu.obs.scopes import scoped
+
+        @jax.jit
+        def step(x):
+            @scoped("glt.model.agg")
+            def agg(y):
+                return y.sum()
+            with jax.named_scope("glt.model.dense"):
+                return agg(x) + 1
+        """
+        assert findings_for(src, "span-in-traced-code") == []
+
     def test_suppression_with_justification(self):
         src = """
         import jax
