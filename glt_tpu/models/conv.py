@@ -10,6 +10,9 @@ with -1 padding and an ``edge_mask``, ``edge_index[0]`` = message source
 TPU notes: aggregation is ``jax.ops.segment_sum`` with a spill segment for
 padding edges (XLA lowers this to sorted-scatter, MXU-friendly); all matmuls
 are batched over the padded node dimension so shapes are static.
+``SAGEConv`` can aggregate into a static prefix of the rows only
+(``num_dst``), which is how :class:`~glt_tpu.models.sage.GraphSAGE` trims
+each layer to the hops whose result reaches the seeds.
 
 Mixed precision: every layer takes ``dtype`` (e.g. ``jnp.bfloat16``) — the
 COMPUTE dtype of its Dense matmuls only.  Params stay float32, the MXU
@@ -83,22 +86,34 @@ class SAGEConv(nn.Module):
     """GraphSAGE convolution (mean aggregator).
 
     ``h_i = W_self x_i + W_nbr mean_{j->i} x_j``
+
+    ``num_dst`` makes the layer bipartite over a prefix: messages are
+    gathered from every row of ``x``, summed into the first ``num_dst``
+    rows only, and ``lin_self`` runs on ``x[:num_dst]``; the result has
+    ``num_dst`` rows.  The caller guarantees that every unmasked edge
+    has ``dst < num_dst`` (the sampler's hop-block layout does, see
+    :func:`~glt_tpu.sampler.neighbor_sampler.hop_bounds`), so rows
+    ``< num_dst`` are the numbers the whole layer computes for them.
+    ``None`` is the whole layer: every row of ``x`` is a destination.
     """
     out_features: int
     use_bias: bool = True
     dtype: Any = None   # matmul compute dtype (e.g. bf16); params/agg f32
 
     @nn.compact
-    def __call__(self, x, edge_index, edge_mask):
-        num_nodes = x.shape[0]
+    def __call__(self, x, edge_index, edge_mask,
+                 num_dst: Optional[int] = None):
+        num_src = x.shape[0]
+        if num_dst is None:
+            num_dst = num_src
         src, dst = edge_index[0], edge_index[1]
         with jax.named_scope("glt.model.msg"):
-            msgs = jnp.take(x, jnp.clip(src, 0, num_nodes - 1), axis=0)
-        agg = scatter_mean(msgs, dst, num_nodes, edge_mask)
+            msgs = jnp.take(x, jnp.clip(src, 0, num_src - 1), axis=0)
+        agg = scatter_mean(msgs, dst, num_dst, edge_mask)
         dt = _mm_dtype(self.dtype)
         with jax.named_scope("glt.model.dense"):
             out = (nn.Dense(self.out_features, use_bias=self.use_bias,
-                            dtype=dt, name="lin_self")(x)
+                            dtype=dt, name="lin_self")(x[:num_dst])
                    + nn.Dense(self.out_features, use_bias=False,
                               dtype=dt, name="lin_nbr")(agg))
             return out if dt is None else out.astype(jnp.float32)

@@ -64,17 +64,54 @@ def create_train_state(model, rng, sample_batch, tx) -> TrainState:
 
 
 @scoped("glt.step.loss")
-def seed_cross_entropy(logits, y, batch_size: int, node_mask):
-    """Mean CE over valid seed rows (first ``batch_size`` slots)."""
+def seed_cross_entropy(logits, y, batch_size: int, node_mask,
+                       num_seeds=None):
+    """Mean CE over valid seed rows (first ``batch_size`` slots).
+
+    ``num_seeds`` (a traced count, ``SamplerOutput.num_sampled_nodes[0]``)
+    keeps the loss to the rows that hold a seed: a partly padded seed
+    batch has fewer unique seeds than ``batch_size``, and the rows behind
+    them hold labelled hop-1 nodes.
+    """
     sl = logits[:batch_size]
     sy = y[:batch_size]
     valid = (sy >= 0) & node_mask[:batch_size]
+    if num_seeds is not None:
+        valid &= jnp.arange(batch_size, dtype=jnp.int32) < num_seeds
     sy_safe = jnp.where(valid, sy, 0)
     ce = optax.softmax_cross_entropy_with_integer_labels(sl, sy_safe)
     n = jnp.maximum(valid.sum(), 1)
     loss = jnp.where(valid, ce, 0).sum() / n
     acc = jnp.where(valid, jnp.argmax(sl, -1) == sy_safe, False).sum() / n
     return loss, acc
+
+
+def hop_trimming(model, hops) -> dict:
+    """``model.apply`` keywords that run ``model`` trimmed to ``hops``.
+
+    A model that trims by the sampler's hop-block layout says so by
+    having ``layer_extents(hops)`` and taking ``hops=``
+    (:class:`~glt_tpu.models.sage.GraphSAGE`); any other model runs whole
+    (``{}``).  Engagement is a trace-time fact, so it is recorded here,
+    when the step is built: ``glt.model.layer_edge_slots{layer=l}`` /
+    ``glt.model.layer_node_rows{layer=l}`` (the rows layer ``l``
+    computes; it reads the rows of layer ``l-1``) against
+    ``glt.model.edge_slots`` / ``glt.model.node_rows`` of the batch.
+    """
+    if not hasattr(model, "layer_extents"):
+        return {}
+    gauge = _metrics.gauge
+    gauge("glt.model.edge_slots", "edge slots of the sampled batch of the "
+          "last hop-trimmed step built").set(hops.edge_bounds[-1])
+    gauge("glt.model.node_rows", "node rows of the sampled batch of the "
+          "last hop-trimmed step built").set(hops.node_bounds[-1])
+    for i, (_, n_edge, n_dst) in enumerate(model.layer_extents(hops), 1):
+        labels = {"layer": str(i)}
+        gauge("glt.model.layer_edge_slots", "edge slots one GraphSAGE layer "
+              "aggregates in that step", labels).set(n_edge)
+        gauge("glt.model.layer_node_rows", "rows one GraphSAGE layer "
+              "computes in that step", labels).set(n_dst)
+    return {"hops": hops}
 
 
 def make_train_step(model, tx, batch_size: int,
@@ -224,6 +261,11 @@ def make_scanned_node_train_step(model, tx, sampler, rows, labels,
     are dispatch-bound exactly like the link/subgraph paths where the
     same trick bought 7–17×.
 
+    A model that trims by the hop-block layout (``GraphSAGE``) runs each
+    layer over ``sampler.hop_bounds`` only (:func:`hop_trimming`): the
+    same seed logits, loss and gradients from fewer edge slots.  The loss
+    is over the rows of real seeds (``num_sampled_nodes[0]``).
+
     Returns ``step(state, seeds_blk [G, B], key) -> (state, losses [G],
     accs [G], overflows [G])``; seed blocks are -1 padded (fully-padded
     trailing batches contribute zero-valid losses).  ``overflows`` is
@@ -268,6 +310,7 @@ def make_scanned_node_train_step(model, tx, sampler, rows, labels,
                                           force=gather_force)
     gather_xy = make_gather_xy(rows.id2index, dedup=dedup,
                                force=gather_force, fused=fused_frontier)
+    trim = hop_trimming(model, sampler.hop_bounds)
 
     @partial(jax.jit, donate_argnums=(6,))
     def run(indptr, indices, eids, rows_arg, labels_arg,
@@ -286,9 +329,11 @@ def make_scanned_node_train_step(model, tx, sampler, rows, labels,
 
             def loss_fn(p):
                 logits = model.apply(p, x, edge_index, out.edge_mask,
-                                     train=True, rngs={"dropout": rng})
+                                     train=True, rngs={"dropout": rng},
+                                     **trim)
                 return seed_cross_entropy(logits, y, batch_size,
-                                          out.node_mask)
+                                          out.node_mask,
+                                          out.num_sampled_nodes[0])
 
             (loss, acc), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(st.params)

@@ -44,7 +44,8 @@ from ..ops.unique import (
     unique_first_occurrence,
 )
 from ..sampler.base import NegativeSampling, SamplerOutput
-from ..sampler.neighbor_sampler import hop_widths, max_sampled_nodes
+from ..sampler.neighbor_sampler import (hop_bounds, hop_widths,
+                                        max_sampled_nodes)
 from ..typing import PADDING_ID
 
 # Host-boundary instrumentation; the shard_map program itself is traced
@@ -1329,6 +1330,8 @@ class DistNeighborSampler:
         self.node_capacity = max_sampled_nodes(self.batch_size,
                                                self.num_neighbors,
                                                frontier_cap)
+        self.hop_bounds = hop_bounds(self.batch_size, self.num_neighbors,
+                                     frontier_cap)
 
         g = self.g
         gspec = P(axis_name)

@@ -23,7 +23,8 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models.train import TrainState, seed_cross_entropy
+from ..models.train import TrainState, hop_trimming, seed_cross_entropy
+from ..sampler.neighbor_sampler import hop_bounds
 from ..typing import PADDING_ID
 from ..ops.unique import unique_first_occurrence
 from .dist_feature import (
@@ -177,6 +178,10 @@ def make_dist_train_step(
     ``last_hop_dedup=False`` selects the leaf-block final hop (see
     NeighborSampler) — loss/acc are over seed rows, which stay in the
     compact interior prefix, so the objective is unchanged.
+    A model that trims by the hop-block layout (``GraphSAGE``) runs each
+    layer over ``hop_bounds(batch_size, num_neighbors, frontier_cap)``
+    only (:func:`~glt_tpu.models.train.hop_trimming`): the same seed
+    logits, loss and gradients from fewer edge slots.
     ``exchange_load_factor`` bounds the sampler's all-to-all buckets (see
     :func:`~glt_tpu.parallel.dist_sampler.dist_sample_multi_hop`).
     ``dedup_gather`` routes unique node ids through the feature/label
@@ -214,6 +219,8 @@ def make_dist_train_step(
         frontier_cap, f.rows.shape[-1], axis_name, mesh_shape,
         route=route, hier_load_factor=hier_load_factor)
     record_bytes = _byte_counters(byte_model)
+    trim = hop_trimming(
+        model, hop_bounds(batch_size, num_neighbors, frontier_cap))
 
     def local_body(indptr, indices, edge_ids, rows, labels_blk, seeds,
                    params, key):
@@ -240,8 +247,9 @@ def make_dist_train_step(
 
         def loss_fn(p):
             logits = model.apply(p, x, edge_index, out.edge_mask,
-                                 train=True, rngs={"dropout": key})
-            return seed_cross_entropy(logits, y, batch_size, out.node_mask)
+                                 train=True, rngs={"dropout": key}, **trim)
+            return seed_cross_entropy(logits, y, batch_size, out.node_mask,
+                                      out.num_sampled_nodes[0])
 
         (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params)
@@ -320,7 +328,8 @@ def make_scanned_dist_train_step(
     INSIDE one ``shard_map`` program, so intermediate ids and the
     updated replicated state never round-trip through host dispatch
     between batches: the per-batch dispatch + state re-feed of the
-    serial dist step is paid once per ``G``.
+    serial dist step is paid once per ``G``.  The model is trimmed to
+    the hop-block layout as in the serial step.
 
     Returns ``step(state, seeds_blk [G, S, B], key) -> (state,
     losses [G], accs [G])``.  Per-slot keys follow the homo scan
@@ -352,6 +361,8 @@ def make_scanned_dist_train_step(
         frontier_cap, f.rows.shape[-1], axis_name, mesh_shape,
         route=route, hier_load_factor=hier_load_factor)
     record_bytes = _byte_counters(byte_model)
+    trim = hop_trimming(
+        model, hop_bounds(batch_size, num_neighbors, frontier_cap))
 
     def local_body(indptr, indices, edge_ids, rows, labels_blk,
                    seeds_blk, state: TrainState, keys):
@@ -380,9 +391,11 @@ def make_scanned_dist_train_step(
 
             def loss_fn(p):
                 logits = model.apply(p, x, edge_index, out.edge_mask,
-                                     train=True, rngs={"dropout": key})
+                                     train=True, rngs={"dropout": key},
+                                     **trim)
                 return seed_cross_entropy(logits, y, batch_size,
-                                          out.node_mask)
+                                          out.node_mask,
+                                          out.num_sampled_nodes[0])
 
             (loss, acc), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(st.params)

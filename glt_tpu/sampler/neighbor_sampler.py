@@ -29,7 +29,7 @@ Key design points:
 from __future__ import annotations
 
 from functools import partial
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -84,6 +84,43 @@ def max_sampled_nodes(batch_size: int, fanouts: Sequence[int],
     """Padded node capacity (cf. ``_max_sampled_nodes``, neighbor_sampler.py:595)."""
     widths = hop_widths(batch_size, fanouts, frontier_cap)
     return widths[0] + sum(w * f for w, f in zip(widths, fanouts))
+
+
+class HopBounds(NamedTuple):
+    """Static hop-block layout of a sampled batch (see :func:`hop_bounds`)."""
+    node_bounds: Tuple[int, ...]
+    edge_bounds: Tuple[int, ...]
+
+
+def hop_bounds(batch_size: int, fanouts: Sequence[int],
+               frontier_cap: Optional[int] = None,
+               node_capacity: Optional[int] = None) -> HopBounds:
+    """Cumulative static bounds of the hop blocks, hops ``0..len(fanouts)``.
+
+    ``edge_bounds[k]`` is the number of edge slots of hops ``1..k`` (hop
+    blocks are concatenated in order, so hops ``1..k`` are the prefix
+    ``[:edge_bounds[k]]``); ``node_bounds[k]`` bounds the rows of the node
+    buffer that can hold a node first seen by hop ``k`` (first-occurrence
+    order, seeds first), clamped to ``node_capacity``.  Every valid edge
+    of hop block ``k`` has ``col < node_bounds[k-1]`` and
+    ``row < node_bounds[k]``: a node is expanded once, at the hop after
+    it was first seen, and its neighbours are appended behind everything
+    seen before.  Holds for both ``dedup`` strategies, the leaf block of
+    ``last_hop_dedup=False`` (it ends at the capacity), a ``frontier_cap``
+    (an unexpanded node has no in-edges) and an occupancy capacity
+    (overflow edges are masked) — tests/test_neighbor_sampler.py and
+    tests/test_dist_train.py hold the samplers to it, because
+    :class:`~glt_tpu.models.sage.GraphSAGE` trims its layers by it.
+    """
+    widths = hop_widths(batch_size, fanouts, frontier_cap)
+    edges = [0]
+    for w, f in zip(widths, fanouts):
+        edges.append(edges[-1] + w * f)
+    cap = batch_size + edges[-1]
+    if node_capacity is not None:
+        cap = min(cap, int(node_capacity))
+    return HopBounds(tuple(min(batch_size + e, cap) for e in edges),
+                     tuple(edges))
 
 
 def measure_occupancy(sampler: "NeighborSampler", seed_batches) -> np.ndarray:
@@ -226,6 +263,8 @@ class NeighborSampler(BaseSampler):
         self.capped = self.node_capacity < self.full_node_capacity
         self.edge_capacity = sum(
             w * f for w, f in zip(self._widths, self.num_neighbors))
+        self.hop_bounds = hop_bounds(self.batch_size, self.num_neighbors,
+                                     frontier_cap, self.node_capacity)
 
         self._sample_jit = jax.jit(self._sample_impl)
         self._sample_many_jit = {}

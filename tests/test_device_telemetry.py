@@ -7,6 +7,7 @@ leak watch rides the ``jax.live_arrays()`` fallback, the triggered
 captures produce REAL ``jax.profiler`` traces on disk, and the compile
 watch counts actual backend compilations through ``jax.monitoring``.
 """
+import gc
 import json
 import os
 
@@ -67,6 +68,10 @@ class TestDeviceStats:
         assert device.peak_bytes_in_use() is None
 
     def test_live_bytes_fallback_counts_arrays(self):
+        # Earlier tests' arrays held in reference cycles must not be freed
+        # between the two readings (it depends on where the collector's
+        # counters stand, so on how many tests the worker has seen).
+        gc.collect()
         base = device.live_bytes()
         keep = jnp.zeros((256, 8), jnp.float32)
         jax.block_until_ready(keep)

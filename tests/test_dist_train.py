@@ -277,3 +277,109 @@ def test_hetero_tiered_pipeline_loss_drops():
     assert losses[-1] < losses[0] * 0.6, (losses[0], losses[-1])
     assert pipe.flush_dropped() == 0
     pipe.close()
+
+
+# -- per-layer trimming by the hop-block layout, distributed ----------------
+def _random_sharded(n=512, dim=8, classes=4, seed=0):
+    rng = np.random.default_rng(seed)
+    src = np.repeat(np.arange(n), rng.integers(0, 9, n))
+    topo = CSRTopo(np.stack([src, rng.integers(0, n, src.shape[0])]),
+                   num_nodes=n)
+    feat = rng.normal(size=(n, dim)).astype(np.float32)
+    labels = rng.integers(0, classes, n).astype(np.int32)
+    g = shard_graph(topo, N_DEV)
+    return (g, shard_feature(feat, N_DEV),
+            jnp.asarray(labels.reshape(N_DEV, g.nodes_per_shard)))
+
+
+def _dist_seeds(g, bs, it, pad_shard=None):
+    seeds = np.stack([
+        s * g.nodes_per_shard + np.random.default_rng(it * N_DEV + s).choice(
+            g.nodes_per_shard, bs, replace=False)
+        for s in range(N_DEV)]).astype(np.int32)
+    if pad_shard is not None:
+        seeds[pad_shard, bs // 2:] = -1
+    return seeds
+
+
+@pytest.mark.parametrize("variant", [
+    {}, {"frontier_cap": 8}, {"exchange_load_factor": 1.0},
+    {"collective": "ring"}])
+@pytest.mark.parametrize("lhd", [True, False])
+def test_dist_hop_blocks_keep_their_static_bounds(lhd, variant):
+    """The layout ``GraphSAGE`` trims by, held by the dist sampler on every
+    shard (see tests/test_neighbor_sampler.py for the one-chip sampler)."""
+    from glt_tpu.parallel import DistNeighborSampler
+    from tests.test_neighbor_sampler import assert_hop_layout
+
+    mesh = Mesh(np.array(jax.devices()[:N_DEV]), ("shard",))
+    g, _, _ = _random_sharded()
+    bs = 4
+    s = DistNeighborSampler(g, mesh, num_neighbors=[3, 3, 2], batch_size=bs,
+                            last_hop_dedup=lhd, **variant)
+    assert s.hop_bounds.node_bounds[-1] == s.node_capacity
+    for it in range(2):
+        out = s.sample_from_nodes(jnp.asarray(_dist_seeds(g, bs, it,
+                                                          pad_shard=it)))
+        for shard in range(N_DEV):
+            assert_hop_layout(jax.tree.map(lambda a: a[shard], out),
+                              s.hop_bounds)
+
+
+@pytest.mark.parametrize("scanned", [False, True])
+def test_dist_train_step_trims_and_equals_whole_steps(scanned):
+    """N steps of ``make_dist_train_step`` / ``make_scanned_dist_train_step``
+    (trimmed by the layout of their own arguments) against the same factory
+    driving the whole model: losses and parameters, dropout off; and the
+    gauges of the layout."""
+    from glt_tpu import obs
+    from glt_tpu.parallel import make_scanned_dist_train_step
+    from tests.test_models import Whole
+
+    mesh = Mesh(np.array(jax.devices()[:N_DEV]), ("shard",))
+    g, f, lab = _random_sharded()
+    model = GraphSAGE(hidden_features=16, out_features=4, num_layers=3,
+                      dropout_rate=0.0)
+    tx = optax.adam(1e-2)
+    bs, fanouts, steps = 4, [3, 2, 2], 3
+    make = make_scanned_dist_train_step if scanned else make_dist_train_step
+
+    obs.metrics.reset()
+    obs.metrics.enable()
+    try:
+        trimmed = make(model, tx, g, f, lab, mesh, fanouts, bs)
+        snap = obs.metrics.snapshot()
+    finally:
+        obs.metrics.disable()
+        obs.metrics.reset()
+    assert snap["glt.model.edge_slots"] == 12 + 24 + 48
+    assert snap["glt.model.node_rows"] == 4 + 12 + 24 + 48
+    assert [snap["glt.model.layer_edge_slots{layer=%d}" % l]
+            for l in (1, 2, 3)] == [84, 36, 12]
+    assert [snap["glt.model.layer_node_rows{layer=%d}" % l]
+            for l in (1, 2, 3)] == [40, 16, 4]
+    whole = make(Whole(model), tx, g, f, lab, mesh, fanouts, bs)
+
+    # Shard 1's batch is half padding in every step.
+    seeds = np.stack([_dist_seeds(g, bs, it, pad_shard=1)
+                      for it in range(steps)])
+    results = []
+    for step in (trimmed, whole):
+        state = init_dist_state(model, tx, g, f, jax.random.PRNGKey(0),
+                                fanouts, bs)
+        if scanned:
+            state, losses, _ = step(state, jnp.asarray(seeds),
+                                    jax.random.PRNGKey(7))
+        else:
+            losses = []
+            for it in range(steps):
+                state, loss, _ = step(state, jnp.asarray(seeds[it]),
+                                      jax.random.PRNGKey(it))
+                losses.append(loss)
+        assert int(state.step) == steps
+        results.append((np.asarray(jnp.stack(list(losses))), state.params))
+    (loss_t, p_t), (loss_w, p_w) = results
+    np.testing.assert_allclose(loss_t, loss_w, rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(p_t),
+                    jax.tree_util.tree_leaves(p_w)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)

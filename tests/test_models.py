@@ -4,11 +4,14 @@ The E2E test is the framework's minimum end-to-end slice (SURVEY §7 stage
 5): NeighborLoader feeding a jitted GraphSAGE train step, loss must drop on
 a learnable synthetic task.
 """
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import optax
 import pytest
+from flax import linen as nn
 
 from glt_tpu.data import CSRTopo, Dataset
 from glt_tpu.loader import NeighborLoader
@@ -474,3 +477,237 @@ def test_run_scanned_epoch_driver():
         m_losses += [float(x) for x in np.asarray(ls)]
     np.testing.assert_allclose(losses, np.asarray(m_losses[:3]),
                                rtol=1e-6)
+
+
+# -- per-layer trimming by the sampler's hop-block layout -------------------
+@functools.lru_cache(maxsize=None)
+def _hop_graph():
+    from tests.test_neighbor_sampler import hop_graph
+    return hop_graph()
+
+
+@functools.lru_cache(maxsize=None)
+def _hop_batch(dedup, lhd, variant):
+    """One sampled batch of a sampler variant with random features and
+    labels: ``(sampler, out, x, y)``, made once for the three depths."""
+    from tests.test_neighbor_sampler import hop_sample, hop_sampler
+
+    s = hop_sampler(_hop_graph(), dedup, lhd, variant)
+    out = hop_sample(s, variant)
+    rng = np.random.default_rng(1)
+    node = np.asarray(out.node)
+    x = np.where((node >= 0)[:, None],
+                 rng.normal(size=(node.shape[0], 12)), 0)
+    y = np.where(node >= 0, rng.integers(0, 5, node.shape[0]), -1)
+    return s, out, jnp.asarray(x, jnp.float32), jnp.asarray(y)
+
+
+@pytest.mark.parametrize("layers", [2, 3, 4])
+@pytest.mark.parametrize("variant", ["uncapped", "frontier_cap",
+                                     "occupancy_overflow", "padded_seeds"])
+@pytest.mark.parametrize("lhd", [True, False])
+@pytest.mark.parametrize("dedup", ["dense", "sort"])
+def test_trimmed_sage_is_the_whole_sage_on_the_seeds(dedup, lhd, variant,
+                                                     layers):
+    """GraphSAGE(hops=sampler.hop_bounds) against the whole model: seed
+    logits, loss and every parameter gradient, dropout off."""
+    from glt_tpu.models import seed_cross_entropy
+
+    s, out, x, y = _hop_batch(dedup, lhd, variant)
+    bs = s.batch_size
+    ei = jnp.stack([out.row, out.col])
+    model = GraphSAGE(hidden_features=16, out_features=5,
+                      num_layers=layers, dropout_rate=0.0)
+    params = model.init(jax.random.PRNGKey(0), x, ei, out.edge_mask)
+    num_seeds = out.num_sampled_nodes[0]
+
+    def loss_fn(p, hops):
+        logits = model.apply(p, x, ei, out.edge_mask, train=True, hops=hops)
+        loss, _ = seed_cross_entropy(logits, y, bs, out.node_mask, num_seeds)
+        return loss, logits
+
+    (l_whole, lg_whole), g_whole = jax.value_and_grad(
+        loss_fn, has_aux=True)(params, None)
+    (l_trim, lg_trim), g_trim = jax.value_and_grad(
+        loss_fn, has_aux=True)(params, s.hop_bounds)
+    assert lg_whole.shape == (s.node_capacity, 5)
+    assert lg_trim.shape == (bs, 5)
+    n = int(num_seeds)
+    assert n == (4 if variant == "padded_seeds" else bs)
+    np.testing.assert_allclose(lg_trim[:n], lg_whole[:n], rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(l_trim, l_whole, rtol=1e-5)
+    flat_w = jax.tree_util.tree_leaves_with_path(g_whole)
+    flat_t = jax.tree_util.tree_leaves_with_path(g_trim)
+    assert [k for k, _ in flat_w] == [k for k, _ in flat_t]
+    for (path, a), (_, b) in zip(flat_t, flat_w):
+        assert np.abs(np.asarray(b)).max() > 0, path
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7,
+                                   err_msg=str(path))
+
+
+def test_sage_hops_must_be_the_batchs_own_layout():
+    from glt_tpu.sampler import hop_bounds
+
+    model = GraphSAGE(hidden_features=8, out_features=3, num_layers=2)
+    x, ei = jnp.ones((10, 4)), jnp.array([[1, 2, -1], [0, 0, -1]])
+    params = model.init(jax.random.PRNGKey(0), x, ei, ei[0] >= 0)
+    with pytest.raises(ValueError, match="not laid out"):
+        model.apply(params, x, ei, ei[0] >= 0, hops=hop_bounds(2, [2, 2]))
+
+
+class _SeedSAGEConv(nn.Module):
+    """``SAGEConv`` as it stood before ``num_dst`` (commit f209a76)."""
+    out_features: int
+    use_bias: bool = True
+    dtype: object = None
+
+    @nn.compact
+    def __call__(self, x, edge_index, edge_mask):
+        from glt_tpu.models.conv import _mm_dtype
+
+        num_nodes = x.shape[0]
+        src, dst = edge_index[0], edge_index[1]
+        with jax.named_scope("glt.model.msg"):
+            msgs = jnp.take(x, jnp.clip(src, 0, num_nodes - 1), axis=0)
+        agg = scatter_mean(msgs, dst, num_nodes, edge_mask)
+        dt = _mm_dtype(self.dtype)
+        with jax.named_scope("glt.model.dense"):
+            out = (nn.Dense(self.out_features, use_bias=self.use_bias,
+                            dtype=dt, name="lin_self")(x)
+                   + nn.Dense(self.out_features, use_bias=False,
+                              dtype=dt, name="lin_nbr")(agg))
+            return out if dt is None else out.astype(jnp.float32)
+
+
+class _SeedGraphSAGE(nn.Module):
+    """``GraphSAGE`` as it stood before ``hops`` (commit f209a76)."""
+    hidden_features: int
+    out_features: int
+    num_layers: int = 3
+    dropout_rate: float = 0.5
+    dtype: object = None
+
+    @nn.compact
+    def __call__(self, x, edge_index, edge_mask, *, train: bool = False):
+        for i in range(self.num_layers):
+            last = i == self.num_layers - 1
+            dim = self.out_features if last else self.hidden_features
+            x = _SeedSAGEConv(dim, dtype=self.dtype,
+                              name=f"conv{i}")(x, edge_index, edge_mask)
+            if not last:
+                with jax.named_scope("glt.model.dense"):
+                    x = nn.relu(x)
+                    x = nn.Dropout(self.dropout_rate,
+                                   deterministic=not train)(x)
+        return x
+
+
+@pytest.mark.parametrize("which", ["conv", "sage-train", "sage-eval-bf16"])
+def test_sage_called_as_before_compiles_to_the_seeds_program(which):
+    """``SAGEConv(num_dst=None)`` and ``GraphSAGE(hops=None)`` against
+    copies of the seed's modules: forward and backward differ in metadata
+    only (the comparison of tests/test_obs_scopes.py)."""
+    from glt_tpu.models import SAGEConv
+    from tests.test_obs_scopes import _without_debug_info
+
+    s, out, x, y = _hop_batch("dense", True, "uncapped")
+    ei = jnp.stack([out.row, out.col])
+    if which == "conv":
+        new, old, kw = SAGEConv(7), _SeedSAGEConv(7), {}
+    else:
+        cfg = dict(hidden_features=16, out_features=5, num_layers=3)
+        if which == "sage-eval-bf16":
+            cfg["dtype"], kw = jnp.bfloat16, {}
+        else:
+            kw = {"train": True, "rngs": {"dropout": jax.random.PRNGKey(1)}}
+        new, old = GraphSAGE(**cfg), _SeedGraphSAGE(**cfg)
+    params = new.init(jax.random.PRNGKey(0), x, ei, out.edge_mask)
+
+    def text(module):
+        def f(p, x):
+            return jax.value_and_grad(lambda p: jnp.sum(
+                module.apply(p, x, ei, out.edge_mask, **kw) ** 2))(p)
+        return _without_debug_info(
+            jax.jit(f).lower(params, x).compile().as_text())
+
+    assert text(new) == text(old)
+
+
+class Whole:
+    """A model without ``layer_extents``: the step factories run it whole."""
+    def __init__(self, model):
+        self.apply = model.apply
+
+
+def test_scanned_node_step_trims_and_equals_whole_steps():
+    """N batches through ``make_scanned_node_train_step`` (trimmed by its
+    sampler's layout) against the same factory driving the whole model:
+    losses and parameters, dropout off; and the gauges of the layout."""
+    from glt_tpu import obs
+    from glt_tpu.models import TrainState, make_scanned_node_train_step
+    from glt_tpu.sampler import NeighborSampler
+
+    ds, labels = _cluster_dataset()
+    model = GraphSAGE(hidden_features=16, out_features=3, num_layers=3,
+                      dropout_rate=0.0)
+    tx = optax.adam(1e-2)
+    bs, G = 8, 5
+    sampler = NeighborSampler(ds.get_graph(), [3, 2, 2], batch_size=bs,
+                              with_edge=False)
+    feat = ds.get_node_feature()
+    params = model.init(
+        {"params": jax.random.PRNGKey(0)},
+        jnp.zeros((sampler.node_capacity, feat.shape[1]), jnp.float32),
+        jnp.full((2, sampler.edge_capacity), -1, jnp.int32),
+        jnp.zeros((sampler.edge_capacity,), bool))
+
+    def fresh_state():
+        return TrainState(params=params, opt_state=tx.init(params),
+                          step=jnp.zeros((), jnp.int32))
+
+    # 37 seeds in 5 batches of 8: the last is partly padded.
+    block = np.full((G, bs), -1, np.int32)
+    block.reshape(-1)[:37] = np.random.default_rng(0).permutation(48)[:37]
+    key = jax.random.PRNGKey(3)
+
+    obs.metrics.reset()
+    obs.metrics.enable()
+    try:
+        trimmed = make_scanned_node_train_step(model, tx, sampler, feat,
+                                               labels, bs)
+        snap = obs.metrics.snapshot()
+    finally:
+        obs.metrics.disable()
+    assert sampler.hop_bounds.edge_bounds == (0, 24, 72, 168)
+    assert sampler.hop_bounds.node_bounds == (8, 32, 80, 176)
+    assert snap["glt.model.edge_slots"] == 168
+    assert snap["glt.model.node_rows"] == 176
+    assert [snap["glt.model.layer_edge_slots{layer=%d}" % l]
+            for l in (1, 2, 3)] == [168, 72, 24]
+    assert [snap["glt.model.layer_node_rows{layer=%d}" % l]
+            for l in (1, 2, 3)] == [80, 32, 8]
+
+    obs.metrics.reset()
+    obs.metrics.enable()
+    try:
+        whole = make_scanned_node_train_step(Whole(model), tx, sampler,
+                                             feat, labels, bs)
+        assert obs.metrics.snapshot()["glt.model.edge_slots"] == 0
+    finally:
+        obs.metrics.disable()
+        obs.metrics.reset()
+
+    st_t, loss_t, acc_t, _ = trimmed(fresh_state(), block, key)
+    st_w, loss_w, acc_w, _ = whole(fresh_state(), block, key)
+    assert int(st_t.step) == int(st_w.step) == G
+    np.testing.assert_allclose(loss_t, loss_w, rtol=1e-5)
+    np.testing.assert_array_equal(acc_t, acc_w)
+    for a, b in zip(jax.tree_util.tree_leaves(st_t.params),
+                    jax.tree_util.tree_leaves(st_w.params)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+    # The trimmed program carries the trimmed shapes, the whole one not.
+    hlo_t = jax.jit(trimmed).lower(fresh_state(), block, key).as_text()
+    hlo_w = jax.jit(whole).lower(fresh_state(), block, key).as_text()
+    assert "tensor<72x16xf32>" in hlo_t and "tensor<24x16xf32>" in hlo_t
+    assert "tensor<72x16xf32>" not in hlo_w

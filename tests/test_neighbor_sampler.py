@@ -289,3 +289,89 @@ class TestDedupStrategies:
                 np.testing.assert_array_equal(
                     np.asarray(getattr(outs, field))[i],
                     np.asarray(getattr(single, field)), err_msg=field)
+
+
+# -- the hop-block layout GraphSAGE trims its layers by ---------------------
+#: Sampler variants that share the model, as ``NeighborSampler`` keywords
+#: given ``last_hop_dedup`` (batch 8, fanout [3, 3, 2]: 248 rows uncapped).
+#: Without a frontier cap the leaf block alone fills an occupancy capacity
+#: up to the full one, so that case caps the frontier too.
+HOP_VARIANTS = {
+    "uncapped": lambda lhd: {},
+    "frontier_cap": lambda lhd: {"frontier_cap": 16},
+    "occupancy_overflow": lambda lhd: (
+        {"node_capacity": 104} if lhd
+        else {"frontier_cap": 16, "node_capacity": 76}),
+    "padded_seeds": lambda lhd: {},
+}
+HOP_BATCH, HOP_FANOUT = 8, [3, 3, 2]
+
+
+def hop_graph(n=2000, seed=0):
+    """Degrees 0..12, so some frontier nodes have fewer neighbours than
+    the fanout (masked slots) and some none."""
+    rng = np.random.default_rng(seed)
+    src = np.repeat(np.arange(n), rng.integers(0, 13, n))
+    return Graph(CSRTopo(np.stack([src, rng.integers(0, n, src.shape[0])]),
+                         num_nodes=n), mode="HOST")
+
+
+def hop_sampler(graph, dedup, lhd, variant):
+    return NeighborSampler(graph, HOP_FANOUT, batch_size=HOP_BATCH,
+                           dedup=dedup, last_hop_dedup=lhd, with_edge=False,
+                           **HOP_VARIANTS[variant](lhd))
+
+
+def hop_sample(sampler, variant, seed=0):
+    """One batch of ``variant`` out of ``sampler``."""
+    seeds = np.random.default_rng(seed).choice(
+        sampler.graph.num_nodes, HOP_BATCH, replace=False)
+    if variant == "padded_seeds":
+        seeds[4], seeds[5:] = seeds[0], -1      # a duplicate, three pads
+    out = sampler.sample_from_nodes(NodeSamplerInput(seeds))
+    if variant == "occupancy_overflow":
+        assert bool(out.metadata["overflow"])
+    return out
+
+
+def assert_hop_layout(out, bounds):
+    """Every valid edge of hop block ``k`` has ``col < node_bounds[k-1]``
+    and ``row < node_bounds[k]``; the blocks tile the edge slots."""
+    nb, eb = bounds.node_bounds, bounds.edge_bounds
+    row, col = np.asarray(out.row), np.asarray(out.col)
+    mask = np.asarray(out.edge_mask)
+    assert eb[0] == 0 and eb[-1] == row.shape[0]
+    assert nb[-1] == np.asarray(out.node).shape[0]
+    assert mask.any()
+    for k in range(1, len(eb)):
+        blk = slice(eb[k - 1], eb[k])
+        m = mask[blk]
+        assert (col[blk][m] >= 0).all() and (row[blk][m] >= 0).all()
+        assert (col[blk][m] < nb[k - 1]).all(), k
+        assert (row[blk][m] < nb[k]).all(), k
+        # the block is hop k's: as many valid edges as the sampler counted
+        assert m.sum() == int(np.asarray(out.num_sampled_edges)[k - 1])
+
+
+def test_hop_bounds_of_the_products_shape():
+    from glt_tpu.sampler import hop_bounds
+
+    b = hop_bounds(1024, [15, 10, 5], None, 402944)
+    assert b.node_bounds == (1024, 16384, 169984, 402944)
+    assert b.edge_bounds == (0, 15360, 168960, 936960)
+    assert hop_bounds(1024, [15, 10, 5]).node_bounds[-1] == 937984
+    capped = hop_bounds(1024, [15, 10, 5], 8192)
+    assert capped.node_bounds == (1024, 16384, 98304, 139264)
+    assert hash(b) != hash(capped)          # a static argument of a trace
+
+
+@pytest.mark.parametrize("variant", sorted(HOP_VARIANTS))
+@pytest.mark.parametrize("lhd", [True, False])
+@pytest.mark.parametrize("dedup", ["dense", "sort"])
+def test_hop_blocks_keep_their_static_bounds(dedup, lhd, variant):
+    """What per-layer trimming stands on (models/sage.py): a sampler
+    change that breaks the layout fails here, not in a loss."""
+    s = hop_sampler(hop_graph(), dedup, lhd, variant)
+    assert s.hop_bounds.node_bounds[-1] == s.node_capacity
+    for seed in range(3):
+        assert_hop_layout(hop_sample(s, variant, seed), s.hop_bounds)
