@@ -187,7 +187,9 @@ def igbh_from_disk(name: str = "igbh-tiny", graph_mode: str = "HOST"):
 
 def synthetic_igbh(scale: float = 1.0, seed: int = 0,
                    graph_mode: str = "DEVICE", use_real: bool = False):
-    """IGBH-tiny-shaped hetero graph: paper/author/institute.
+    """IGBH-tiny-shaped hetero graph: IGBH's four node types (paper,
+    author, institute, fos) and seven relations, named as upstream's
+    ``examples/igbh/dataset.py`` names them.
 
     With ``use_real=True``, loads a converted real IGBH from
     ``DATA_ROOT/igbh-tiny`` (scripts/convert_ogb.py) — honoring the
@@ -203,10 +205,12 @@ def synthetic_igbh(scale: float = 1.0, seed: int = 0,
                 f"{DATA_ROOT}/igbh-tiny (run scripts/convert_ogb.py)")
         return real
     return _synthetic_citation_hetero(
-        {"paper": (200, 1000), "author": (150, 800), "institute": (20, 80)},
+        {"paper": (200, 1000), "author": (150, 800), "institute": (20, 80),
+         "fos": (30, 120)},
         [("paper", "cites", "paper", 4, None),
-         ("author", "writes", "paper", 3, "rev_writes"),
-         ("author", "affiliated", "institute", 1, "rev_affiliated")],
+         ("paper", "written_by", "author", 3, "rev_written_by"),
+         ("author", "affiliated_to", "institute", 1, "rev_affiliated_to"),
+         ("paper", "topic", "fos", 2, "rev_topic")],
         scale, seed, graph_mode)
 
 

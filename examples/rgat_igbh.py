@@ -1,8 +1,11 @@
 """R-GAT on a (synthetic) IGBH-shaped heterogeneous graph.
 
 TPU rebuild of the reference's examples/igbh R-GAT training: hetero
-neighbor sampling over paper/author/institute types, HeteroConv R-GAT,
-paper-node classification.
+neighbor sampling over IGBH's four node types and seven relations,
+upstream's ``RGNN('rgat')`` (``--hidden 512 --heads 4 --layers 3 --fanout
+15,10,5`` are its defaults), paper-node classification.  The scanned
+path (``--group``, the default) sizes its sampler by calibration and
+reports overflowed batches beside loss and accuracy.
 """
 import argparse
 import sys
@@ -17,7 +20,7 @@ import optax
 
 from examples.datasets import synthetic_igbh
 from glt_tpu.loader.hetero_neighbor_loader import HeteroNeighborLoader
-from glt_tpu.models.rgat import RGAT
+from glt_tpu.models.rgat import RGNN
 from glt_tpu.typing import reverse_edge_type
 
 
@@ -70,13 +73,13 @@ def run_distributed(args):
             f"least one shard without any seeds; use fewer devices or a "
             f"larger --scale")
     bs = min(args.batch_size, min(len(o) for o in owned))
-    sampler = DistHeteroNeighborSampler(sharded, mesh, [4, 4], "paper",
+    # frontier_cap CUTS the sampling semantics (nodes past the cap are
+    # never expanded, silently): the distributed sampler has neither the
+    # exact clamp nor calibrated capacities yet.
+    sampler = DistHeteroNeighborSampler(sharded, mesh, args.fanout, "paper",
                                         batch_size=bs, frontier_cap=512,
                                         seed=0)
-    batch_ets = [reverse_edge_type(et) for et in ds.get_edge_types()]
-    model = RGAT(edge_types=batch_ets, hidden_features=32,
-                 out_features=classes, target_type="paper", num_layers=2,
-                 conv="gat", dropout_rate=0.0)
+    model = build_model(args, ds, classes)
     tx = optax.adam(5e-3)
     state = init_hetero_dist_state(model, tx, sampler, feats,
                                    jax.random.PRNGKey(0))
@@ -102,6 +105,15 @@ def run_distributed(args):
               f"time={dt:.2f}s")
 
 
+def build_model(args, ds, classes):
+    """Upstream's ``RGNN('rgat')`` over the batch's (reversed) edge types."""
+    batch_ets = [reverse_edge_type(et) for et in ds.get_edge_types()]
+    return RGNN(batch_ets, hidden_features=args.hidden, out_features=classes,
+                target_type="paper", num_layers=args.layers,
+                heads=args.heads, dropout_rate=args.dropout,
+                dtype=jnp.bfloat16 if args.bf16 else None)
+
+
 def main():
     from glt_tpu.utils import enable_compile_cache
 
@@ -122,6 +134,13 @@ def main():
     ap.add_argument("--group", type=int, default=8,
                     help="scan G batches per program (0 = eager loader)")
     ap.add_argument("--bf16", action="store_true")
+    ap.add_argument("--hidden", type=int, default=512)
+    ap.add_argument("--heads", type=int, default=4)
+    ap.add_argument("--layers", type=int, default=3)
+    ap.add_argument("--dropout", type=float, default=0.2)
+    ap.add_argument("--fanout", type=lambda s: [int(f) for f in s.split(",")],
+                    default=[15, 10, 5],
+                    help="per-hop fanout of every relation, e.g. 15,10,5")
     ap.add_argument("--data-root", default=None,
                     help="dir holding a converted IGBH "
                          "(scripts/convert_ogb.py igbh); overrides "
@@ -137,11 +156,7 @@ def main():
 
     ds, train_idx, classes = synthetic_igbh(scale=args.scale, use_real=args.use_real)
 
-    batch_ets = [reverse_edge_type(et) for et in ds.get_edge_types()]
-    model = RGAT(edge_types=batch_ets, hidden_features=32,
-                 out_features=classes, target_type="paper", num_layers=2,
-                 conv="gat", dropout_rate=0.0,
-                 dtype=jax.numpy.bfloat16 if args.bf16 else None)
+    model = build_model(args, ds, classes)
 
     if args.group > 0:
         from glt_tpu.models import (
@@ -151,11 +166,23 @@ def main():
         )
         from glt_tpu.sampler.hetero_neighbor_sampler import (
             HeteroNeighborSampler,
+            calibrate_hetero_node_capacity,
         )
 
-        sampler = HeteroNeighborSampler(ds.graph, [4, 4], "paper",
+        sampler = HeteroNeighborSampler(ds.graph, args.fanout, "paper",
                                         batch_size=args.batch_size,
                                         seed=0)
+        # Occupancy-sized buffers and frontiers (pct 99 held jointly,
+        # margin 1.05, 24 batches): a batch past them is flagged, never
+        # cut silently.
+        rng = np.random.default_rng(42)
+        probe = [rng.choice(train_idx, min(args.batch_size, len(train_idx)),
+                            replace=False) for _ in range(24)]
+        caps, fronts = calibrate_hetero_node_capacity(sampler, probe)
+        print(f"node capacity {caps} of {sampler.node_capacity}")
+        sampler = HeteroNeighborSampler(
+            ds.graph, args.fanout, "paper", batch_size=args.batch_size,
+            seed=0, node_capacity=caps, frontier_capacity=fronts)
         feats = {t: ds.get_node_feature(t)
                  for t in ds.get_node_types()}
         labels = {"paper": np.asarray(ds.node_labels["paper"])}
@@ -163,19 +190,21 @@ def main():
         state = init_hetero_state(model, tx, sampler, feats,
                                   jax.random.PRNGKey(0))
         sstep = make_scanned_hetero_train_step(
-            model, tx, sampler, feats, labels, args.batch_size)
+            model, tx, sampler, feats, labels, args.batch_size,
+            seed_hops=True)
         rng = np.random.default_rng(0)
         for epoch in range(args.epochs):
             t0 = time.perf_counter()
-            state, losses, accs, _ = run_scanned_epoch(
+            state, losses, accs, ovf = run_scanned_epoch(
                 sstep, state, train_idx, args.batch_size, args.group,
                 rng, jax.random.PRNGKey(100 + epoch))
             dt = time.perf_counter() - t0
             print(f"epoch {epoch}: loss={float(np.mean(losses)):.4f} "
-                  f"acc={float(np.mean(accs)):.4f} time={dt:.2f}s")
+                  f"acc={float(np.mean(accs)):.4f} "
+                  f"overflowed={ovf}/{len(losses)} time={dt:.2f}s")
         return
 
-    loader = HeteroNeighborLoader(ds, [4, 4], ("paper", train_idx),
+    loader = HeteroNeighborLoader(ds, args.fanout, ("paper", train_idx),
                                   batch_size=args.batch_size, shuffle=True)
 
     first = next(iter(loader))

@@ -1,7 +1,7 @@
 from .conv import GATConv, SAGEConv, scatter_mean, scatter_sum, segment_softmax
 from .gat import GAT
 from .hgt import HGT, HGTConv
-from .rgat import RGAT, HeteroConv
+from .rgat import RGAT, RGNN, HeteroConv
 from .sage import GraphSAGE
 from .train import (
     TrainState,
@@ -29,6 +29,7 @@ __all__ = [
     "HGTConv",
     "HeteroConv",
     "RGAT",
+    "RGNN",
     "SAGEConv",
     "TrainState",
     "create_train_state",

@@ -120,7 +120,16 @@ class SAGEConv(nn.Module):
 
 
 class GATConv(nn.Module):
-    """Graph attention convolution (GATv1, multi-head, concat)."""
+    """Graph attention convolution (GATv1, multi-head, concat).
+
+    ``x`` is one array (every row is a source and a destination) or a
+    pair ``(x_src, x_dst)`` — PyG's bipartite form, the one a typed
+    relation needs: the one ``lin`` projects each side, ``alpha_src``
+    comes from the source projection and ``alpha_dst`` from the
+    destination's, the softmax runs over each destination's incoming
+    edges, and the result has ``x_dst.shape[0]`` rows.  ``edge_index[0]``
+    indexes ``x_src``, ``edge_index[1]`` indexes ``x_dst``.
+    """
     out_features: int
     heads: int = 1
     concat: bool = True
@@ -129,39 +138,42 @@ class GATConv(nn.Module):
 
     @nn.compact
     def __call__(self, x, edge_index, edge_mask):
-        num_nodes = x.shape[0]
+        x_src, x_dst = x if isinstance(x, (tuple, list)) else (x, x)
+        num_src, num_dst = x_src.shape[0], x_dst.shape[0]
         h, f = self.heads, self.out_features
         src, dst = edge_index[0], edge_index[1]
-        src_c = jnp.clip(src, 0, num_nodes - 1)
-        dst_c = jnp.clip(dst, 0, num_nodes - 1)
+        src_c = jnp.clip(src, 0, num_src - 1)
+        dst_c = jnp.clip(dst, 0, num_dst - 1)
 
         with jax.named_scope("glt.model.dense"):
-            z = nn.Dense(h * f, use_bias=False,
-                         dtype=_mm_dtype(self.dtype),
-                         name="lin")(x).astype(jnp.float32).reshape(
-                num_nodes, h, f)
+            lin = nn.Dense(h * f, use_bias=False,
+                           dtype=_mm_dtype(self.dtype), name="lin")
+            z = lin(x_src).astype(jnp.float32).reshape(num_src, h, f)
+            z_dst = z if x_dst is x_src else lin(x_dst).astype(
+                jnp.float32).reshape(num_dst, h, f)
             att_src = self.param("att_src",
                                  nn.initializers.glorot_uniform(), (h, f))
             att_dst = self.param("att_dst",
                                  nn.initializers.glorot_uniform(), (h, f))
-            alpha_src = (z * att_src).sum(-1)   # [N, h]
-            alpha_dst = (z * att_dst).sum(-1)
+            alpha_src = (z * att_src).sum(-1)       # [N_src, h]
+            alpha_dst = (z_dst * att_dst).sum(-1)   # [N_dst, h]
 
         with jax.named_scope("glt.model.msg"):
             e = alpha_src[src_c] + alpha_dst[dst_c]          # [E, h]
             e = nn.leaky_relu(e, self.negative_slope)
         # Per-head softmax over incoming edges of each destination.
         alpha = jax.vmap(
-            lambda s: segment_softmax(s, dst, num_nodes, edge_mask),
+            lambda s: segment_softmax(s, dst, num_dst, edge_mask),
             in_axes=1, out_axes=1)(e)                    # [E, h]
         with jax.named_scope("glt.model.msg"):
             msgs = z[src_c] * alpha[:, :, None]          # [E, h, f]
-        out = scatter_sum(msgs.reshape(-1, h * f), dst, num_nodes,
-                          edge_mask).reshape(num_nodes, h, f)
-        if self.concat:
-            out = out.reshape(num_nodes, h * f)
-        else:
-            out = out.mean(axis=1)
-        bias = self.param("bias", nn.initializers.zeros,
-                          (out.shape[-1],))
-        return out + bias
+        out = scatter_sum(msgs.reshape(-1, h * f), dst, num_dst,
+                          edge_mask).reshape(num_dst, h, f)
+        with jax.named_scope("glt.model.dense"):
+            if self.concat:
+                out = out.reshape(num_dst, h * f)
+            else:
+                out = out.mean(axis=1)
+            bias = self.param("bias", nn.initializers.zeros,
+                              (out.shape[-1],))
+            return out + bias

@@ -479,6 +479,9 @@ def run_scanned_epoch(step, state, train_idx, batch_size: int,
             if accs else np.zeros((0,), np.float32))
     ovf = (int(np.asarray(jax.device_get(
         jnp.concatenate(ovfs))).sum()) if ovfs else 0)
+    counter = getattr(step, "overflow_counter", None)
+    if counter is not None:
+        counter.inc(ovf)
     return state, losses, accs, ovf
 
 
@@ -510,69 +513,128 @@ def hetero_init_shapes(sampler, feats, rows_of):
     return x, ei, mask
 
 
-def init_hetero_state(model, tx, sampler, feats, rng) -> TrainState:
-    """Params/opt-state for hetero models from a
-    :class:`~glt_tpu.sampler.hetero_neighbor_sampler.HeteroNeighborSampler`'s
-    static shapes (the single-device analog of
-    ``parallel.init_hetero_dist_state``)."""
+def _resident_rows(f, whole: bool = True):
+    """The ``[N_t, d]`` device array of one type's table: a
+    :class:`~glt_tpu.data.feature.Feature`'s hot rows (``whole``: all of
+    them must be in HBM), a ``jax.Array`` as it is, anything else placed."""
     import numpy as np
 
     from ..data.feature import Feature
 
-    def _rows(f):
-        if isinstance(f, Feature):
-            return f.hot_rows
-        return jnp.asarray(np.asarray(f))
+    if isinstance(f, Feature):
+        if whole and f.hot_count < f.size:
+            raise ValueError(
+                "scanned hetero step needs device-resident features")
+        return f.hot_rows
+    return f if isinstance(f, jax.Array) else jnp.asarray(np.asarray(f))
 
-    x, ei, mask = hetero_init_shapes(sampler, feats, _rows)
-    params = model.init({"params": rng}, x, ei, mask)
+
+def init_hetero_state(model, tx, sampler, feats, rng) -> TrainState:
+    """Params/opt-state for hetero models from a
+    :class:`~glt_tpu.sampler.hetero_neighbor_sampler.HeteroNeighborSampler`'s
+    edge types and the tables' widths (the single-device analog of
+    ``parallel.init_hetero_dist_state``).  Parameter shapes follow the
+    feature widths, not the row counts: one row and one edge slot each,
+    so that initialising never runs the model at the batch's size."""
+    from ..typing import reverse_edge_type
+
+    rows = {t: _resident_rows(f, whole=False) for t, f in feats.items()
+            if t in sampler.node_capacity}
+    revs = [reverse_edge_type(et) for et in sampler.edge_types]
+    params = model.init(
+        {"params": rng},
+        {t: jnp.zeros((1, r.shape[-1]), r.dtype) for t, r in rows.items()},
+        {et: jnp.full((2, 1), PADDING_ID, jnp.int32) for et in revs},
+        {et: jnp.zeros((1,), bool) for et in revs})
     return TrainState(params=params, opt_state=tx.init(params),
                       step=jnp.zeros((), jnp.int32))
 
 
+def hetero_gather_xy(rows, labels, out, batch_size: int):
+    """``(x {type: [N_t, d]}, y [batch_size])`` of a hetero sample: every
+    type's rows in the table's own dtype, zero off the node list, and the
+    labels of the seed rows (the only ones a loss reads; -1 on padding).
+    ``rows`` / ``labels`` ride as arguments, as in :func:`make_gather_xy`.
+    """
+    x = {}
+    with jax.named_scope("glt.gather.feat"):
+        for t, node in out.node.items():
+            if t not in rows:
+                continue
+            valid = node >= 0
+            xt = jnp.take(rows[t], jnp.where(valid, node, 0), axis=0,
+                          mode="clip")
+            x[t] = jnp.where(valid[:, None], xt, 0)
+    with jax.named_scope("glt.gather.label"):
+        seed_ids = out.node[out.input_type][:batch_size]
+        y = jnp.where(seed_ids >= 0,
+                      jnp.take(labels, jnp.maximum(seed_ids, 0), axis=0,
+                               mode="clip"),
+                      PADDING_ID)
+    return x, y
+
+
+_M_HETERO_OVF = _metrics.counter(
+    "glt.hetero.overflowed_batches",
+    "scanned hetero batches that overflowed a node or frontier capacity "
+    "(counted by run_scanned_epoch at its loss fetch)")
+
+
 def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
-                                   batch_size: int, dropout_seed: int = 0):
+                                   batch_size: int, dropout_seed: int = 0,
+                                   seed_hops: bool = False):
     """ONE jitted program trains ``G`` consecutive hetero seed batches.
 
     The hetero analog of :func:`make_scanned_node_train_step`: per batch
     — multi-type multi-hop sampling
     (:class:`HeteroNeighborSampler._sample_impl`), per-type feature
     gather, target-type label gather, fwd/bwd, update — under
-    ``lax.scan``.  Hetero configs run small batches over several graphs
-    (IGBH: batch 64), so per-batch dispatch dominates the eager loader
-    loop exactly as in the link/subgraph configs; measured on TPU the
-    eager config-4 epoch was ~60 ms/batch of pure dispatch.
+    ``lax.scan``.  Sized by its sampler: per-type node rows and
+    per-relation edge slots are the sampler's static capacities (the
+    product of the fanouts only where neither the exact clamp nor a
+    calibrated ``node_capacity`` bounds them), recorded when the step is
+    built as ``glt.hetero.node_rows{type}`` and
+    ``glt.hetero.edge_slots{edge_type}``.  Rows keep the table's dtype
+    into the model (16-bit rows meet the first projection as they are).
+    The loss is over the rows of real seeds.
 
     Args:
       sampler: a :class:`HeteroNeighborSampler`.
       feats: dict ``node_type -> Feature | [N_t, d] array`` (device
-        resident).
+        resident; a ``jax.Array`` is used where it is).
       labels: dict ``node_type -> [N_t] int array`` — the sampler's
         ``input_type`` entry supplies the supervised target.
+      seed_hops: call the model with ``hops=sampler.hop_bounds`` (an
+        :class:`~glt_tpu.models.rgat.RGNN` then runs its last layer over
+        what reaches the seeds only).
 
     Returns ``step(state, seeds_blk [G, B], key) -> (state, losses [G],
-    accs [G])``.
+    accs [G], overflows [G])``; ``overflows`` is each batch's
+    capacity-overflow flag (zeros for a sampler without
+    ``node_capacity``): a flagged batch trained with its excess nodes'
+    edges masked.
     """
     import numpy as np
 
-    from ..data.feature import Feature
+    from ..typing import as_str
 
     tgt = sampler.input_type
     graphs = sampler.graphs
     graph_arrays = {et: (g.indptr, g.indices, g.gather_edge_ids)
                     for et, g in graphs.items()}
-
-    def _rows(f):
-        if isinstance(f, Feature):
-            if f.hot_count < f.size:
-                raise ValueError(
-                    "scanned hetero step needs device-resident features")
-            return f.hot_rows
-        return jnp.asarray(np.asarray(f))
-
-    rows = {t: _rows(f) for t, f in feats.items()}
+    rows = {t: _resident_rows(f) for t, f in feats.items()}
     labels_tgt = jnp.asarray(np.asarray(labels[tgt]))
     widths, cap = sampler._widths, sampler._capacity
+    hops = sampler.hop_bounds
+    trim = {"hops": hops} if seed_hops else {}
+    for t, n in cap.items():
+        _metrics.gauge("glt.hetero.node_rows", "node rows of one type in "
+                       "the batch of the last scanned hetero step built",
+                       {"type": t}).set(n)
+    for et, b in hops.edge_bounds.items():
+        _metrics.gauge("glt.hetero.edge_slots", "edge slots of one "
+                       "relation in the batch of the last scanned hetero "
+                       "step built", {"edge_type": as_str(et)}).set(b[-1])
 
     @jax.jit
     def run(graph_args, rows_args, labels_arg, state: TrainState,
@@ -582,21 +644,7 @@ def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
             seeds, k = inp
             out = sampler._sample_impl(widths, cap, graph_args,
                                        {tgt: seeds}, k)
-            x = {}
-            for t, node in out.node.items():
-                if t not in rows_args:
-                    continue
-                valid = node >= 0
-                gid = jnp.where(valid, node, 0)
-                xt = jnp.take(rows_args[t], gid, axis=0, mode="clip")
-                x[t] = jnp.where(valid[:, None], xt, 0)
-            node_t = out.node[tgt]
-            y = jnp.where(node_t >= 0,
-                          jnp.take(labels_arg,
-                                   jnp.clip(node_t, 0,
-                                            labels_arg.shape[0] - 1),
-                                   axis=0),
-                          PADDING_ID)
+            x, y = hetero_gather_xy(rows_args, labels_arg, out, batch_size)
             edge_index = {et: jnp.stack([out.row[et], out.col[et]])
                           for et in out.row}
             rng = jax.random.fold_in(jax.random.PRNGKey(dropout_seed),
@@ -604,32 +652,38 @@ def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
 
             def loss_fn(p):
                 logits = model.apply(p, x, edge_index, out.edge_mask,
-                                     train=True, rngs={"dropout": rng})
+                                     train=True, rngs={"dropout": rng},
+                                     **trim)
                 return seed_cross_entropy(logits, y, batch_size,
-                                          out.node_mask[tgt])
+                                          out.node_mask[tgt],
+                                          out.num_sampled_nodes[tgt][0])
 
             (loss, acc), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(st.params)
 
             def apply(s):
-                updates, opt_state = tx.update(grads, s.opt_state,
-                                               s.params)
-                params = optax.apply_updates(s.params, updates)
+                with jax.named_scope("glt.step.update"):
+                    updates, opt_state = tx.update(grads, s.opt_state,
+                                                   s.params)
+                    params = optax.apply_updates(s.params, updates)
                 return TrainState(params, opt_state, s.step + 1)
 
             st = jax.lax.cond(jnp.any(seeds >= 0), apply, lambda s: s, st)
-            return st, (loss, acc)
+            ovf = (out.metadata["overflow"].astype(jnp.int32)
+                   if out.metadata else jnp.zeros((), jnp.int32))
+            return st, (loss, acc, ovf)
 
         keys = jax.random.split(key, seeds_blk.shape[0])
-        state, (losses, accs) = jax.lax.scan(body, state,
-                                             (seeds_blk, keys))
-        return state, losses, accs
+        state, (losses, accs, ovfs) = jax.lax.scan(body, state,
+                                                   (seeds_blk, keys))
+        return state, losses, accs, ovfs
 
     def step(state: TrainState, seeds_blk, key):
         with _compilewatch.label("scanned_hetero_step"):
             return run(graph_arrays, rows, labels_tgt, state,
                        jnp.asarray(seeds_blk, jnp.int32), key)
 
+    step.overflow_counter = _M_HETERO_OVF
     return step
 
 

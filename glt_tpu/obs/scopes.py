@@ -23,9 +23,11 @@ call, and no flag turns them off.  The whole taxonomy:
 ``glt.route.bucket``   owner bucketing of ids (``build_routing``)
 ``glt.route.payload``  assembling exchange payloads, un-permuting replies
 ``glt.route.exchange`` the ``all_to_all``/``ppermute`` calls themselves
-``glt.model.msg``      the model's edge-slot gather ``x[src]``
-``glt.model.agg``      segment reductions (sum, mean, softmax)
-``glt.model.dense``    matmuls, with their activation and dropout
+``glt.model.msg``      the model's edge-slot gathers (``x[src]``; GAT's
+                       attention logits and weighted messages)
+``glt.model.agg``      segment reductions (sum, mean, softmax) and the
+                       sum over relations
+``glt.model.dense``    matmuls, with their bias, activation and dropout
 ``glt.step.loss``      the loss
 ``glt.step.update``    optimiser update (and the gradient all-reduce)
 =====================  ====================================================

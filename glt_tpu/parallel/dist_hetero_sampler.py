@@ -98,6 +98,7 @@ class DistHeteroNeighborSampler:
         p.input_type = input_type
         p.batch_size = int(batch_size)
         p.last_hop_dedup = bool(last_hop_dedup)
+        p.capped = False
         self.last_hop_dedup = bool(last_hop_dedup)
         # Global per-type node counts so the planner's dense inducer
         # engages (ids here are global across shards).

@@ -12,7 +12,7 @@ kernels, and the same reversed-edge-type output convention
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,12 +34,18 @@ from .base import BaseSampler, HeteroSamplerOutput, NodeSamplerInput
 from .neighbor_sampler import _pad_ids
 
 
+_NO_LIMIT = 1 << 62
+
+
 def hetero_hop_widths(
     edge_types: Sequence[EdgeType],
     num_neighbors: Dict[EdgeType, List[int]],
     seed_widths: Dict[NodeType, int],
     num_hops: int,
     frontier_cap: Optional[int] = None,
+    num_nodes: Optional[Dict[NodeType, int]] = None,
+    node_capacity: Optional[Dict[NodeType, int]] = None,
+    frontier_capacity: Optional[Dict[NodeType, Sequence[int]]] = None,
 ) -> Tuple[List[Dict[NodeType, int]], Dict[NodeType, int]]:
     """Static frontier width per (hop, node type) + total capacity per type.
 
@@ -49,14 +55,35 @@ def hetero_hop_widths(
     gives the hop-0 frontier per type (node sampling seeds one type; link
     sampling seeds the edge's endpoint types).
 
-    ``frontier_cap`` bounds each (hop, type) frontier, exactly like the
-    homo sampler's knob (neighbor_sampler.py ``hop_widths``): without it,
-    widths multiply across edge types per hop and IGBH-scale fanouts
-    explode trace-time capacities.  Newly-discovered nodes beyond the cap
-    don't expand further hops (they stay in the node set).
+    Widths multiply across edge types per hop, so three bounds keep them
+    from being the product of the fanouts:
+
+    * ``num_nodes`` (exact): a de-duplicated frontier of type ``t``, and
+      the node buffer of type ``t``, never hold more than ``N_t`` nodes.
+      Nothing is cut.
+    * ``node_capacity`` (occupancy, see
+      :func:`calibrate_hetero_node_capacity`): the buffer of type ``t``
+      holds its first ``node_capacity[t]`` uniques, and no frontier of
+      the type is wider than that.  A batch that discovers more is
+      flagged (``metadata['overflow']``) and the edges of the excess
+      nodes are masked.
+    * ``frontier_capacity`` (occupancy, same calibration):
+      ``frontier_capacity[t][k-1]`` is the width of type ``t``'s hop-``k``
+      frontier, ``k = 1..num_hops-1``: the nodes first seen at hop ``k``
+      are mostly fewer than the candidates, which are ``fanout`` a slot
+      whatever the degree.  A batch with more new nodes than the width
+      is flagged the same way; the nodes beyond it stay leaves.
+    * ``frontier_cap`` bounds each (hop, type) frontier like the homo
+      sampler's knob: newly-discovered nodes beyond the cap don't expand
+      further hops (they stay in the node set).  It CUTS the sampling
+      semantics, silently.
     """
     ntypes = sorted({et[0] for et in edge_types} | {et[2] for et in edge_types}
                     | set(seed_widths))
+    limit = {t: min((num_nodes or {}).get(t, _NO_LIMIT),
+                    (node_capacity or {}).get(t, _NO_LIMIT),
+                    _NO_LIMIT if frontier_cap is None else frontier_cap)
+             for t in ntypes}
     widths: List[Dict[NodeType, int]] = [
         {t: seed_widths.get(t, 0) for t in ntypes}]
     for hop in range(num_hops):
@@ -65,11 +92,112 @@ def hetero_hop_widths(
             fanouts = num_neighbors[et]
             if hop < len(fanouts) and fanouts[hop] > 0:
                 nxt[et[2]] += widths[hop][et[0]] * fanouts[hop]
-        if frontier_cap is not None:
-            nxt = {t: min(w, frontier_cap) for t, w in nxt.items()}
-        widths.append(nxt)
+        if frontier_capacity is not None and hop + 1 < num_hops:
+            nxt = {t: min(w, int(frontier_capacity[t][hop]))
+                   if t in frontier_capacity else w for t, w in nxt.items()}
+        widths.append({t: min(w, limit[t]) for t, w in nxt.items()})
     capacity = {t: sum(w[t] for w in widths) for t in ntypes}
+    for bound in (num_nodes, node_capacity):
+        for t, n in (bound or {}).items():
+            if t in capacity:
+                capacity[t] = min(capacity[t], max(int(n), widths[0][t]))
     return widths, capacity
+
+
+
+class HeteroHopBounds(NamedTuple):
+    """Static hop-block layout of a hetero batch, hops ``0..num_hops``
+    (the typed :class:`~glt_tpu.sampler.neighbor_sampler.HopBounds`).
+
+    ``edge_bounds[et][k]`` (``et`` the batch's reversed key) is the
+    number of edge slots of hops ``1..k`` of that relation: hop blocks
+    are concatenated in order.  ``node_bounds[t][k]`` bounds the rows of
+    type ``t``'s node buffer that can hold a node first seen by hop
+    ``k``.  Every valid edge of hop block ``k`` of ``(s, rel, d)`` has
+    ``col < node_bounds[d][k-1]`` and ``row < node_bounds[s][k]``: a node
+    is expanded once, at the hop after it was first seen, and what it
+    reaches is appended behind everything seen before.
+    """
+    node_bounds: Dict[NodeType, Tuple[int, ...]]
+    edge_bounds: Dict[EdgeType, Tuple[int, ...]]
+
+
+def hetero_hop_bounds(edge_types, num_neighbors, widths, capacity,
+                      num_nodes=None) -> HeteroHopBounds:
+    """The layout of a sampler with these ``widths`` and ``capacity``.
+    Node bounds count RAW candidates (a ``frontier_cap`` narrows the
+    frontier, not what the inducer inserts)."""
+    num_hops = len(widths) - 1
+    node = {t: [min(widths[0][t], capacity[t])] for t in capacity}
+    edge = {reverse_edge_type(et): [0] for et in edge_types}
+    for hop in range(num_hops):
+        raw = {t: 0 for t in capacity}
+        for et in edge_types:
+            fo = num_neighbors[et]
+            slots = widths[hop][et[0]] * fo[hop] \
+                if hop < len(fo) and fo[hop] > 0 else 0
+            raw[et[2]] += slots
+            rev = reverse_edge_type(et)
+            edge[rev].append(edge[rev][-1] + slots)
+        for t in capacity:
+            new = min(raw[t], (num_nodes or {}).get(t, _NO_LIMIT))
+            node[t].append(min(node[t][-1] + new, capacity[t]))
+    return HeteroHopBounds({t: tuple(b) for t, b in node.items()},
+                           {et: tuple(b) for et, b in edge.items()})
+
+
+def measure_hetero_occupancy(sampler: "HeteroNeighborSampler",
+                             seed_batches) -> Dict[NodeType, np.ndarray]:
+    """Nodes first seen per hop, ``{type: [batches, num_hops + 1]}`` (one
+    host fetch), the typed :func:`~glt_tpu.sampler.neighbor_sampler.
+    measure_occupancy`; a row's sum is the batch's unique nodes of the
+    type.  ``sampler`` is typically built without capacities."""
+    counts = [sampler.sample_from_nodes(
+        NodeSamplerInput(seeds)).num_sampled_nodes for seeds in seed_batches]
+    counts = jax.device_get(counts)
+    return {t: np.stack([c[t] for c in counts]) for t in counts[0]}
+
+
+def calibrate_hetero_node_capacity(
+        sampler: "HeteroNeighborSampler", seed_batches=None,
+        pct: float = 99.0, margin: float = 1.05, multiple: int = 256,
+        counts: Optional[Dict[NodeType, np.ndarray]] = None
+) -> Tuple[Dict[NodeType, int], Dict[NodeType, List[int]]]:
+    """Occupancy-sized ``(node_capacity, frontier_capacity)`` for a
+    calibrated workload: a threshold per type (its unique nodes) and per
+    (type, hop before the last) (the nodes first seen at that hop).
+
+    A batch overflows when ANY threshold is exceeded, so ``pct`` is held
+    jointly, not threshold by threshold (a dozen thresholds each at pct
+    99 let up to a dozen batches in a hundred through): thresholds are
+    tied as ``mean + z * std`` of their own counts, a batch's excursion
+    is the largest ``z`` it needs anywhere, and ``z`` is the ``pct``
+    percentile of the batches' excursions.  Times ``margin``, rounded up
+    to ``multiple`` rows, never over the sampler's own (clamped) sizes.
+    Feed both to :class:`HeteroNeighborSampler`."""
+    if counts is None:
+        counts = measure_hetero_occupancy(sampler, seed_batches)
+    full, widths = sampler.node_capacity, sampler.hop_widths
+    cols = {(t, 0): (c.sum(axis=1), full[t]) for t, c in counts.items()}
+    for t, c in counts.items():
+        for k in range(1, sampler.num_hops):
+            cols[t, k] = (c[:, k], widths[k][t])
+    spread = {key: (float(c.mean()), float(c.std()))
+              for key, (c, _) in cols.items()}
+    excursion = np.max([(c - spread[key][0]) / spread[key][1]
+                        for key, (c, _) in cols.items()
+                        if spread[key][1] > 0], axis=0)
+    z = float(np.percentile(excursion, pct))
+
+    def size(key):
+        mean, std = spread[key]
+        want = (mean + z * std) * margin
+        return min(int(np.ceil(want / multiple) * multiple), cols[key][1])
+
+    nodes = {t: size((t, 0)) for t in counts}
+    frontiers = {t: [size((t, k)) for k in range(1, sampler.num_hops)]
+                 for t in counts}
+    return nodes, frontiers
 
 
 def _node_mask(buf: jnp.ndarray, count: jnp.ndarray, fast) -> jnp.ndarray:
@@ -92,6 +220,8 @@ class HeteroNeighborSampler(BaseSampler):
         or a dict keyed by edge type.
       input_type: node type of the seeds.
       batch_size: static seed width.
+      frontier_cap / node_capacity / frontier_capacity: see
+        :func:`hetero_hop_widths`.
     """
 
     def __init__(
@@ -103,6 +233,8 @@ class HeteroNeighborSampler(BaseSampler):
         frontier_cap: Optional[int] = None,
         seed: int = 0,
         last_hop_dedup: bool = True,
+        node_capacity: Optional[Dict[NodeType, int]] = None,
+        frontier_capacity: Optional[Dict[NodeType, Sequence[int]]] = None,
     ):
         self.graphs = graphs
         self.edge_types = sorted(graphs.keys())
@@ -119,18 +251,14 @@ class HeteroNeighborSampler(BaseSampler):
         self._base_key = jax.random.PRNGKey(seed)
         self._call_count = 0
 
-        self.frontier_cap = frontier_cap
-        self._widths, self._capacity = hetero_hop_widths(
-            self.edge_types, self.num_neighbors,
-            {input_type: self.batch_size}, self.num_hops,
-            frontier_cap=frontier_cap)
-        self.node_types = sorted(self._capacity.keys())
-        # Per-type node counts for the dense inducer.  A type's id space
-        # must cover BOTH roles: its CSR row count where it is a source
-        # AND the max destination id arriving from other edge types
-        # (CSRTopo derives num_nodes from one edge type's own ids, so a
-        # source-only bound can undercount and silently drop neighbors).
-        # Types with no evidence fall back to the sort-based inducer.
+        # Per-type node counts: the exact clamp of every frontier and
+        # node buffer, and the size of the dense inducer's id map.  A
+        # type's id space must cover BOTH roles: its CSR row count where
+        # it is a source AND the max destination id arriving from other
+        # edge types (CSRTopo derives num_nodes from one edge type's own
+        # ids, so a source-only bound can undercount and silently drop
+        # neighbors).  Types with no evidence fall back to the sort-based
+        # inducer and go unclamped.
         self._num_nodes_by_type = {}
         for et, g in graphs.items():
             if g is None:
@@ -143,6 +271,25 @@ class HeteroNeighborSampler(BaseSampler):
                 self._num_nodes_by_type[dst_t] = max(
                     self._num_nodes_by_type.get(dst_t, 0),
                     int(idx.max()) + 1)
+        self.frontier_cap = frontier_cap
+        # Occupancy-sized per-type capacities (see
+        # calibrate_hetero_node_capacity): a batch that discovers more
+        # uniques of a type than its buffer holds, or more new nodes at a
+        # hop than the next frontier holds, is flagged through
+        # metadata['overflow']; the excess nodes' edges are masked, the
+        # nodes past a frontier stay leaves.
+        self.capped = (node_capacity is not None
+                       or frontier_capacity is not None)
+        self._widths, self._capacity = hetero_hop_widths(
+            self.edge_types, self.num_neighbors,
+            {input_type: self.batch_size}, self.num_hops,
+            frontier_cap=frontier_cap, num_nodes=self._num_nodes_by_type,
+            node_capacity=node_capacity,
+            frontier_capacity=frontier_capacity)
+        self.node_types = sorted(self._capacity.keys())
+        self.hop_bounds = hetero_hop_bounds(
+            self.edge_types, self.num_neighbors, self._widths,
+            self._capacity, self._num_nodes_by_type)
         self._sample_jit = jax.jit(
             partial(self._sample_impl, self._widths, self._capacity))
         self._edges_jit = {}
@@ -228,6 +375,7 @@ class HeteroNeighborSampler(BaseSampler):
                     raw_interior[et[2]] += widths[h][et[0]] * f
 
         keys = jax.random.split(key, self.num_hops * len(self.edge_types))
+        overflow = jnp.zeros((), bool)
 
         for hop in range(self.num_hops):
             # 1) sample every active edge type from its src frontier
@@ -239,13 +387,15 @@ class HeteroNeighborSampler(BaseSampler):
                 if f <= 0 or w <= 0 or frontier[et[0]] is None:
                     continue
                 hop_key = keys[hop * len(self.edge_types) + ei_idx]
-                if one_hop is not None:
-                    out = one_hop(et, graph_arrays[et], frontier[et[0]], f,
-                                  hop_key)
-                else:
-                    indptr, indices, edge_ids = graph_arrays[et]
-                    out = sample_neighbors(indptr, indices, frontier[et[0]],
-                                           f, hop_key, edge_ids=edge_ids)
+                with jax.named_scope(f"glt.sample.hop{hop + 1}"):
+                    if one_hop is not None:
+                        out = one_hop(et, graph_arrays[et],
+                                      frontier[et[0]], f, hop_key)
+                    else:
+                        indptr, indices, edge_ids = graph_arrays[et]
+                        out = sample_neighbors(
+                            indptr, indices, frontier[et[0]], f, hop_key,
+                            edge_ids=edge_ids)
                 src_local = (frontier_start[et[0]]
                              + jnp.arange(w, dtype=jnp.int32))
                 src_local = jnp.where(frontier[et[0]] >= 0, src_local,
@@ -333,6 +483,12 @@ class HeteroNeighborSampler(BaseSampler):
                         (jnp.clip(old_count, 0, buflen),),
                         (nw,))
                 node_buf[t] = uniques_src[:buflen]
+                # merged_count keeps counting uniques past the buffer
+                # (the dense inducer's dump slot absorbs their writes).
+                overflow = overflow | (merged_count > buflen)
+                if hop + 1 < self.num_hops:
+                    overflow = overflow | (
+                        merged_count - old_count > widths[hop + 1][t])
                 count[t] = jnp.minimum(merged_count, buflen)
                 frontier_start[t] = old_count
 
@@ -367,6 +523,7 @@ class HeteroNeighborSampler(BaseSampler):
                        for i in range(len(counts_hist[t]) - 1)])
                 for t in node_types},
             input_type=self.input_type,
+            metadata={"overflow": overflow} if self.capped else None,
         )
         return out
 
@@ -446,9 +603,13 @@ class HeteroNeighborSampler(BaseSampler):
                 sw, dw = q, q
             seed_widths = ({src_t: sw + dw} if src_t == dst_t
                            else {src_t: sw, dst_t: dw})
+            # The seed union runs at its own widths: the exact clamp
+            # holds, an occupancy capacity of the node path does not
+            # transfer (another seed width, another occupancy).
             widths, cap = hetero_hop_widths(
                 self.edge_types, self.num_neighbors, seed_widths,
-                self.num_hops, frontier_cap=self.frontier_cap)
+                self.num_hops, frontier_cap=self.frontier_cap,
+                num_nodes=self._num_nodes_by_type)
 
             # Node counts are static: an edge type's CSR rows are its
             # source type's nodes.

@@ -487,8 +487,9 @@ def test_scanned_hetero_step_matches_eager():
     blocks = np.stack([np.arange(g * bs, (g + 1) * bs) % U
                        for g in range(G)]).astype(np.int32)
     base = jax.random.PRNGKey(7)
-    st, losses, accs = sstep(state0, blocks, base)
+    st, losses, accs, ovfs = sstep(state0, blocks, base)
     g_losses = [float(x) for x in np.asarray(losses)]
+    assert np.asarray(ovfs).tolist() == [0] * G     # no node_capacity
 
     # Eager reference with the scan's key schedule and the same math.
     keys = jax.random.split(base, G)
@@ -518,7 +519,8 @@ def test_scanned_hetero_step_matches_eager():
                                  rngs={"dropout": jax.random.fold_in(
                                      jax.random.PRNGKey(0), state.step)})
             return seed_cross_entropy(logits, y, bs,
-                                      out.node_mask["user"])
+                                      out.node_mask["user"],
+                                      out.num_sampled_nodes["user"][0])
 
         (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params)
