@@ -129,6 +129,12 @@ class GATConv(nn.Module):
     destination's, the softmax runs over each destination's incoming
     edges, and the result has ``x_dst.shape[0]`` rows.  ``edge_index[0]``
     indexes ``x_src``, ``edge_index[1]`` indexes ``x_dst``.
+
+    ``num_dst`` (with one array) makes the first ``num_dst`` rows the
+    destinations, as in :class:`SAGEConv`: every row is projected once,
+    the destinations' projection is the prefix of it, and the result has
+    ``num_dst`` rows.  The caller guarantees that every unmasked edge has
+    ``dst < num_dst``.  With no edge slot at all the result is the bias.
     """
     out_features: int
     heads: int = 1
@@ -137,9 +143,15 @@ class GATConv(nn.Module):
     dtype: Any = None   # matmul compute dtype; attention math stays f32
 
     @nn.compact
-    def __call__(self, x, edge_index, edge_mask):
-        x_src, x_dst = x if isinstance(x, (tuple, list)) else (x, x)
-        num_src, num_dst = x_src.shape[0], x_dst.shape[0]
+    def __call__(self, x, edge_index, edge_mask,
+                 num_dst: Optional[int] = None):
+        pair = isinstance(x, (tuple, list))
+        x_src, x_dst = x if pair else (x, None)
+        num_src = x_src.shape[0]
+        if pair:
+            num_dst = x_dst.shape[0]
+        elif num_dst is None:
+            num_dst = num_src
         h, f = self.heads, self.out_features
         src, dst = edge_index[0], edge_index[1]
         src_c = jnp.clip(src, 0, num_src - 1)
@@ -149,8 +161,10 @@ class GATConv(nn.Module):
             lin = nn.Dense(h * f, use_bias=False,
                            dtype=_mm_dtype(self.dtype), name="lin")
             z = lin(x_src).astype(jnp.float32).reshape(num_src, h, f)
-            z_dst = z if x_dst is x_src else lin(x_dst).astype(
-                jnp.float32).reshape(num_dst, h, f)
+            if pair:
+                z_dst = lin(x_dst).astype(jnp.float32).reshape(num_dst, h, f)
+            else:
+                z_dst = z if num_dst == num_src else z[:num_dst]
             att_src = self.param("att_src",
                                  nn.initializers.glorot_uniform(), (h, f))
             att_dst = self.param("att_dst",

@@ -10,7 +10,7 @@ variant (a per-type input ``Dense``, residual layers, averaged heads, a
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,15 +24,25 @@ class HeteroConv(nn.Module):
     """Apply one conv per edge type; sum results per destination type.
 
     ``edge_types`` use the *batch's* (already reversed) keys: an edge type
-    ``(src_t, rel, dst_t)`` aggregates messages from ``x[src_t]`` into
-    ``x_dst[dst_t]`` rows (``x_dst`` defaults to ``x``; a caller that
-    needs fewer destination rows than source rows passes its own).
+    ``(src_t, rel, dst_t)`` aggregates messages from ``x[src_t]`` into the
+    rows of ``dst_t``.  A relation with no edge slot, and a type with no
+    row or no incoming relation, drop out.
+
+    ``num_dst`` (``node_type -> rows``) is for a caller that reads a
+    prefix of the result only (:class:`RGNN` under ``hops``): the
+    destinations of type ``t`` are the first ``num_dst[t]`` rows of
+    ``x[t]``, the result has that many, and a type not listed is not
+    computed.  The caller has cut ``x`` and the edge slots to what those
+    rows need, so emptiness means nothing here: a relation with no slot
+    left still gives its bias, a type with no row left stays (with none).
 
     ``conv='gat'`` runs the bipartite :class:`GATConv` on
-    ``(x[src_t], x_dst[dst_t])``: each side is projected once, no row is
-    copied.  ``concat=True`` concatenates ``heads`` heads of
-    ``out_features // heads`` (PyG's ``GATConv(in, out // heads,
-    heads)``); ``False`` averages ``heads`` heads of ``out_features``.
+    ``(x[src_t], x[dst_t][:n])``: each side is projected once, no row is
+    copied; a relation whose two ends are one type projects its rows once
+    and takes the destinations' projection as the prefix.
+    ``concat=True`` concatenates ``heads`` heads of ``out_features //
+    heads`` (PyG's ``GATConv(in, out // heads, heads)``); ``False``
+    averages ``heads`` heads of ``out_features``.
     """
     edge_types: Sequence[Tuple[str, str, str]]
     out_features: int
@@ -44,37 +54,44 @@ class HeteroConv(nn.Module):
 
     @nn.compact
     def __call__(self, x: Dict[str, jnp.ndarray], edge_index, edge_mask,
-                 x_dst: Optional[Dict[str, jnp.ndarray]] = None):
+                 num_dst: Optional[Dict[str, int]] = None):
         dt = _mm_dtype(self.dtype)
-        x_dst = x if x_dst is None else x_dst
         outs: Dict[str, list] = {}
         for et in self.edge_types:
             src_t, _, dst_t = et
-            if et not in edge_index or src_t not in x or dst_t not in x_dst:
+            if et not in edge_index or src_t not in x or dst_t not in x:
                 continue
-            ei = edge_index[et]
-            if ei.shape[-1] == 0 or x_dst[dst_t].shape[0] == 0:
+            ei, mask, dst_rows = edge_index[et], edge_mask[et], x[dst_t]
+            if num_dst is None:
+                if ei.shape[-1] == 0 or dst_rows.shape[0] == 0:
+                    continue
+            elif dst_t in num_dst:
+                with jax.named_scope("glt.model.dense"):
+                    dst_rows = dst_rows[:num_dst[dst_t]]
+            else:
                 continue
-            mask = edge_mask[et]
+            n_dst = dst_rows.shape[0]
             if self.conv == "gat":
                 f = (self.out_features // self.heads if self.concat
                      else self.out_features)
-                h = GATConv(f, heads=self.heads, concat=self.concat,
-                            negative_slope=self.negative_slope,
-                            dtype=self.dtype, name=f"{as_str(et)}_conv")(
-                    (x[src_t], x_dst[dst_t]), ei, mask)
+                conv = GATConv(f, heads=self.heads, concat=self.concat,
+                               negative_slope=self.negative_slope,
+                               dtype=self.dtype, name=f"{as_str(et)}_conv")
+                if src_t == dst_t:
+                    h = conv(x[src_t], ei, mask, num_dst=n_dst)
+                else:
+                    h = conv((x[src_t], dst_rows), ei, mask)
             else:
                 # SAGEConv is a one-graph layer: stack src rows behind dst
                 # rows so it can run on one node array.  Src rows are
                 # aligned to the dst width only when the types' feature
                 # dims genuinely differ.
-                n_dst = x_dst[dst_t].shape[0]
                 src_rows = x[src_t]
-                if src_rows.shape[-1] != x_dst[dst_t].shape[-1]:
-                    src_rows = nn.Dense(x_dst[dst_t].shape[-1], dtype=dt,
+                if src_rows.shape[-1] != dst_rows.shape[-1]:
+                    src_rows = nn.Dense(dst_rows.shape[-1], dtype=dt,
                                         name=f"{as_str(et)}_align")(
                         src_rows).astype(jnp.float32)
-                joint = jnp.concatenate([x_dst[dst_t], src_rows], axis=0)
+                joint = jnp.concatenate([dst_rows, src_rows], axis=0)
                 ei_shift = jnp.stack([
                     jnp.where(ei[0] >= 0, ei[0] + n_dst, -1),
                     ei[1],
@@ -141,14 +158,40 @@ class RGNN(nn.Module):
     ``out_features // heads`` (2,983 classes give 2,980 columns); here it
     has one head of ``out_features``.
 
-    ``hops`` (a :class:`~glt_tpu.sampler.hetero_neighbor_sampler.
-    HeteroHopBounds`, the sampler's static hop-block layout) runs the
-    LAST layer over what reaches the seeds only: the seed rows of the
-    target type, the hop-1 edge blocks and the rows first seen by hop 1.
-    The seeds' logits are the whole model's (a seed is expanded at hop 1
-    and nowhere else), and the result has ``node_bounds[target][0]``
-    rows.  The layers before it run whole: trimming them by the same
-    layout is ROADMAP Reach A.1.
+    **Per-layer trimming** (``hops=``, a :class:`~glt_tpu.sampler.
+    hetero_neighbor_sampler.HeteroHopBounds`: the sampler's static
+    hop-block layout) runs every layer over what reaches the seeds only,
+    the typed :class:`~glt_tpu.models.sage.GraphSAGE` ``hops=``.  Layer
+    ``l`` of ``L`` (1-based) sits ``d = L - l`` layers under the output
+    (``d`` clamps to the number of hops) and runs on
+
+    * destinations: rows ``[:node_bounds[t][d]]`` of each type ``t`` (the
+      last layer: the target type alone);
+    * edges: slots ``[:edge_bounds[et][d + 1]]`` of each relation into
+      such a type: hop blocks ``1..d+1``;
+    * sources: rows ``[:node_bounds[t][d + 1]]`` of each type, which is
+      what layer ``l - 1`` emits.
+
+    The seeds' logits (``node_bounds[target][0]`` rows come back), the
+    loss and every gradient are the whole model's, up to float32
+    reassociation.  Every valid edge of hop block ``k`` of ``(s, rel, d)``
+    has ``col < node_bounds[d][k-1]`` and ``row < node_bounds[s][k]``, and
+    a node is expanded once, at the hop after it was first seen (or never:
+    a node past a frontier's capacity stays a leaf, one past a node
+    buffer's has its edges masked).  So a node first seen by hop ``j`` has
+    ALL its incoming edges in blocks ``<= j + 1``: its segment softmax
+    over the trimmed edges is its softmax over all of them, and it reads
+    sources seen by hop ``j + 1``, which the layer below computed from
+    blocks ``<= j + 2``, and so on down to the features.  The bounds count
+    candidates, not uniques, so a row under a bound may hold a node seen
+    later, whose edges were cut: its value is wrong and nothing reads it,
+    because block-``k`` edges read only rows truly seen by hop ``k``.  A
+    type with no row at depth ``d`` (IGBH's institutes at ``d <= 1``) has
+    nothing to compute in that layer; which relations and types are live
+    is decided on the whole batch's shapes, as without ``hops``.  Dropout
+    draws one mask a layer output, so with ``train=True`` a trimmed layer
+    draws a smaller mask: the same distribution, not the same sample.
+    A batch not laid out by ``hops`` raises.
     """
     edge_types: Sequence[Tuple[str, str, str]]
     hidden_features: int
@@ -159,25 +202,68 @@ class RGNN(nn.Module):
     dropout_rate: float = 0.2
     dtype: Any = None       # matmul compute dtype (see conv.py)
 
+    def typed_extents(self, hops) -> List[Tuple[Dict, Dict, Dict]]:
+        """``(source rows by type, edge slots by relation, destination
+        rows by type)`` of each layer under ``hops``, first layer first
+        (the rule in the class docstring)."""
+        nb, eb = hops.node_bounds, hops.edge_bounds
+        k = len(nb[self.target_type]) - 1
+        out = []
+        for d in range(self.num_layers - 1, -1, -1):
+            lo, hi = min(d, k), min(d + 1, k)
+            dst = {t: b[lo] for t, b in nb.items()
+                   if d or t == self.target_type}
+            out.append(({t: b[hi] for t, b in nb.items()},
+                        {et: eb[et][hi]
+                         for et in self.edge_types if et[2] in dst},
+                        dst))
+        return out
+
+    def layer_extents(self, hops) -> List[Tuple[int, int, int]]:
+        """``(source rows, edge slots, destination rows)`` of each layer
+        under ``hops``, summed over types and relations (what
+        :func:`~glt_tpu.models.train.hop_trimming` records)."""
+        return [tuple(sum(part.values()) for part in layer)
+                for layer in self.typed_extents(hops)]
+
     @nn.compact
     def __call__(self, x: Dict[str, jnp.ndarray], edge_index, edge_mask, *,
                  train: bool = False, hops=None):
         h, tgt = x, self.target_type
+        if hops is not None:
+            rows = {t: v.shape[0] for t, v in x.items()}
+            slots = {et: v.shape[1] for et, v in edge_index.items()}
+            nb, eb = hops.node_bounds, hops.edge_bounds
+            # (A type nothing reaches has one padding row in the batch.)
+            if (any(t not in nb or max(nb[t][-1], 1) != n
+                    for t, n in rows.items())
+                    or any(et not in eb or eb[et][-1] != n
+                           for et, n in slots.items())):
+                raise ValueError(
+                    f"batch of {rows} rows and {slots} edge slots is not "
+                    f"laid out by {hops}")
+            extents = self.typed_extents(hops)
+            # Which relations and types are live is the whole batch's
+            # matter: one with no slot or no row there is not in the
+            # whole model either; a cut that leaves none says nothing.
+            h = {t: v for t, v in x.items() if rows[t]}
+            edge_index = {et: v for et, v in edge_index.items() if slots[et]}
         for i in range(self.num_layers):
             last = i + 1 == self.num_layers
-            ei, em, h_dst = edge_index, edge_mask, h
-            if last and hops is not None:
-                ei = {et: edge_index[et][:, :b[1]]
-                      for et, b in hops.edge_bounds.items()
-                      if et[2] == tgt and et in edge_index}
-                em = {et: edge_mask[et][:ei[et].shape[1]] for et in ei}
-                h_dst = {tgt: h[tgt][:hops.node_bounds[tgt][0]]}
-                h = {t: v[:hops.node_bounds[t][1]] for t, v in h.items()}
+            ei, em, n_dst = edge_index, edge_mask, None
+            if hops is not None:
+                n_src, n_edge, n_dst = extents[i]
+                with jax.named_scope("glt.model.msg"):
+                    ei = {et: edge_index[et][:, :n]
+                          for et, n in n_edge.items() if et in edge_index}
+                    em = {et: edge_mask[et][:n_edge[et]] for et in ei}
+                with jax.named_scope("glt.model.dense"):
+                    h = {t: v[:n_src[t]] for t, v in h.items()}
             h = HeteroConv(
                 self.edge_types,
                 self.out_features if last else self.hidden_features,
                 conv="gat", heads=1 if last else self.heads, concat=True,
-                dtype=self.dtype, name=f"layer{i}")(h, ei, em, h_dst)
+                dtype=self.dtype, name=f"layer{i}")(h, ei, em, n_dst)
             if not last:
                 with jax.named_scope("glt.model.dense"):
                     h = {t: nn.leaky_relu(v) for t, v in h.items()}

@@ -90,27 +90,27 @@ def hop_trimming(model, hops) -> dict:
     """``model.apply`` keywords that run ``model`` trimmed to ``hops``.
 
     A model that trims by the sampler's hop-block layout says so by
-    having ``layer_extents(hops)`` and taking ``hops=``
-    (:class:`~glt_tpu.models.sage.GraphSAGE`); any other model runs whole
-    (``{}``).  Engagement is a trace-time fact, so it is recorded here,
+    having ``layer_extents(hops)`` and taking ``hops=`` (``GraphSAGE``;
+    ``RGNN``, typed: summed over types and relations); any other model
+    runs whole (``{}``).  Engagement is a trace-time fact, recorded here
     when the step is built: ``glt.model.layer_edge_slots{layer=l}`` /
     ``glt.model.layer_node_rows{layer=l}`` (the rows layer ``l``
-    computes; it reads the rows of layer ``l-1``) against
-    ``glt.model.edge_slots`` / ``glt.model.node_rows`` of the batch.
+    computes) against ``glt.model.edge_slots`` / ``.node_rows``.
     """
     if not hasattr(model, "layer_extents"):
         return {}
-    gauge = _metrics.gauge
+    gauge, typed = _metrics.gauge, isinstance(hops.node_bounds, dict)
+    whole = [sum(b[-1] for b in part.values()) if typed else part[-1]
+             for part in (hops.edge_bounds, hops.node_bounds)]
     gauge("glt.model.edge_slots", "edge slots of the sampled batch of the "
-          "last hop-trimmed step built").set(hops.edge_bounds[-1])
+          "last hop-trimmed step built").set(whole[0])
     gauge("glt.model.node_rows", "node rows of the sampled batch of the "
-          "last hop-trimmed step built").set(hops.node_bounds[-1])
+          "last hop-trimmed step built").set(whole[1])
     for i, (_, n_edge, n_dst) in enumerate(model.layer_extents(hops), 1):
-        labels = {"layer": str(i)}
-        gauge("glt.model.layer_edge_slots", "edge slots one GraphSAGE layer "
-              "aggregates in that step", labels).set(n_edge)
-        gauge("glt.model.layer_node_rows", "rows one GraphSAGE layer "
-              "computes in that step", labels).set(n_dst)
+        gauge("glt.model.layer_edge_slots", "edge slots one layer "
+              "aggregates in that step", {"layer": str(i)}).set(n_edge)
+        gauge("glt.model.layer_node_rows", "rows one layer computes "
+              "in that step", {"layer": str(i)}).set(n_dst)
     return {"hops": hops}
 
 
@@ -604,9 +604,10 @@ def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
         resident; a ``jax.Array`` is used where it is).
       labels: dict ``node_type -> [N_t] int array`` — the sampler's
         ``input_type`` entry supplies the supervised target.
-      seed_hops: call the model with ``hops=sampler.hop_bounds`` (an
-        :class:`~glt_tpu.models.rgat.RGNN` then runs its last layer over
-        what reaches the seeds only).
+      seed_hops: call the model with ``hops=sampler.hop_bounds``: an
+        :class:`~glt_tpu.models.rgat.RGNN` then runs every layer over
+        the typed hop blocks that reach the seeds only (the same seed
+        logits; :func:`hop_trimming` records the extents).
 
     Returns ``step(state, seeds_blk [G, B], key) -> (state, losses [G],
     accs [G], overflows [G])``; ``overflows`` is each batch's
@@ -626,7 +627,7 @@ def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
     labels_tgt = jnp.asarray(np.asarray(labels[tgt]))
     widths, cap = sampler._widths, sampler._capacity
     hops = sampler.hop_bounds
-    trim = {"hops": hops} if seed_hops else {}
+    trim = hop_trimming(model, hops) if seed_hops else {}
     for t, n in cap.items():
         _metrics.gauge("glt.hetero.node_rows", "node rows of one type in "
                        "the batch of the last scanned hetero step built",

@@ -32,8 +32,9 @@ def main() -> int:
     ap.add_argument("--frontier-capacity", default=None)
     ap.add_argument("--group", type=int, default=2)
     ap.add_argument("--whole-last-layer", action="store_true",
-                    help="the step without seed_hops: the class-wide last "
-                         "layer over every sampled row and edge slot")
+                    help="the step without seed_hops: every layer, the "
+                         "class-wide last one too, over every sampled row "
+                         "and edge slot")
     args = ap.parse_args()
 
     import jax
@@ -101,6 +102,7 @@ def main() -> int:
         "node_capacity": sampler.node_capacity,
         "edge_slots": sum(b[-1] for b in
                           sampler.hop_bounds.edge_bounds.values()),
+        "layer_extents": model.layer_extents(sampler.hop_bounds),
         "memory_gb": gb, "compile_s": round(time.perf_counter() - t0, 1)}),
         flush=True)
     return 0

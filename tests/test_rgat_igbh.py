@@ -1,7 +1,7 @@
 """The IGBH R-GAT path at small sizes: upstream's ``RGNN('rgat')`` against
 a plain reference, the bipartite ``GATConv``, the hetero sampler's exact
-clamp and occupancy capacities, the last layer run over what reaches the
-seeds, and the scanned step's overflow channel."""
+clamp and occupancy capacities, every layer run over what reaches the
+seeds, and the scanned step's overflow channel and gauges."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -292,13 +292,18 @@ def test_capacities_at_occupancy_change_nothing_below_it_flag_and_mask():
         assert set(map(tuple, ea[et].T.tolist())) <= have
 
 
-@pytest.mark.parametrize("capped", [False, True])
+@pytest.mark.parametrize("capped", [False, True, "frontier"])
 def test_sampler_output_obeys_its_hop_bounds(capped):
     graphs = igbh_graphs(seed=2)
     caps = {"paper": 40, "author": 30, "institute": 6, "fos": 10} \
         if capped else None
+    # As the cell samples: a width per type and hop, none for a type
+    # nothing reaches at hop 1, one narrow enough to leave leaves.
+    fronts = {"paper": [12, 20], "author": [6, 8], "institute": [0, 3],
+              "fos": [6, 4]} if capped == "frontier" else None
     samp = HeteroNeighborSampler(graphs, [3, 2, 2], "paper", batch_size=6,
-                                 node_capacity=caps)
+                                 node_capacity=caps,
+                                 frontier_capacity=fronts)
     hb = samp.hop_bounds
     assert hb == hetero_hop_bounds(samp.edge_types, samp.num_neighbors,
                                    samp._widths, samp._capacity,
@@ -316,13 +321,44 @@ def test_sampler_output_obeys_its_hop_bounds(capped):
                 assert (col[blk][m[blk]] < hb.node_bounds[et[2]][k - 1]).all()
 
 
-def test_last_layer_over_the_seeds_hops_is_the_whole_model(setup):
-    """``RGNN(hops=)`` runs its last layer over seed rows, hop-1 edges and
-    hop-1 rows: the seeds' logits, the loss and every gradient are the
-    whole model's."""
+TRIM_LAYOUTS = {
+    # (node_capacity, frontier_capacity, must a batch overflow)
+    "uncapped": (None, None, False),
+    "overflowing": ({"paper": 12, "author": 6, "institute": 2, "fos": 3},
+                    None, True),
+    # As the cell runs: both, and no frontier for institutes at hop 1.
+    "as_the_cell": ({"paper": 52, "author": 44, "institute": 6, "fos": 12},
+                    {"paper": [12, 18], "author": [8, 18],
+                     "institute": [0, 4], "fos": [6, 6]}, False),
+    # A type with rows at hop 1 and no frontier there: the relations out
+    # of its hop-3 frontier are live, have no slot in the inner layers'
+    # blocks, and still give their bias to the rows of hop 1.
+    "no_frontier": (None, {"author": [0, 18]}, True),
+}
+
+
+@pytest.mark.parametrize("num_layers", [2, 3, 4])
+@pytest.mark.parametrize("layout", list(TRIM_LAYOUTS))
+def test_last_layer_over_the_seeds_hops_is_the_whole_model(
+        setup, layout, num_layers):
+    """``RGNN(hops=)`` runs EVERY layer over what reaches the seeds (the
+    name is PR 26's, when it was the last layer alone): the seeds'
+    logits, the loss and every gradient are the whole model's in float32,
+    for fewer, as many and more layers than hops."""
     s = setup
-    model, out, x, ei = s["model"], s["out"], s["x"], s["ei"]
-    hops = s["sampler"].hop_bounds
+    caps, fronts, overflows = TRIM_LAYOUTS[layout]
+    sampler = HeteroNeighborSampler(s["graphs"], [3, 2, 2], "paper",
+                                    batch_size=4, seed=0,
+                                    node_capacity=caps,
+                                    frontier_capacity=fronts)
+    hops = sampler.hop_bounds
+    model = RGNN(s["ets"], hidden_features=8, out_features=CLASSES,
+                 target_type="paper", num_layers=num_layers, heads=2,
+                 dropout_rate=0.0)
+    out, x, ei = batch_of(sampler, s["feats"], [0, 7, 21, 40])
+    assert bool((out.metadata or {}).get("overflow", False)) == overflows
+    params = model.init({"params": jax.random.PRNGKey(3)}, x, ei,
+                        out.edge_mask)
     y = jnp.asarray(s["labels"])[jnp.maximum(out.node["paper"], 0)]
 
     def loss(p, **kw):
@@ -330,16 +366,86 @@ def test_last_layer_over_the_seeds_hops_is_the_whole_model(setup):
         return seed_cross_entropy(logits, y, 4, out.node_mask["paper"])[0], \
             logits
 
-    (l1, z1), g1 = jax.jit(jax.value_and_grad(loss, has_aux=True))(
-        s["params"])
+    (l1, z1), g1 = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
     (l2, z2), g2 = jax.jit(jax.value_and_grad(
-        lambda p: loss(p, hops=hops), has_aux=True))(s["params"])
+        lambda p: loss(p, hops=hops), has_aux=True))(params)
     assert z2.shape == (4, CLASSES) and z1.shape[0] > 4
     np.testing.assert_allclose(z2, z1[:4], rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(l2, l1, rtol=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(g1),
-                    jax.tree_util.tree_leaves(g2)):
-        np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-7)
+    flat1, flat2 = (jax.tree_util.tree_leaves_with_path(g) for g in (g1, g2))
+    assert jax.tree_util.tree_structure(g1) == jax.tree_util.tree_structure(g2)
+    assert any(float(jnp.abs(v).max()) > 0 for _, v in flat1)
+    for (path, a), (_, b) in zip(flat1, flat2):
+        np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-7,
+                                   err_msg=str(path))
+    # Every layer is cut, not the last alone: layer l emits what layer
+    # l + 1 reads, the first layers under a clamped depth run whole, and
+    # with fewer layers than hops nothing touches the outer blocks.
+    ext = model.layer_extents(hops)
+    rows = sum(b[-1] for b in hops.node_bounds.values())
+    slots = sum(b[-1] for b in hops.edge_bounds.values())
+    assert [e[2] for e in ext[:-1]] == [e[0] for e in ext[1:]]
+    assert ext[-1][2] == 4 and ext[-1][1] < ext[-2][1]
+    assert all(a[1] >= b[1] and a[2] >= b[2] for a, b in zip(ext, ext[1:]))
+    assert (ext[0][1] == slots) == (num_layers >= 3)
+    assert ext[0][2] < rows or num_layers == 4 or caps is not None
+    assert ext[0] == (rows, slots, rows) or num_layers < 4
+
+
+def test_trimming_engages_in_the_scanned_step_and_says_so(setup):
+    """``seed_hops=True`` records the extents of every layer when the step
+    is built; without it that build sets nothing.  A batch that ``hops``
+    does not describe raises."""
+    from glt_tpu.obs import metrics
+
+    s = setup
+    sampler, model, tx = s["sampler"], s["model"], optax.adam(1e-3)
+    hops = sampler.hop_bounds
+    ext = model.layer_extents(hops)
+    assert ext == [tuple(sum(part.values()) for part in layer)
+                   for layer in model.typed_extents(hops)]
+
+    def build(**kw):
+        metrics.reset()
+        metrics.enable()
+        try:
+            make_scanned_hetero_train_step(
+                model, tx, sampler, s["feats"], {"paper": s["labels"]}, 4,
+                **kw)
+            return metrics.snapshot()
+        finally:
+            metrics.disable()
+            metrics.reset()
+
+    snap = build(seed_hops=True)
+    assert snap["glt.model.edge_slots"] == sum(
+        b[-1] for b in hops.edge_bounds.values()) == ext[0][1]
+    assert snap["glt.model.node_rows"] == sum(
+        b[-1] for b in hops.node_bounds.values()) == ext[0][0]
+    got = [(snap["glt.model.layer_edge_slots{layer=%d}" % l],
+            snap["glt.model.layer_node_rows{layer=%d}" % l])
+           for l in (1, 2, 3)]
+    assert got == [(e[1], e[2]) for e in ext]
+    assert got[1][0] < got[0][0] and got[1][1] < got[0][1]
+    assert got[2] == (sum(b[1] for et, b in hops.edge_bounds.items()
+                          if et[2] == "paper"), 4)
+    snap = build(seed_hops=False)
+    assert snap["glt.model.edge_slots"] == 0
+    assert all(v == 0 for k, v in snap.items()
+               if k.startswith("glt.model.layer_"))
+
+    x, ei, em = s["x"], s["ei"], s["out"].edge_mask
+    short = dict(x, author=x["author"][:-1])
+    with pytest.raises(ValueError, match="not laid out"):
+        model.apply(s["params"], short, ei, em, hops=hops)
+    et = ("paper", "cites", "paper")
+    with pytest.raises(ValueError, match="not laid out"):
+        model.apply(s["params"], x, {**ei, et: ei[et][:, :-1]},
+                    {**em, et: em[et][:-1]}, hops=hops)
+    with pytest.raises(ValueError, match="not laid out"):
+        model.apply(s["params"], x, ei, em, hops=hops._replace(
+            node_bounds={t: b for t, b in hops.node_bounds.items()
+                         if t != "fos"}))
 
 
 def test_scanned_hetero_step_reports_overflow_and_counts_it(setup):
