@@ -1224,7 +1224,7 @@ class UnboundedQueuePut(Rule):
 class DispatchInEpochLoop(Rule):
     """Per-batch host round-trips inside an epoch driver's batch loop.
 
-    The fused-epoch contract (glt_tpu/models/train.py "The fused
+    The fused-epoch contract (docs/architecture.md "The fused
     epoch"): an epoch driver dispatches compiled programs and fetches
     device values ONCE at the epoch boundary — a device->host fetch
     (``jax.device_get`` / ``np.asarray`` / ``.item()`` /

@@ -222,7 +222,7 @@ class RGNN(nn.Module):
     def layer_extents(self, hops) -> List[Tuple[int, int, int]]:
         """``(source rows, edge slots, destination rows)`` of each layer
         under ``hops``, summed over types and relations (what
-        :func:`~glt_tpu.models.train.hop_trimming` records)."""
+        :func:`~glt_tpu.models.step.hop_trimming` records)."""
         return [tuple(sum(part.values()) for part in layer)
                 for layer in self.typed_extents(hops)]
 

@@ -1,39 +1,35 @@
-"""Jitted supervised train/eval steps for sampled batches.
+"""Train and eval steps over sampled batches, and their epoch drivers.
 
 The reference leaves training loops to user PyTorch code
-(examples/train_sage_ogbn_products.py); here the train step is part of the
-framework so the whole batch -> loss -> grad -> update path is one XLA
-program.  Loss is masked cross-entropy over the **seed rows only** — seeds
-occupy ``node[:batch_size]`` by the sampler's first-occurrence contract.
-
-**The fused epoch.**  The canonical epoch driver is the *scanned* path
-(:func:`make_scanned_node_train_step` + :func:`run_scanned_epoch`):
-sample -> dedup -> gather -> fwd/bwd -> update for ``G`` consecutive
-batches compiles as ONE XLA program per scan group, so intermediate ids
-never round-trip through host dispatch and per-batch host work drops to
-one seed-block feed per ``G`` batches.  An earlier "overlapped" driver
-(``make_pipelined_train_step`` — one program fusing "train batch k"
-with "sample batch k+1") was DELETED in the gather-wall round: it never
-beat the serial loop in three bench rounds, and the scanned route
-carries the same resume/cache/donation seams.
+(examples/train_sage_ogbn_products.py); here the step is part of the
+framework, so that sample -> gather -> forward/backward -> update is one
+XLA program.  Every factory in this file wraps the one body of
+:mod:`~glt_tpu.models.step` and owns what differs: how a batch is
+sampled and gathered, and whether the body runs eagerly on a loader's
+``Batch`` or under a ``lax.scan`` over ``G`` seed batches (the canonical
+epoch: :func:`make_scanned_node_train_step` + :func:`run_scanned_epoch`,
+one host dispatch and one seed feed per ``G`` batches).  The supervised
+loss is masked cross-entropy over the rows of real seeds, which lead the
+node list by the sampler's first-occurrence contract.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Callable, NamedTuple
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
-import optax
 
 from ..obs import compilewatch as _compilewatch
 from ..obs import device as _device
 from ..obs import flight as _flight
 from ..obs import metrics as _metrics
 from ..obs import profiler as _profiler
-from ..obs.scopes import scoped
 from ..obs.trace import span as _span
 from ..typing import PADDING_ID
+from .step import (TrainState, gated_update, graph_inputs,  # noqa: F401
+                   hop_trimming, loss_and_grads, seed_cross_entropy,
+                   seed_loss)
 
 # Epoch-driver instrumentation (docs/observability.md).  Only the HOST
 # loops are instrumented — the jitted step bodies must stay span-free
@@ -48,12 +44,6 @@ _M_BLOCK_MS = _metrics.histogram(
     "wall per [G, B] block: dispatch + (when a hook syncs) device wait")
 
 
-class TrainState(NamedTuple):
-    params: Any
-    opt_state: Any
-    step: jnp.ndarray
-
-
 def create_train_state(model, rng, sample_batch, tx) -> TrainState:
     params = model.init({"params": rng}, sample_batch.x,
                         sample_batch.edge_index, sample_batch.edge_mask)
@@ -63,81 +53,47 @@ def create_train_state(model, rng, sample_batch, tx) -> TrainState:
                       step=jnp.zeros((), jnp.int32))
 
 
-@scoped("glt.step.loss")
-def seed_cross_entropy(logits, y, batch_size: int, node_mask,
-                       num_seeds=None):
-    """Mean CE over valid seed rows (first ``batch_size`` slots).
+def _batch_inputs(batch):
+    """:func:`~glt_tpu.models.step.graph_inputs` of a loader ``Batch``,
+    which carries its seed ids and no per-hop counts: the rows that hold
+    a seed are as many as the unique valid ids among them."""
+    from ..ops.unique import unique_first_occurrence
 
-    ``num_seeds`` (a traced count, ``SamplerOutput.num_sampled_nodes[0]``)
-    keeps the loss to the rows that hold a seed: a partly padded seed
-    batch has fewer unique seeds than ``batch_size``, and the rows behind
-    them hold labelled hop-1 nodes.
-    """
-    sl = logits[:batch_size]
-    sy = y[:batch_size]
-    valid = (sy >= 0) & node_mask[:batch_size]
-    if num_seeds is not None:
-        valid &= jnp.arange(batch_size, dtype=jnp.int32) < num_seeds
-    sy_safe = jnp.where(valid, sy, 0)
-    ce = optax.softmax_cross_entropy_with_integer_labels(sl, sy_safe)
-    n = jnp.maximum(valid.sum(), 1)
-    loss = jnp.where(valid, ce, 0).sum() / n
-    acc = jnp.where(valid, jnp.argmax(sl, -1) == sy_safe, False).sum() / n
-    return loss, acc
-
-
-def hop_trimming(model, hops) -> dict:
-    """``model.apply`` keywords that run ``model`` trimmed to ``hops``.
-
-    A model that trims by the sampler's hop-block layout says so by
-    having ``layer_extents(hops)`` and taking ``hops=`` (``GraphSAGE``;
-    ``RGNN``, typed: summed over types and relations); any other model
-    runs whole (``{}``).  Engagement is a trace-time fact, recorded here
-    when the step is built: ``glt.model.layer_edge_slots{layer=l}`` /
-    ``glt.model.layer_node_rows{layer=l}`` (the rows layer ``l``
-    computes) against ``glt.model.edge_slots`` / ``.node_rows``.
-    """
-    if not hasattr(model, "layer_extents"):
-        return {}
-    gauge, typed = _metrics.gauge, isinstance(hops.node_bounds, dict)
-    whole = [sum(b[-1] for b in part.values()) if typed else part[-1]
-             for part in (hops.edge_bounds, hops.node_bounds)]
-    gauge("glt.model.edge_slots", "edge slots of the sampled batch of the "
-          "last hop-trimmed step built").set(whole[0])
-    gauge("glt.model.node_rows", "node rows of the sampled batch of the "
-          "last hop-trimmed step built").set(whole[1])
-    for i, (_, n_edge, n_dst) in enumerate(model.layer_extents(hops), 1):
-        gauge("glt.model.layer_edge_slots", "edge slots one layer "
-              "aggregates in that step", {"layer": str(i)}).set(n_edge)
-        gauge("glt.model.layer_node_rows", "rows one layer computes "
-              "in that step", {"layer": str(i)}).set(n_dst)
-    return {"hops": hops}
+    return batch.edge_index, batch.edge_mask, (
+        batch.node_mask, unique_first_occurrence(batch.batch).count[None])
 
 
 def make_train_step(model, tx, batch_size: int,
                     dropout_seed: int = 0) -> Callable:
-    """Build a jitted ``(state, batch) -> (state, loss, acc)`` step."""
+    """Build a jitted ``(state, batch) -> (state, loss, acc)`` step over a
+    loader's ``Batch`` (no sampler layout, so the model runs whole)."""
+    grads_of = loss_and_grads(model, seed_loss(batch_size))
+    update = gated_update(tx)
 
     @jax.jit
     def train_step(state: TrainState, batch):
         rng = jax.random.fold_in(jax.random.PRNGKey(dropout_seed), state.step)
-
-        def loss_fn(params):
-            logits = model.apply(params, batch.x, batch.edge_index,
-                                 batch.edge_mask, train=True,
-                                 rngs={"dropout": rng})
-            return seed_cross_entropy(logits, batch.y, batch_size,
-                                      batch.node_mask)
-
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params)
-        with jax.named_scope("glt.step.update"):
-            updates, opt_state = tx.update(grads, state.opt_state,
-                                           state.params)
-            params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1), loss, acc
+        edge_index, edge_mask, aux = _batch_inputs(batch)
+        loss, acc, grads = grads_of(state.params, batch.x, edge_index,
+                                    edge_mask, batch.y, aux, rng)
+        return update(state, grads, jnp.any(batch.batch >= 0)), loss, acc
 
     return train_step
+
+
+def make_eval_step(model, batch_size: int) -> Callable:
+    """``(params, batch) -> (loss, acc)``: the same loss on an
+    evaluation-mode forward."""
+    loss = seed_loss(batch_size)
+
+    @jax.jit
+    def eval_step(params, batch):
+        edge_index, edge_mask, aux = _batch_inputs(batch)
+        logits = model.apply(params, batch.x, edge_index, edge_mask,
+                             train=False)
+        return loss(logits, batch.y, aux)
+
+    return eval_step
 
 
 def make_gather_xy(id2index=None, dedup: bool = False,
@@ -232,6 +188,21 @@ def make_cached_gather_xy(id2index=None, force: str = "auto"):
     return gather_xy
 
 
+def _device_rows(rows, what: str):
+    """``rows`` as a :class:`~glt_tpu.data.feature.Feature` that is whole
+    in HBM, which the scanned steps need."""
+    import numpy as np
+
+    from ..data.feature import Feature
+
+    if not isinstance(rows, Feature):
+        rows = Feature(np.asarray(rows))
+    if rows.hot_count < rows.size:
+        raise ValueError(
+            f"scanned {what} step needs device-resident rows")
+    return rows
+
+
 def _check_cache(feature_cache, rows_dtype, dim):
     """The cache table's dtype/width must match the feature rows, or the
     cached-path ``x`` would silently change dtype vs the naive path."""
@@ -245,113 +216,41 @@ def _check_cache(feature_cache, rows_dtype, dim):
             f"feature_cache dim {feature_cache.dim} != feature dim {dim}")
 
 
-def make_scanned_node_train_step(model, tx, sampler, rows, labels,
-                                 batch_size: int, dropout_seed: int = 0,
-                                 dedup: bool = False, feature_cache=None,
-                                 gather_force: str = "auto",
-                                 fused_frontier: str = "off"):
-    """ONE jitted program trains ``G`` consecutive seed-node batches.
+def _scanned_supervised(model, tx, batch_size: int, dropout_seed: int,
+                        hops, batch_of, arrays_of, label: str,
+                        feature_cache=None):
+    """The wrapper of both scanned supervised steps: ONE jitted
+    ``lax.scan`` of the body over ``seeds_blk [G, B]``, as
+    ``step(state, seeds_blk, key) -> (state, losses [G], accs [G],
+    overflows [G])`` compiling under the compilewatch ``label``.
 
-    The supervised-node analog of :func:`make_scanned_link_train_step`:
-    per batch — multi-hop sampling, feature/label gather, fwd/bwd,
-    optimizer update — rolled into a ``lax.scan`` so host dispatch and
-    per-batch seed transfers are paid once per ``G`` batches.  Config-1
-    is device-bound at batch 1024 (the scan amortises only the ~2 ms
-    dispatch + seed-feed overhead), but smaller-batch supervised configs
-    are dispatch-bound exactly like the link/subgraph paths where the
-    same trick bought 7–17×.
-
-    A model that trims by the hop-block layout (``GraphSAGE``) runs each
-    layer over ``sampler.hop_bounds`` only (:func:`hop_trimming`): the
-    same seed logits, loss and gradients from fewer edge slots.  The loss
-    is over the rows of real seeds (``num_sampled_nodes[0]``).
-
-    Returns ``step(state, seeds_blk [G, B], key) -> (state, losses [G],
-    accs [G], overflows [G])``; seed blocks are -1 padded (fully-padded
-    trailing batches contribute zero-valid losses).  ``overflows`` is
-    each batch's occupancy-cap overflow flag (all zeros for uncapped
-    samplers) — with a capped sampler, overflowed batches train with
-    their excess-node edges masked; monitor the flags and re-run hot
-    batches at full capacity (or raise the cap) if the rate matters.
-
-    ``dedup=True`` switches the in-scan feature gather to the dedup-aware
-    path; ``feature_cache`` threads a cross-batch HBM cache through the
-    scan carry AND across blocks (buffers donated — read the live state
-    via ``step.feature_cache()``).  Both leave ``x`` bit-identical.
-    ``gather_force`` pins the row-gather kernel inside the fused program
-    ('auto' serves the :func:`~glt_tpu.ops.gather_pallas.
-    autotune_gather_rows` winner for this table/batch shape — autotune
-    at the CAPPED shape before building the step so the fused gather
-    runs the tile/ring point measured for its own batch size).
-    ``fused_frontier`` != 'off' routes the in-scan feature gather through
-    the one-dispatch sample->dedup->gather kernel
-    (:func:`~glt_tpu.ops.fused_frontier.fused_frontier`; bit-identical
-    ``x``, VMEM-overflowing frontiers fall back to the unfused path).
-    The cross-batch ``feature_cache`` wins when both are set — its
-    unique-pass bookkeeping IS the fusion's dedup half, so the fused
-    kernel only applies to the cache-less gather.  The kernel compiles
-    under the ``scanned_node_step`` compilewatch label like everything
-    else in the scan.
+    ``batch_of(arrays, cache, seeds, key) -> (cache, out, x, y)`` is the
+    factory's own part: one batch sampled and gathered out of
+    ``arrays_of()``, which ride as jit arguments.  ``cache`` is the
+    feature cache (or ``None``): it rides the scan carry and, donated,
+    the closure between calls (``step.feature_cache()`` reads it,
+    ``step.set_feature_cache`` is the checkpoint-restore seam of
+    glt_tpu.ckpt).  The dropout key is ``fold_in(PRNGKey(dropout_seed),
+    state.step)``; a slot without a real seed is a no-op
+    (:func:`~glt_tpu.models.step.gated_update`); ``overflows`` is each
+    batch's capacity-overflow flag (zeros for an uncapped sampler): a
+    flagged batch trained with its excess nodes' edges masked.
     """
-    import numpy as np
+    grads_of = loss_and_grads(model, seed_loss(batch_size), hops)
+    update = gated_update(tx)
 
-    from ..data.feature import Feature
-
-    g = sampler.graph
-    labels = jnp.asarray(labels)
-    if not isinstance(rows, Feature):
-        rows = Feature(np.asarray(rows))
-    if rows.hot_count < rows.size:
-        raise ValueError("scanned node step needs device-resident rows")
-    hot_rows = rows.hot_rows
-    if feature_cache is not None:
-        _check_cache(feature_cache, hot_rows.dtype, hot_rows.shape[-1])
-        cached_xy = make_cached_gather_xy(rows.id2index,
-                                          force=gather_force)
-    gather_xy = make_gather_xy(rows.id2index, dedup=dedup,
-                               force=gather_force, fused=fused_frontier)
-    trim = hop_trimming(model, sampler.hop_bounds)
-
-    @partial(jax.jit, donate_argnums=(6,))
-    def run(indptr, indices, eids, rows_arg, labels_arg,
-            state: TrainState, cache, seeds_blk, key):
+    @partial(jax.jit, donate_argnums=(2,))
+    def run(arrays, state: TrainState, cache, seeds_blk, key):
         def body(carry, inp):
             st, cache = carry
             seeds, k = inp
-            out = sampler._sample_impl(indptr, indices, eids, seeds, k)
-            if cache is None:
-                x, y = gather_xy(rows_arg, labels_arg, out)
-            else:
-                cache, x, y = cached_xy(cache, rows_arg, labels_arg, out)
-            edge_index = jnp.stack([out.row, out.col])
+            cache, out, x, y = batch_of(arrays, cache, seeds, k)
+            edge_index, edge_mask, aux = graph_inputs(out)
             rng = jax.random.fold_in(jax.random.PRNGKey(dropout_seed),
                                      st.step)
-
-            def loss_fn(p):
-                logits = model.apply(p, x, edge_index, out.edge_mask,
-                                     train=True, rngs={"dropout": rng},
-                                     **trim)
-                return seed_cross_entropy(logits, y, batch_size,
-                                          out.node_mask,
-                                          out.num_sampled_nodes[0])
-
-            (loss, acc), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(st.params)
-
-            def apply(s):
-                with jax.named_scope("glt.step.update"):
-                    updates, opt_state = tx.update(grads, s.opt_state,
-                                                   s.params)
-                    params = optax.apply_updates(s.params, updates)
-                return TrainState(params, opt_state, s.step + 1)
-
-            # Fully-padded trailing batches (block padding) must be
-            # no-ops: their grads are zero, but a stateful optimizer
-            # (adam momentum decay) would still move params and the step
-            # bump would shift later dropout keys — gating keeps the
-            # scanned path equivalent to the serial loop over REAL
-            # batches only.
-            st = jax.lax.cond(jnp.any(seeds >= 0), apply, lambda s: s, st)
+            loss, acc, grads = grads_of(st.params, x, edge_index,
+                                        edge_mask, y, aux, rng)
+            st = update(st, grads, jnp.any(seeds >= 0))
             ovf = (out.metadata["overflow"].astype(jnp.int32)
                    if out.metadata else jnp.zeros((), jnp.int32))
             return (st, cache), (loss, acc, ovf)
@@ -361,26 +260,69 @@ def make_scanned_node_train_step(model, tx, sampler, rows, labels,
             body, (state, cache), (seeds_blk, keys))
         return state, cache, losses, accs, ovfs
 
-    cache_holder = {"cache": feature_cache}
+    holder = {"cache": feature_cache}
 
     def step(state: TrainState, seeds_blk, key):
-        with _compilewatch.label("scanned_node_step"):
-            state, cache_holder["cache"], losses, accs, ovfs = run(
-                g.indptr, g.indices, g.gather_edge_ids, hot_rows,
-                labels, state, cache_holder["cache"],
+        with _compilewatch.label(label):
+            state, holder["cache"], losses, accs, ovfs = run(
+                arrays_of(), state, holder["cache"],
                 jnp.asarray(seeds_blk, jnp.int32), key)
         return state, losses, accs, ovfs
 
-    step.feature_cache = lambda: cache_holder["cache"]
-
-    def _set_feature_cache(new_cache):
-        # Checkpoint-restore seam (glt_tpu.ckpt): the cross-block cache
-        # rides the closure, so a resumed run pushes the captured
-        # FeatureCacheState back in here before its first block.
-        cache_holder["cache"] = new_cache
-
-    step.set_feature_cache = _set_feature_cache
+    step.feature_cache = lambda: holder["cache"]
+    step.set_feature_cache = lambda cache: holder.update(cache=cache)
     return step
+
+
+def make_scanned_node_train_step(model, tx, sampler, rows, labels,
+                                 batch_size: int, dropout_seed: int = 0,
+                                 dedup: bool = False, feature_cache=None,
+                                 gather_force: str = "auto",
+                                 fused_frontier: str = "off"):
+    """ONE jitted program trains ``G`` consecutive seed-node batches:
+    multi-hop sampling, :func:`make_gather_xy` and the step's body under
+    ``lax.scan`` (:func:`_scanned_supervised`); the model is trimmed to
+    ``sampler.hop_bounds`` where it can be.
+
+    Returns ``step(state, seeds_blk [G, B], key) -> (state, losses [G],
+    accs [G], overflows [G])``; seed blocks are -1 padded.  With a capped
+    sampler, monitor ``overflows`` and re-run hot batches at full
+    capacity (or raise the cap) if the rate matters.
+
+    The options choose the in-scan feature gather and leave ``x``
+    bit-identical: ``dedup`` / ``gather_force`` / ``fused_frontier`` as
+    :func:`make_gather_xy`'s ``dedup`` / ``force`` / ``fused`` ('auto'
+    serves the autotune winner for this table/batch shape — autotune at
+    the CAPPED shape before building the step); ``feature_cache`` threads
+    a cross-batch HBM cache (:func:`make_cached_gather_xy`) through the
+    scan carry AND across blocks (buffers donated — read the live state
+    via ``step.feature_cache()``), and wins over ``fused_frontier``: its
+    unique-pass bookkeeping IS the fusion's dedup half.
+    """
+    g = sampler.graph
+    labels = jnp.asarray(labels)
+    rows = _device_rows(rows, "node")
+    hot_rows = rows.hot_rows
+    if feature_cache is not None:
+        _check_cache(feature_cache, hot_rows.dtype, hot_rows.shape[-1])
+        cached_xy = make_cached_gather_xy(rows.id2index,
+                                          force=gather_force)
+    gather_xy = make_gather_xy(rows.id2index, dedup=dedup,
+                               force=gather_force, fused=fused_frontier)
+
+    def batch_of(arrays, cache, seeds, k):
+        indptr, indices, eids, rows_arg, labels_arg = arrays
+        out = sampler._sample_impl(indptr, indices, eids, seeds, k)
+        if cache is None:
+            x, y = gather_xy(rows_arg, labels_arg, out)
+        else:
+            cache, x, y = cached_xy(cache, rows_arg, labels_arg, out)
+        return cache, out, x, y
+
+    return _scanned_supervised(
+        model, tx, batch_size, dropout_seed, sampler.hop_bounds, batch_of,
+        lambda: (g.indptr, g.indices, g.gather_edge_ids, hot_rows, labels),
+        "scanned_node_step", feature_cache)
 
 
 def node_seed_blocks(train_idx, batch_size: int, group: int, rng):
@@ -485,34 +427,6 @@ def run_scanned_epoch(step, state, train_idx, batch_size: int,
     return state, losses, accs, ovf
 
 
-def hetero_init_shapes(sampler, feats, rows_of):
-    """Zero-filled ``(x, edge_index, edge_mask)`` dummies matching a
-    hetero sampler's static output shapes — the shared shape builder for
-    :func:`init_hetero_state` and ``parallel.init_hetero_dist_state``.
-
-    ``sampler`` exposes ``node_capacity`` / ``hop_widths`` /
-    ``edge_types`` / ``num_neighbors`` (both the single-device and
-    distributed hetero samplers do); ``rows_of(feats[t])`` returns the
-    per-type ``[N_t, d]`` array whose dtype/width the dummies mirror.
-    """
-    from ..typing import reverse_edge_type
-
-    capacity = sampler.node_capacity
-    widths = sampler.hop_widths
-    x = {t: jnp.zeros((max(capacity[t], 1), rows_of(feats[t]).shape[-1]),
-                      rows_of(feats[t]).dtype)
-         for t in feats if t in capacity}
-    ei, mask = {}, {}
-    for et in sampler.edge_types:
-        fanouts = sampler.num_neighbors[et]
-        ecap = sum(widths[hop][et[0]] * f
-                   for hop, f in enumerate(fanouts) if f > 0)
-        rev = reverse_edge_type(et)
-        ei[rev] = jnp.full((2, max(ecap, 1)), PADDING_ID, jnp.int32)
-        mask[rev] = jnp.zeros((max(ecap, 1),), bool)
-    return x, ei, mask
-
-
 def _resident_rows(f, whole: bool = True):
     """The ``[N_t, d]`` device array of one type's table: a
     :class:`~glt_tpu.data.feature.Feature`'s hot rows (``whole``: all of
@@ -530,12 +444,12 @@ def _resident_rows(f, whole: bool = True):
 
 
 def init_hetero_state(model, tx, sampler, feats, rng) -> TrainState:
-    """Params/opt-state for hetero models from a
-    :class:`~glt_tpu.sampler.hetero_neighbor_sampler.HeteroNeighborSampler`'s
-    edge types and the tables' widths (the single-device analog of
-    ``parallel.init_hetero_dist_state``).  Parameter shapes follow the
-    feature widths, not the row counts: one row and one edge slot each,
-    so that initialising never runs the model at the batch's size."""
+    """Params/opt-state for hetero models from a hetero sampler's edge
+    types (:class:`~glt_tpu.sampler.hetero_neighbor_sampler.
+    HeteroNeighborSampler` or the distributed one) and the tables'
+    widths.  Parameter shapes follow the feature widths, not the row
+    counts: one row and one edge slot each, so that initialising never
+    runs the model at the batch's size."""
     from ..typing import reverse_edge_type
 
     rows = {t: _resident_rows(f, whole=False) for t, f in feats.items()
@@ -583,20 +497,16 @@ _M_HETERO_OVF = _metrics.counter(
 def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
                                    batch_size: int, dropout_seed: int = 0,
                                    seed_hops: bool = False):
-    """ONE jitted program trains ``G`` consecutive hetero seed batches.
-
-    The hetero analog of :func:`make_scanned_node_train_step`: per batch
-    — multi-type multi-hop sampling
-    (:class:`HeteroNeighborSampler._sample_impl`), per-type feature
-    gather, target-type label gather, fwd/bwd, update — under
-    ``lax.scan``.  Sized by its sampler: per-type node rows and
-    per-relation edge slots are the sampler's static capacities (the
-    product of the fanouts only where neither the exact clamp nor a
-    calibrated ``node_capacity`` bounds them), recorded when the step is
-    built as ``glt.hetero.node_rows{type}`` and
-    ``glt.hetero.edge_slots{edge_type}``.  Rows keep the table's dtype
-    into the model (16-bit rows meet the first projection as they are).
-    The loss is over the rows of real seeds.
+    """The hetero analog of :func:`make_scanned_node_train_step`: typed
+    multi-hop sampling (:class:`HeteroNeighborSampler._sample_impl`),
+    :func:`hetero_gather_xy` and the step's body under ``lax.scan``.
+    Sized by its sampler: per-type node rows and per-relation edge slots
+    are the sampler's static capacities (the product of the fanouts only
+    where neither the exact clamp nor a calibrated ``node_capacity``
+    bounds them), recorded when the step is built as
+    ``glt.hetero.node_rows{type}`` and ``glt.hetero.edge_slots{edge_type}``.
+    Rows keep the table's dtype into the model (16-bit rows meet the
+    first projection as they are).
 
     Args:
       sampler: a :class:`HeteroNeighborSampler`.
@@ -604,30 +514,25 @@ def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
         resident; a ``jax.Array`` is used where it is).
       labels: dict ``node_type -> [N_t] int array`` — the sampler's
         ``input_type`` entry supplies the supervised target.
-      seed_hops: call the model with ``hops=sampler.hop_bounds``: an
+      seed_hops: hand the model ``sampler.hop_bounds``: an
         :class:`~glt_tpu.models.rgat.RGNN` then runs every layer over
         the typed hop blocks that reach the seeds only (the same seed
-        logits; :func:`hop_trimming` records the extents).
+        logits); ``False`` runs it whole.
 
     Returns ``step(state, seeds_blk [G, B], key) -> (state, losses [G],
-    accs [G], overflows [G])``; ``overflows`` is each batch's
-    capacity-overflow flag (zeros for a sampler without
-    ``node_capacity``): a flagged batch trained with its excess nodes'
-    edges masked.
+    accs [G], overflows [G])``.
     """
     import numpy as np
 
     from ..typing import as_str
 
     tgt = sampler.input_type
-    graphs = sampler.graphs
     graph_arrays = {et: (g.indptr, g.indices, g.gather_edge_ids)
-                    for et, g in graphs.items()}
+                    for et, g in sampler.graphs.items()}
     rows = {t: _resident_rows(f) for t, f in feats.items()}
     labels_tgt = jnp.asarray(np.asarray(labels[tgt]))
     widths, cap = sampler._widths, sampler._capacity
     hops = sampler.hop_bounds
-    trim = hop_trimming(model, hops) if seed_hops else {}
     for t, n in cap.items():
         _metrics.gauge("glt.hetero.node_rows", "node rows of one type in "
                        "the batch of the last scanned hetero step built",
@@ -637,69 +542,66 @@ def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
                        "relation in the batch of the last scanned hetero "
                        "step built", {"edge_type": as_str(et)}).set(b[-1])
 
-    @jax.jit
-    def run(graph_args, rows_args, labels_arg, state: TrainState,
-            seeds_blk, key):
-        def body(carry, inp):
-            st = carry
-            seeds, k = inp
-            out = sampler._sample_impl(widths, cap, graph_args,
-                                       {tgt: seeds}, k)
-            x, y = hetero_gather_xy(rows_args, labels_arg, out, batch_size)
-            edge_index = {et: jnp.stack([out.row[et], out.col[et]])
-                          for et in out.row}
-            rng = jax.random.fold_in(jax.random.PRNGKey(dropout_seed),
-                                     st.step)
+    def batch_of(arrays, cache, seeds, k):
+        graph_args, rows_args, labels_arg = arrays
+        out = sampler._sample_impl(widths, cap, graph_args, {tgt: seeds}, k)
+        return (cache, out) + hetero_gather_xy(rows_args, labels_arg, out,
+                                               batch_size)
 
-            def loss_fn(p):
-                logits = model.apply(p, x, edge_index, out.edge_mask,
-                                     train=True, rngs={"dropout": rng},
-                                     **trim)
-                return seed_cross_entropy(logits, y, batch_size,
-                                          out.node_mask[tgt],
-                                          out.num_sampled_nodes[tgt][0])
-
-            (loss, acc), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(st.params)
-
-            def apply(s):
-                with jax.named_scope("glt.step.update"):
-                    updates, opt_state = tx.update(grads, s.opt_state,
-                                                   s.params)
-                    params = optax.apply_updates(s.params, updates)
-                return TrainState(params, opt_state, s.step + 1)
-
-            st = jax.lax.cond(jnp.any(seeds >= 0), apply, lambda s: s, st)
-            ovf = (out.metadata["overflow"].astype(jnp.int32)
-                   if out.metadata else jnp.zeros((), jnp.int32))
-            return st, (loss, acc, ovf)
-
-        keys = jax.random.split(key, seeds_blk.shape[0])
-        state, (losses, accs, ovfs) = jax.lax.scan(body, state,
-                                                   (seeds_blk, keys))
-        return state, losses, accs, ovfs
-
-    def step(state: TrainState, seeds_blk, key):
-        with _compilewatch.label("scanned_hetero_step"):
-            return run(graph_arrays, rows, labels_tgt, state,
-                       jnp.asarray(seeds_blk, jnp.int32), key)
-
+    step = _scanned_supervised(
+        model, tx, batch_size, dropout_seed, hops if seed_hops else None,
+        batch_of, lambda: (graph_arrays, rows, labels_tgt),
+        "scanned_hetero_step")
     step.overflow_counter = _M_HETERO_OVF
     return step
 
 
+def _take_rows(rows_arg, id2index, node):
+    """``x`` of a node list by plain ``jnp.take``, zero on padding: the
+    link and subgraph steps' gather.  (:func:`make_gather_xy` goes
+    through ``gather_rows``, which lowers to another program.)"""
+    valid = node >= 0
+    gid = jnp.where(valid, node, 0)
+    ridx = (gid if id2index is None
+            else jnp.take(id2index, gid, axis=0, mode="clip"))
+    return jnp.where(valid[:, None],
+                     jnp.take(rows_arg, ridx, axis=0, mode="clip"), 0)
+
+
+def _scan_unsupervised(tx, grads_of, batch_of):
+    """The jitted scan of the link and subgraph steps, which carry
+    ``(params, opt_state)`` and report losses only: ``run(arrays, params,
+    opt_state, blocks, key)`` with ``batch_of(arrays, *slot, key) -> (out, x, y,
+    aux, any_valid)`` one batch of the block's leading axis.  The model
+    runs in evaluation mode (no dropout key), as it always has here."""
+    update = gated_update(tx)
+
+    @jax.jit
+    def run(arrays, params, opt_state, blocks, key):
+        def body(st, inp):
+            out, x, y, aux, any_valid = batch_of(arrays, *inp)
+            edge_index, edge_mask, _ = graph_inputs(out)
+            loss, _, grads = grads_of(st.params, x, edge_index, edge_mask,
+                                      y, aux, None)
+            return update(st, grads, any_valid), loss
+
+        keys = jax.random.split(key, blocks[0].shape[0])
+        st, losses = jax.lax.scan(
+            body, TrainState(params, opt_state, jnp.zeros((), jnp.int32)),
+            blocks + (keys,))
+        return st.params, st.opt_state, losses
+
+    return run
+
+
 def make_scanned_link_train_step(model, tx, sampler, rows, loss_fn,
                                  neg_sampling=None, group: int = 8):
-    """ONE jitted program trains ``group`` consecutive seed-edge batches.
-
-    Per batch — negative sampling (strict trials + padding), multi-hop
-    sampling, feature gather, fwd/bwd, optimizer update — rolled into a
-    ``lax.scan``, so host dispatch cost is paid once per ``group``
-    batches instead of per batch.  This is the TPU answer to the
-    reference's per-worker in-flight batch concurrency
-    (dist_options.py:21-100): link-prediction configs run small batches
-    whose per-batch device time is comparable to dispatch latency, so
-    G-batching moves epoch time directly.
+    """ONE jitted program trains ``group`` consecutive seed-edge batches:
+    negative sampling (strict trials + padding), multi-hop sampling,
+    feature gather and the step's body under ``lax.scan`` — the TPU
+    answer to the reference's per-worker in-flight batch concurrency
+    (dist_options.py:21-100): link-prediction batches are small enough
+    that dispatch latency rivals their device time.
 
     Args:
       sampler: :class:`~glt_tpu.sampler.neighbor_sampler.NeighborSampler`.
@@ -710,18 +612,11 @@ def make_scanned_link_train_step(model, tx, sampler, rows, loss_fn,
       neg_sampling: the loader's :class:`NegativeSampling` (or None).
 
     Returns ``step(params, opt_state, src [G, q], dst [G, q], key) ->
-    (params, opt_state, losses [G])``; seed-edge blocks are -1 padded.
+    (params, opt_state, losses [G])``; seed-edge blocks are -1 padded, and
+    a batch without a seed edge moves nothing.
     """
-    import numpy as np
-
-    from ..data.feature import Feature
-
     g = sampler.graph
-    if not isinstance(rows, Feature):
-        rows = Feature(np.asarray(rows))
-    if rows.hot_count < rows.size:
-        raise ValueError("scanned link step needs device-resident rows")
-    hot_rows = rows.hot_rows
+    rows = _device_rows(rows, "link")
     id2index = rows.id2index
 
     mode = None if neg_sampling is None else neg_sampling.mode
@@ -731,139 +626,89 @@ def make_scanned_link_train_step(model, tx, sampler, rows, loss_fn,
     impl = partial(sampler._sample_edges_impl, mode, amount, weighted)
     q = sampler.batch_size
 
-    @jax.jit
-    def run(indptr, indices, eids, sorted_indices, rows_arg, params,
-            opt_state, src_blk, dst_blk, cdf_arg, key):
-        def body(carry, inp):
-            params, opt = carry
-            s, d, k = inp
-            out = impl(indptr, indices, eids, sorted_indices, s, d,
-                       cdf_arg, k)
-            meta = dict(out.metadata)
-            if mode == "binary":
-                pos = jnp.where(s >= 0, 1, PADDING_ID)
-                meta["edge_label"] = jnp.concatenate(
-                    [pos, jnp.zeros((q * amount,), jnp.int32)])
-            valid = out.node >= 0
-            gid = jnp.where(valid, out.node, 0)
-            ridx = (gid if id2index is None
-                    else jnp.take(id2index, gid, axis=0, mode="clip"))
-            x = jnp.take(rows_arg, ridx, axis=0, mode="clip")
-            x = jnp.where(valid[:, None], x, 0)
-            edge_index = jnp.stack([out.row, out.col])
+    def batch_of(arrays, s, d, k):
+        indptr, indices, eids, sorted_indices, rows_arg, cdf_arg = arrays
+        out = impl(indptr, indices, eids, sorted_indices, s, d, cdf_arg, k)
+        meta = dict(out.metadata)
+        if mode == "binary":
+            pos = jnp.where(s >= 0, 1, PADDING_ID)
+            meta["edge_label"] = jnp.concatenate(
+                [pos, jnp.zeros((q * amount,), jnp.int32)])
+        return (out, _take_rows(rows_arg, id2index, out.node), None, meta,
+                jnp.any(s >= 0))
 
-            def lf(p):
-                z = model.apply(p, x, edge_index, out.edge_mask)
-                return loss_fn(z, meta)
-
-            loss, grads = jax.value_and_grad(lf)(params)
-            updates, opt = tx.update(grads, opt, params)
-            params = optax.apply_updates(params, updates)
-            return (params, opt), loss
-
-        keys = jax.random.split(key, src_blk.shape[0])
-        (params, opt_state), losses = jax.lax.scan(
-            body, (params, opt_state), (src_blk, dst_blk, keys))
-        return params, opt_state, losses
+    run = _scan_unsupervised(
+        tx, loss_and_grads(model, lambda z, y, meta: (loss_fn(z, meta),
+                                                      None)), batch_of)
 
     def step(params, opt_state, src_blk, dst_blk, key):
         sorted_ix = g.sorted_indices if mode is not None else g.indices
         cdf_arg = (jnp.zeros((1,), jnp.float32) if cdf is None else cdf)
         with _compilewatch.label("scanned_link_step"):
-            return run(g.indptr, g.indices, g.gather_edge_ids, sorted_ix,
-                       hot_rows, params, opt_state,
-                       jnp.asarray(src_blk, jnp.int32),
-                       jnp.asarray(dst_blk, jnp.int32), cdf_arg, key)
+            return run((g.indptr, g.indices, g.gather_edge_ids, sorted_ix,
+                        rows.hot_rows, cdf_arg), params, opt_state,
+                       (jnp.asarray(src_blk, jnp.int32),
+                        jnp.asarray(dst_blk, jnp.int32)), key)
 
     return step
 
 
 def make_scanned_subgraph_train_step(model, tx, sampler, rows, loss_fn,
                                      max_degree: int):
-    """ONE jitted program trains a block of induced-subgraph batches.
-
-    Per batch — hop expansion, induced extraction
-    (:func:`~glt_tpu.ops.subgraph.node_subgraph`), feature gather,
-    fwd/bwd, update — under ``lax.scan`` (scan length = the seed block's
-    leading axis); the SEAL-style configs run tiny batches where per-call
-    dispatch/transfer dominates, so G-batching (plus device-resident seed
-    blocks) moves epoch time the same way it does for the link path.
+    """ONE jitted program trains a block of induced-subgraph batches
+    (SEAL-style configs: tiny batches, dispatch-bound): hop expansion,
+    induced extraction (:func:`~glt_tpu.ops.subgraph.node_subgraph`),
+    feature gather and the step's body under ``lax.scan`` over the seed
+    block's leading axis.
 
     ``loss_fn(z, out, y) -> scalar`` gets node embeddings over the
     extracted subgraph, the per-batch :class:`SamplerOutput` (graph-
     direction COO), and the per-batch label block ``y``.  Seeds are
     DEDUPED in the node list, so positional slicing of ``z`` mispairs
     whenever a seed repeats — use ``out.metadata['seed_index']``
-    (``[B_seeds]`` local indices of the seed slots, -1 for padding) to
-    locate seed embeddings.
+    (``[B_seeds]`` local indices of the seed slots, -1 for padding).
 
-    Returns ``step(params, opt_state, seeds [G, B], y [G, ...], key)``.
+    Returns ``step(params, opt_state, seeds [G, B], y [G, ...], key)``; a
+    batch without a seed moves nothing.
     """
-    import numpy as np
-
-    from ..data.feature import Feature
     from ..ops.subgraph import node_subgraph
     from ..ops.unique import relabel_by_reference
     from ..sampler.base import SamplerOutput
 
     g = sampler.graph
-    if not isinstance(rows, Feature):
-        rows = Feature(np.asarray(rows))
-    if rows.hot_count < rows.size:
-        raise ValueError("scanned subgraph step needs device-resident rows")
+    rows = _device_rows(rows, "subgraph")
     if not sampler.last_hop_dedup:
         # Same guard as NeighborSampler.subgraph(): the induced extract
         # relabels against a UNIQUE node set.
         raise ValueError(
             "scanned subgraph step requires last_hop_dedup=True")
-    hot_rows = rows.hot_rows
     id2index = rows.id2index
     k_deg = int(max_degree)
 
-    @jax.jit
-    def run(indptr, indices, eids, sub_eids, rows_arg, params, opt_state,
-            seeds_blk, y_blk, key):
-        def body(carry, inp):
-            params, opt = carry
-            seeds, y, k = inp
-            base = sampler._sample_impl(indptr, indices, eids, seeds, k)
-            sub = node_subgraph(indptr, indices, base.node, k_deg,
-                                edge_ids=sub_eids)
-            ref = base.node[: seeds.shape[0]]
-            out = SamplerOutput(
-                node=base.node, row=sub.rows, col=sub.cols, edge=sub.eids,
-                batch=seeds, node_mask=base.node_mask, edge_mask=sub.mask,
-                num_sampled_nodes=base.num_sampled_nodes,
-                metadata={"seed_index":
-                          relabel_by_reference(ref, seeds)})
-            valid = out.node >= 0
-            gid = jnp.where(valid, out.node, 0)
-            ridx = (gid if id2index is None
-                    else jnp.take(id2index, gid, axis=0, mode="clip"))
-            x = jnp.where(valid[:, None],
-                          jnp.take(rows_arg, ridx, axis=0, mode="clip"), 0)
-            edge_index = jnp.stack([out.row, out.col])
+    def batch_of(arrays, seeds, y, k):
+        indptr, indices, eids, sub_eids, rows_arg = arrays
+        base = sampler._sample_impl(indptr, indices, eids, seeds, k)
+        sub = node_subgraph(indptr, indices, base.node, k_deg,
+                            edge_ids=sub_eids)
+        ref = base.node[: seeds.shape[0]]
+        out = SamplerOutput(
+            node=base.node, row=sub.rows, col=sub.cols, edge=sub.eids,
+            batch=seeds, node_mask=base.node_mask, edge_mask=sub.mask,
+            num_sampled_nodes=base.num_sampled_nodes,
+            metadata={"seed_index": relabel_by_reference(ref, seeds)})
+        return (out, _take_rows(rows_arg, id2index, out.node), y, out,
+                jnp.any(seeds >= 0))
 
-            def lf(p):
-                z = model.apply(p, x, edge_index, out.edge_mask)
-                return loss_fn(z, out, y)
-
-            loss, grads = jax.value_and_grad(lf)(params)
-            updates, opt = tx.update(grads, opt, params)
-            params = optax.apply_updates(params, updates)
-            return (params, opt), loss
-
-        keys = jax.random.split(key, seeds_blk.shape[0])
-        (params, opt_state), losses = jax.lax.scan(
-            body, (params, opt_state), (seeds_blk, y_blk, keys))
-        return params, opt_state, losses
+    run = _scan_unsupervised(
+        tx, loss_and_grads(model, lambda z, y, out: (loss_fn(z, out, y),
+                                                     None)), batch_of)
 
     def step(params, opt_state, seeds_blk, y_blk, key):
         with _compilewatch.label("scanned_subgraph_step"):
-            return run(g.indptr, g.indices, g.gather_edge_ids, g.edge_ids,
-                       hot_rows, params, opt_state,
-                       jnp.asarray(seeds_blk, jnp.int32),
-                       jnp.asarray(y_blk), key)
+            return run((g.indptr, g.indices, g.gather_edge_ids, g.edge_ids,
+                        rows.hot_rows), params, opt_state,
+                       (jnp.asarray(seeds_blk, jnp.int32),
+                        jnp.asarray(y_blk)), key)
 
     return step
 
@@ -891,14 +736,3 @@ def link_seed_blocks(edge_index, batch_size: int, group: int, rng):
         sb.reshape(-1)[:m] = chunk_s
         db.reshape(-1)[:m] = chunk_d
         yield sb, db, -(-m // batch_size)
-
-
-def make_eval_step(model, batch_size: int) -> Callable:
-    @jax.jit
-    def eval_step(params, batch):
-        logits = model.apply(params, batch.x, batch.edge_index,
-                             batch.edge_mask, train=False)
-        return seed_cross_entropy(logits, batch.y, batch_size,
-                                  batch.node_mask)
-
-    return eval_step

@@ -13,24 +13,22 @@ graph-shard owner and data-parallel trainer.
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Any, NamedTuple, Optional, Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models.train import TrainState, hop_trimming, seed_cross_entropy
+from ..models.step import (TrainState, gated_update, graph_inputs,
+                           loss_and_grads, seed_loss)
+from ..models.train import node_seed_blocks
 from ..sampler.neighbor_sampler import hop_bounds
 from ..typing import PADDING_ID
-from ..ops.unique import unique_first_occurrence
 from .dist_feature import (
     TieredShardedFeature,
     HostColdStore,
-    _dedup_scatter_back,
     exchange_gather,
     exchange_gather_hot,
     exchange_gather_xy,
@@ -101,54 +99,147 @@ def _byte_counters(byte_model):
     return record
 
 
-def _gather_xy_local(node, rows, labels_blk, f, g, axis_name,
-                     dedup_gather, route, fused, fuse_xy,
-                     fused_frontier="off", mesh_shape=None,
-                     hier_load_factor=None):
-    """Per-shard feature+label gather for one sampled node list — the
-    shared body of the serial and scanned dist train steps (one routing
-    plan + one payload collective when the id spaces agree).
-    ``fused_frontier`` selects the serving-side fused dedup+gather kernel
-    on the FEATURE exchange (label columns are 1-wide — nothing to fuse);
-    bit-identical either way.  ``mesh_shape``/``hier_load_factor``
-    select the hierarchical topology on a 2-D mesh (tuple
-    ``axis_name``); bit-identical to flat."""
-    if fuse_xy:
-        x, y = exchange_gather_xy(
-            node, rows, labels_blk, f.nodes_per_shard, f.num_shards,
-            axis_name, dedup=dedup_gather, route=route, fused=fused,
-            fused_frontier=fused_frontier, mesh_shape=mesh_shape,
+def _sharded_step(tx, mesh, axis_name, local_grads, any_valid):
+    """The wrapper of every step that takes its gradients inside a
+    ``shard_map`` and updates outside it: ``step(arrays, state, batch,
+    key) -> (state, loss, acc)``, one jit.
+
+    ``local_grads(arrays, batch, params, key) -> (loss, acc, grads)`` sees
+    one shard's blocks (leading axis stripped) and ``key`` folded with
+    the shard's index, and returns mesh means; ``any_valid(batch)`` gates
+    the replicated update (:func:`~glt_tpu.models.step.gated_update`).
+    The sharded ``arrays`` ride as jit ARGUMENTS, not closure captures:
+    multi-host global arrays span non-addressable devices and may not be
+    closed over.
+    """
+    update = gated_update(tx)
+
+    def local_body(arrays, batch, params, key):
+        arrays, batch = jax.tree.map(lambda a: a[0], (arrays, batch))
+        key = jax.random.fold_in(key, lax.axis_index(axis_name))
+        return local_grads(arrays, batch, params, key)
+
+    shard_fn = jax.shard_map(
+        local_body, mesh=mesh,
+        in_specs=(P(axis_name), P(axis_name), P(), P()),
+        out_specs=(P(), P(), P()),
+        check_vma=False)
+
+    @jax.jit
+    def _step(arrays, state: TrainState, batch, key: jax.Array):
+        loss, acc, grads = shard_fn(arrays, batch, state.params, key)
+        return update(state, grads, any_valid(batch)), loss, acc
+
+    return _step
+
+
+def _any_seed(seeds):
+    return jnp.sum((seeds >= 0).astype(jnp.int32)) > 0
+
+
+def _exchange_xy(axis_name, mesh_shape, label_space, route, fused,
+                 hier_load_factor, dedup: bool = False,
+                 fused_frontier: str = "off"):
+    """The exchange gathers of the distributed steps, closed over the
+    routing options: ``gather_x(node, rows, space, staged) -> x`` and
+    ``gather_xy(node, rows, labels, space, staged) -> (x, y)``.
+
+    ``space = (nodes_per_shard, hot_per_shard, num_shards)`` of the
+    table; ``staged = (rows, slots)`` is the compact host staging of its
+    cold rows, ``None`` for a table whole in HBM.  Features and labels
+    ride ONE routing plan and ONE payload collective
+    (:func:`~glt_tpu.parallel.dist_feature.exchange_gather_xy`) when the
+    table's id space is the labels' ``label_space = (per_shard,
+    num_shards)`` (always true for shard_graph/shard_feature over the
+    same node set).  ``dedup`` routes unique ids through every exchange
+    and scatters back, ``fused_frontier`` selects the serving-side fused
+    dedup+gather kernel on the feature rows of a table whole in HBM; both
+    bit-identical.  ``y`` is -1 off the node list.
+    """
+    kw = dict(route=route, mesh_shape=mesh_shape,
+              hier_load_factor=hier_load_factor)
+
+    def gather_x(node, rows, space, staged):
+        c, h, s = space
+        if staged is None:
+            return exchange_gather(node, rows, c, s, axis_name, dedup=dedup,
+                                   fused_frontier=fused_frontier, **kw)
+        return exchange_gather_hot(node, rows, c, h, s, axis_name,
+                                   staged_rows=staged[0],
+                                   staged_slots=staged[1], dedup=dedup,
+                                   **kw)
+
+    def gather_xy(node, rows, labels, space, staged):
+        c, h, s = space
+        if (c, s) == label_space:
+            srows, sslots = staged or (None, None)
+            x, y = exchange_gather_xy(
+                node, rows, labels, c, s, axis_name, hot_per_shard=h,
+                staged_rows=srows, staged_slots=sslots, dedup=dedup,
+                fused=fused, fused_frontier=fused_frontier, **kw)
+        else:
+            x = gather_x(node, rows, space, staged)
+            y = exchange_gather(node, labels[:, None].astype(jnp.int32),
+                                *label_space, axis_name, dedup=dedup,
+                                **kw)[:, 0]
+        return x, jnp.where(node >= 0, y, PADDING_ID)
+
+    return gather_x, gather_xy
+
+
+def _feature_space(f):
+    """``(rows, (nodes_per_shard, hot_per_shard, num_shards))`` of a
+    :class:`ShardedFeature` or :class:`TieredShardedFeature`."""
+    if isinstance(f, TieredShardedFeature):
+        return f.hot, (f.nodes_per_shard, f.hot_per_shard, f.num_shards)
+    return f.rows, (f.nodes_per_shard, f.nodes_per_shard, f.num_shards)
+
+
+def _any_seed_of(out):
+    batch = out.batch
+    return _any_seed(batch[out.input_type] if isinstance(batch, dict)
+                     else batch)
+
+
+def _dist_local_grads(model, g, f, mesh, num_neighbors, batch_size,
+                      axis_name, frontier_cap, last_hop_dedup,
+                      exchange_load_factor, dedup_gather, route, fused,
+                      fused_frontier, hier_load_factor):
+    """What :func:`make_dist_train_step` and its scanned twin share:
+    ``(axis_name, byte_model, local_grads)`` with ``local_grads(arrays,
+    seeds, params, key) -> (loss, acc, grads)`` one shard's sample,
+    gather, forward and backward, meaned over the mesh.  ``key`` draws
+    the sample AND the dropout mask."""
+    axis_name = resolve_mesh_axes(mesh, axis_name)
+    mesh_shape = mesh_axis_sizes(mesh, axis_name)
+    _, gather_xy = _exchange_xy(
+        axis_name, mesh_shape, (g.nodes_per_shard, g.num_shards), route,
+        fused, hier_load_factor, dedup=dedup_gather,
+        fused_frontier=fused_frontier)
+    space = _feature_space(f)[1]
+    byte_model = dist_step_byte_model(
+        g.nodes_per_shard, g.num_shards, num_neighbors, batch_size,
+        frontier_cap, f.rows.shape[-1], axis_name, mesh_shape,
+        route=route, hier_load_factor=hier_load_factor)
+    grads_of = loss_and_grads(
+        model, seed_loss(batch_size),
+        hop_bounds(batch_size, num_neighbors, frontier_cap),
+        mean_over=axis_name)
+
+    def local_grads(arrays, seeds, params, key):
+        indptr, indices, edge_ids, rows, labels_blk = arrays
+        out = dist_sample_multi_hop(
+            indptr, indices, edge_ids, seeds, key, num_neighbors,
+            g.nodes_per_shard, g.num_shards, axis_name, frontier_cap,
+            last_hop_dedup=last_hop_dedup,
+            exchange_load_factor=exchange_load_factor,
+            route=route, fused=fused, mesh_shape=mesh_shape,
             hier_load_factor=hier_load_factor)
-    elif dedup_gather:
-        # ONE unique pass feeds both exchanges; rows/labels scatter
-        # back to every original position (bit-identical batch).
-        uniq, inv, _ = unique_first_occurrence(node)
-        x = _dedup_scatter_back(
-            exchange_gather(uniq, rows, f.nodes_per_shard,
-                            f.num_shards, axis_name, route=route,
-                            fused_frontier=fused_frontier,
-                            mesh_shape=mesh_shape,
-                            hier_load_factor=hier_load_factor),
-            inv)
-        y = _dedup_scatter_back(
-            exchange_gather(uniq, labels_blk[:, None].astype(jnp.int32),
-                            g.nodes_per_shard, g.num_shards, axis_name,
-                            route=route, mesh_shape=mesh_shape,
-                            hier_load_factor=hier_load_factor),
-            inv)[:, 0]
-    else:
-        x = exchange_gather(node, rows, f.nodes_per_shard,
-                            f.num_shards, axis_name, route=route,
-                            fused_frontier=fused_frontier,
-                            mesh_shape=mesh_shape,
-                            hier_load_factor=hier_load_factor)
-        y = exchange_gather(node,
-                            labels_blk[:, None].astype(jnp.int32),
-                            g.nodes_per_shard, g.num_shards,
-                            axis_name, route=route,
-                            mesh_shape=mesh_shape,
-                            hier_load_factor=hier_load_factor)[:, 0]
-    return x, jnp.where(node >= 0, y, PADDING_ID)
+        x, y = gather_xy(out.node, rows, labels_blk, space, None)
+        edge_index, edge_mask, aux = graph_inputs(out)
+        return grads_of(params, x, edge_index, edge_mask, y, aux, key)
+
+    return axis_name, byte_model, local_grads
 
 
 def make_dist_train_step(
@@ -174,126 +265,41 @@ def make_dist_train_step(
 
     ``seeds`` carries one seed batch per shard (the per-rank disjoint seed
     split of dist_train_sage_supervised.py:76); params/opt state are
-    replicated; gradients are ``pmean``-ed across the mesh.
+    replicated; gradients are ``pmean``-ed across the mesh.  The model is
+    trimmed to ``hop_bounds(batch_size, num_neighbors, frontier_cap)``
+    where it can be.
+
     ``last_hop_dedup=False`` selects the leaf-block final hop (see
-    NeighborSampler) — loss/acc are over seed rows, which stay in the
-    compact interior prefix, so the objective is unchanged.
-    A model that trims by the hop-block layout (``GraphSAGE``) runs each
-    layer over ``hop_bounds(batch_size, num_neighbors, frontier_cap)``
-    only (:func:`~glt_tpu.models.train.hop_trimming`): the same seed
-    logits, loss and gradients from fewer edge slots.
-    ``exchange_load_factor`` bounds the sampler's all-to-all buckets (see
-    :func:`~glt_tpu.parallel.dist_sampler.dist_sample_multi_hop`).
-    ``dedup_gather`` routes unique node ids through the feature/label
-    exchange (one unique pass shared by both) and scatters rows back —
-    bit-identical batches, duplicated ids cross the ICI once; pair it
-    with ``last_hop_dedup=False``, whose leaf blocks repeat hub nodes.
-    ``route`` / ``fused`` select the routing implementation and fused
-    collectives (see :mod:`~glt_tpu.parallel.dist_sampler`): features +
-    labels ride ONE routing plan and ONE payload collective
-    (:func:`~glt_tpu.parallel.dist_feature.exchange_gather_xy`).
+    NeighborSampler) — seed rows stay in the compact interior prefix, so
+    the objective is unchanged.  ``exchange_load_factor``, ``route``,
+    ``fused`` and ``hier_load_factor`` are
+    :func:`~glt_tpu.parallel.dist_sampler.dist_sample_multi_hop`'s and
+    the gather's (:func:`_exchange_xy`); ``dedup_gather`` routes unique
+    node ids through the feature/label exchange — pair it with
+    ``last_hop_dedup=False``, whose leaf blocks repeat hub nodes;
     ``fused_frontier`` != 'off' serves each shard's landed feature
-    requests through the one-dispatch dedup+gather kernel inside
-    shard_map (sampling stays per-shard local; see
-    :func:`~glt_tpu.parallel.dist_feature._request_rows`).
+    requests through the one-dispatch dedup+gather kernel
+    (:func:`~glt_tpu.parallel.dist_feature._request_rows`).  All leave
+    the batch bit-identical.
 
     ``axis_name=None`` resolves to the mesh's own axes — the 1-D
-    ``global_mesh`` name or the 2-D ``global_mesh_2d`` tuple.  On a 2-D
-    mesh the step runs both sampling hops and the gather over the
-    hierarchical dedup-then-exchange topology when ``route`` resolves
-    'hier' (bit-identical to 'flat'); ``hier_load_factor`` bounds the
-    DCN leg (see :func:`~glt_tpu.parallel.dist_sampler.
-    hier_request_cap`).  The returned step carries its static
-    ``step.collective_bytes`` ICI/DCN byte model and feeds the
-    ``glt.dist.collective_bytes{axis=}`` counters per call.
+    ``global_mesh`` name or the 2-D ``global_mesh_2d`` tuple, over which
+    sampling and gather ride the hierarchical dedup-then-exchange
+    topology when ``route`` resolves 'hier' (bit-identical to 'flat').
+    The returned step carries its static ``step.collective_bytes``
+    ICI/DCN byte model and feeds the ``glt.dist.collective_bytes{axis=}``
+    counters per call.
     """
-    axis_name = resolve_mesh_axes(mesh, axis_name)
-    mesh_shape = mesh_axis_sizes(mesh, axis_name)
-    gspec = P(axis_name)
-    # Feature/label fusion needs one id space for both (always true for
-    # shard_graph/shard_feature over the same node set).
-    fuse_xy = (f.nodes_per_shard == g.nodes_per_shard
-               and f.num_shards == g.num_shards)
-    byte_model = dist_step_byte_model(
-        g.nodes_per_shard, g.num_shards, num_neighbors, batch_size,
-        frontier_cap, f.rows.shape[-1], axis_name, mesh_shape,
-        route=route, hier_load_factor=hier_load_factor)
+    axis_name, byte_model, local_grads = _dist_local_grads(
+        model, g, f, mesh, num_neighbors, batch_size, axis_name,
+        frontier_cap, last_hop_dedup, exchange_load_factor, dedup_gather,
+        route, fused, fused_frontier, hier_load_factor)
     record_bytes = _byte_counters(byte_model)
-    trim = hop_trimming(
-        model, hop_bounds(batch_size, num_neighbors, frontier_cap))
-
-    def local_body(indptr, indices, edge_ids, rows, labels_blk, seeds,
-                   params, key):
-        indptr, indices, edge_ids = indptr[0], indices[0], edge_ids[0]
-        rows, labels_blk, seeds = rows[0], labels_blk[0], seeds[0]
-        key = jax.random.fold_in(key, lax.axis_index(axis_name))
-
-        out = dist_sample_multi_hop(
-            indptr, indices, edge_ids, seeds, key, num_neighbors,
-            g.nodes_per_shard, g.num_shards, axis_name, frontier_cap,
-            last_hop_dedup=last_hop_dedup,
-            exchange_load_factor=exchange_load_factor,
-            route=route, fused=fused, mesh_shape=mesh_shape,
-            hier_load_factor=hier_load_factor)
-        # ONE routing plan + ONE payload collective for features AND
-        # labels when the id spaces agree (dedup additionally shares a
-        # single unique pass) — see _gather_xy_local.
-        x, y = _gather_xy_local(out.node, rows, labels_blk, f, g,
-                                axis_name, dedup_gather, route, fused,
-                                fuse_xy, fused_frontier,
-                                mesh_shape=mesh_shape,
-                                hier_load_factor=hier_load_factor)
-        edge_index = jnp.stack([out.row, out.col])
-
-        def loss_fn(p):
-            logits = model.apply(p, x, edge_index, out.edge_mask,
-                                 train=True, rngs={"dropout": key}, **trim)
-            return seed_cross_entropy(logits, y, batch_size, out.node_mask,
-                                      out.num_sampled_nodes[0])
-
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params)
-        # One scope for the three: XLA combines them into one all-reduce.
-        with jax.named_scope("glt.step.update"):
-            grads = lax.pmean(grads, axis_name)
-            loss = lax.pmean(loss, axis_name)
-            acc = lax.pmean(acc, axis_name)
-        return loss, acc, grads
-
-    shard_fn = jax.shard_map(
-        local_body, mesh=mesh,
-        in_specs=(gspec, gspec, gspec, gspec, gspec, gspec, P(), P()),
-        out_specs=(P(), P(), P()),
-        check_vma=False)
-
-    # The sharded graph/feature/label arrays ride as jit ARGUMENTS, not
-    # closure captures: multi-host global arrays span non-addressable
-    # devices and may not be closed over.
-    @jax.jit
-    def _step(indptr, indices, edge_ids, rows, labels_blk,
-              state: TrainState, seeds: jnp.ndarray, key: jax.Array):
-        loss, acc, grads = shard_fn(indptr, indices, edge_ids,
-                                    rows, labels_blk, seeds, state.params,
-                                    key)
-
-        def apply(s):
-            with jax.named_scope("glt.step.update"):
-                updates, opt_state = tx.update(grads, s.opt_state,
-                                               s.params)
-                params = optax.apply_updates(s.params, updates)
-            return TrainState(params, opt_state, s.step + 1)
-
-        # A fully-padded batch must not move a stateful optimizer or the
-        # step counter (same gating as the scanned step): every exchange
-        # carries only -1 slots over both fabrics, so the step is a
-        # global no-op, not a momentum-only Adam update.
-        nvalid = jnp.sum((seeds >= 0).astype(jnp.int32))
-        new_state = jax.lax.cond(nvalid > 0, apply, lambda s: s, state)
-        return new_state, loss, acc
+    _step = _sharded_step(tx, mesh, axis_name, local_grads, _any_seed)
 
     def step(state: TrainState, seeds: jnp.ndarray, key: jax.Array):
         record_bytes()
-        return _step(g.indptr, g.indices, g.edge_ids, f.rows, labels,
+        return _step((g.indptr, g.indices, g.edge_ids, f.rows, labels),
                      state, seeds, key)
 
     step.collective_bytes = byte_model
@@ -319,106 +325,45 @@ def make_scanned_dist_train_step(
     fused_frontier: str = "off",
     hier_load_factor: Optional[float] = None,
 ):
-    """ONE jitted program trains ``G`` consecutive distributed batches.
-
-    The fused-epoch shape of :func:`make_dist_train_step` (the dist
-    analog of ``models.train.make_scanned_node_train_step``): per scan
-    slot — all-to-all multi-hop sampling, fused feature+label exchange,
-    fwd/bwd, gradient ``pmean``, optimizer update — under ``lax.scan``
-    INSIDE one ``shard_map`` program, so intermediate ids and the
-    updated replicated state never round-trip through host dispatch
-    between batches: the per-batch dispatch + state re-feed of the
-    serial dist step is paid once per ``G``.  The model is trimmed to
-    the hop-block layout as in the serial step.
+    """:func:`make_dist_train_step` under ``lax.scan`` INSIDE one
+    ``shard_map`` program (same options): ``G`` consecutive distributed
+    batches, update included, so intermediate ids and the updated
+    replicated state never round-trip through host dispatch between
+    batches.
 
     Returns ``step(state, seeds_blk [G, S, B], key) -> (state,
     losses [G], accs [G])``.  Per-slot keys follow the homo scan
     convention (``jax.random.split(key, G)``, then the per-shard
     ``fold_in(axis_index)`` of the serial step), and a fully padded
-    slot (every shard's seeds all ``-1``) is an exact no-op — params,
-    opt state, and the step counter hold, so a padded trailing block
-    equals the serial loop over real batches only.
-
-    ``fused_frontier`` != 'off' routes the per-shard feature serving of
-    every scan slot through the fused dedup+gather kernel (sampling
-    stays per-shard local; the kernel runs inside shard_map and compiles
-    under the scanned dist program's compilewatch label); bit-identical
-    batches, VMEM-overflowing request blocks fall back to the unfused
-    serve.
-
-    On a 2-D mesh (``axis_name=None`` resolves the tuple) the scan body
-    traces the hierarchical exchange ONCE — the topology choice is
-    static, so scanning over ``dist_seed_blocks`` recompiles nothing.
+    slot (every shard's seeds all ``-1``) is an exact no-op: the gate
+    reads a global count, so every shard takes the same branch.  On a 2-D
+    mesh the scan body traces the hierarchical exchange ONCE — the
+    topology choice is static, so scanning over ``dist_seed_blocks``
+    recompiles nothing.
     """
-    axis_name = resolve_mesh_axes(mesh, axis_name)
-    mesh_shape = mesh_axis_sizes(mesh, axis_name)
-    gspec = P(axis_name)
-    blkspec = P(None, axis_name)
-    fuse_xy = (f.nodes_per_shard == g.nodes_per_shard
-               and f.num_shards == g.num_shards)
-    byte_model = dist_step_byte_model(
-        g.nodes_per_shard, g.num_shards, num_neighbors, batch_size,
-        frontier_cap, f.rows.shape[-1], axis_name, mesh_shape,
-        route=route, hier_load_factor=hier_load_factor)
+    axis_name, byte_model, local_grads = _dist_local_grads(
+        model, g, f, mesh, num_neighbors, batch_size, axis_name,
+        frontier_cap, last_hop_dedup, exchange_load_factor, dedup_gather,
+        route, fused, fused_frontier, hier_load_factor)
     record_bytes = _byte_counters(byte_model)
-    trim = hop_trimming(
-        model, hop_bounds(batch_size, num_neighbors, frontier_cap))
+    update = gated_update(tx)
+    gspec = P(axis_name)
 
     def local_body(indptr, indices, edge_ids, rows, labels_blk,
                    seeds_blk, state: TrainState, keys):
-        indptr, indices, edge_ids = indptr[0], indices[0], edge_ids[0]
-        rows, labels_blk = rows[0], labels_blk[0]
+        arrays = (indptr[0], indices[0], edge_ids[0], rows[0],
+                  labels_blk[0])
         seeds_blk = seeds_blk[:, 0]          # [G, B] local slice
         me = lax.axis_index(axis_name)
 
         def body(carry, inp):
             st, = carry
             seeds, k = inp
-            key = jax.random.fold_in(k, me)
-            out = dist_sample_multi_hop(
-                indptr, indices, edge_ids, seeds, key, num_neighbors,
-                g.nodes_per_shard, g.num_shards, axis_name, frontier_cap,
-                last_hop_dedup=last_hop_dedup,
-                exchange_load_factor=exchange_load_factor,
-                route=route, fused=fused, mesh_shape=mesh_shape,
-                hier_load_factor=hier_load_factor)
-            x, y = _gather_xy_local(out.node, rows, labels_blk, f, g,
-                                    axis_name, dedup_gather, route,
-                                    fused, fuse_xy, fused_frontier,
-                                    mesh_shape=mesh_shape,
-                                    hier_load_factor=hier_load_factor)
-            edge_index = jnp.stack([out.row, out.col])
-
-            def loss_fn(p):
-                logits = model.apply(p, x, edge_index, out.edge_mask,
-                                     train=True, rngs={"dropout": key},
-                                     **trim)
-                return seed_cross_entropy(logits, y, batch_size,
-                                          out.node_mask,
-                                          out.num_sampled_nodes[0])
-
-            (loss, acc), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(st.params)
-            with jax.named_scope("glt.step.update"):
-                grads = lax.pmean(grads, axis_name)
-                loss = lax.pmean(loss, axis_name)
-                acc = lax.pmean(acc, axis_name)
-
-            def apply(s):
-                with jax.named_scope("glt.step.update"):
-                    updates, opt_state = tx.update(grads, s.opt_state,
-                                                   s.params)
-                    params = optax.apply_updates(s.params, updates)
-                return TrainState(params, opt_state, s.step + 1)
-
-            # Fully-padded slots must not move a stateful optimizer or
-            # the step counter (same gating as the homo scanned step);
-            # the predicate is a global count so every shard takes the
-            # same branch.
+            loss, acc, grads = local_grads(arrays, seeds, st.params,
+                                           jax.random.fold_in(k, me))
             nvalid = lax.psum(jnp.sum((seeds >= 0).astype(jnp.int32)),
                               axis_name)
-            st = jax.lax.cond(nvalid > 0, apply, lambda s: s, st)
-            return (st,), (loss, acc)
+            return (update(st, grads, nvalid > 0),), (loss, acc)
 
         (state,), (losses, accs) = lax.scan(body, (state,),
                                             (seeds_blk, keys))
@@ -426,7 +371,8 @@ def make_scanned_dist_train_step(
 
     shard_fn = jax.shard_map(
         local_body, mesh=mesh,
-        in_specs=(gspec, gspec, gspec, gspec, gspec, blkspec, P(), P()),
+        in_specs=(gspec, gspec, gspec, gspec, gspec, P(None, axis_name),
+                  P(), P()),
         out_specs=(P(), P(), P()),
         check_vma=False)
 
@@ -454,13 +400,9 @@ def dist_seed_blocks(train_idx, num_shards: int, batch_size: int,
     for :func:`make_scanned_dist_train_step` (each scan slot carries one
     disjoint per-shard seed batch; trailing slots may be fully padded
     no-ops)."""
-    ids = np.asarray(train_idx)[rng.permutation(len(train_idx))]
-    per_block = batch_size * num_shards * group
-    for lo in range(0, len(ids), per_block):
-        blk = np.full((group, num_shards, batch_size), -1, np.int64)
-        chunk = ids[lo: lo + per_block]
-        blk.reshape(-1)[: chunk.shape[0]] = chunk
-        yield blk
+    for blk in node_seed_blocks(train_idx, num_shards * batch_size, group,
+                                rng):
+        yield blk.reshape(group, num_shards, batch_size)
 
 
 def run_scanned_dist_epoch(step, state, train_idx, num_shards: int,
@@ -532,79 +474,30 @@ def make_tiered_train_step(
 
     ``dedup_gather`` must match the :class:`TieredTrainPipeline`'s flag:
     the staged cold rows are keyed to the (possibly deduped) request
-    layout.  The hot feature gather and the label gather share one
-    routing plan and one fused payload collective
-    (:func:`~glt_tpu.parallel.dist_feature.exchange_gather_xy`) when the
-    graph and feature id spaces agree.
+    layout.  The model runs whole (the sample stage's layout is not
+    handed over); the dropout key is the per-shard fold of ``key``.
     """
     axis_name = resolve_mesh_axes(mesh, axis_name)
-    mesh_shape = mesh_axis_sizes(mesh, axis_name)
-    gspec = P(axis_name)
-    fuse_xy = (f.nodes_per_shard == g.nodes_per_shard
-               and f.num_shards == g.num_shards)
+    _, gather_xy = _exchange_xy(
+        axis_name, mesh_axis_sizes(mesh, axis_name),
+        (g.nodes_per_shard, g.num_shards), route, fused, hier_load_factor,
+        dedup=dedup_gather)
+    hot, space = _feature_space(f)
+    grads_of = loss_and_grads(model, seed_loss(batch_size),
+                              mean_over=axis_name)
 
-    def local_body(hot_rows, labels_blk, out, staged_rows, staged_slots,
-                   params, key):
-        hot_rows, labels_blk = hot_rows[0], labels_blk[0]
-        staged_rows, staged_slots = staged_rows[0], staged_slots[0]
-        out = jax.tree.map(lambda x: x[0], out)
-        key = jax.random.fold_in(key, lax.axis_index(axis_name))
+    def local_grads(arrays, batch, params, key):
+        hot_rows, labels_blk = arrays
+        out, staged = batch
+        x, y = gather_xy(out.node, hot_rows, labels_blk, space, staged)
+        edge_index, edge_mask, aux = graph_inputs(out)
+        return grads_of(params, x, edge_index, edge_mask, y, aux, key)
 
-        if fuse_xy:
-            x, y = exchange_gather_xy(
-                out.node, hot_rows, labels_blk, f.nodes_per_shard,
-                f.num_shards, axis_name, hot_per_shard=f.hot_per_shard,
-                staged_rows=staged_rows, staged_slots=staged_slots,
-                dedup=dedup_gather, route=route, fused=fused,
-                mesh_shape=mesh_shape, hier_load_factor=hier_load_factor)
-        else:
-            x = exchange_gather_hot(out.node, hot_rows, f.nodes_per_shard,
-                                    f.hot_per_shard, f.num_shards,
-                                    axis_name, staged_rows=staged_rows,
-                                    staged_slots=staged_slots,
-                                    dedup=dedup_gather, route=route,
-                                    mesh_shape=mesh_shape,
-                                    hier_load_factor=hier_load_factor)
-            y = exchange_gather(out.node,
-                                labels_blk[:, None].astype(jnp.int32),
-                                g.nodes_per_shard, g.num_shards, axis_name,
-                                dedup=dedup_gather, route=route,
-                                mesh_shape=mesh_shape,
-                                hier_load_factor=hier_load_factor)[:, 0]
-        y = jnp.where(out.node >= 0, y, PADDING_ID)
-        edge_index = jnp.stack([out.row, out.col])
-
-        def loss_fn(p):
-            logits = model.apply(p, x, edge_index, out.edge_mask,
-                                 train=True, rngs={"dropout": key})
-            return seed_cross_entropy(logits, y, batch_size, out.node_mask)
-
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params)
-        grads = lax.pmean(grads, axis_name)
-        loss = lax.pmean(loss, axis_name)
-        acc = lax.pmean(acc, axis_name)
-        return loss, acc, grads
-
-    shard_fn = jax.shard_map(
-        local_body, mesh=mesh,
-        in_specs=(gspec, gspec, gspec, gspec, gspec, P(), P()),
-        out_specs=(P(), P(), P()),
-        check_vma=False)
-
-    # Global arrays as jit arguments (multi-host: no closure capture).
-    @jax.jit
-    def _train(hot_rows, labels_blk, state: TrainState, out, staged_rows,
-               staged_slots, key: jax.Array):
-        loss, acc, grads = shard_fn(hot_rows, labels_blk, out, staged_rows,
-                                    staged_slots, state.params, key)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1), loss, acc
+    _step = _sharded_step(tx, mesh, axis_name, local_grads,
+                          lambda batch: _any_seed_of(batch[0]))
 
     def train(state: TrainState, out, staged, key: jax.Array):
-        rows, slots = staged
-        return _train(f.hot, labels, state, out, rows, slots, key)
+        return _step((hot, labels), state, (out, tuple(staged)), key)
 
     return train
 
@@ -956,6 +849,31 @@ def init_dist_state(model, tx, g: ShardedGraph, f,
                       step=jnp.zeros((), jnp.int32))
 
 
+def _typed_gather(sampler, feats, labels, axis_name, mesh, route, fused,
+                  hier_load_factor):
+    """``(rows, gather)`` of the typed distributed steps: every type's
+    device rows, and ``gather(out, rows_l, labels_l, staged) -> (x, y)``
+    for one shard — each type's rows through its own exchange (``staged``:
+    ``{type: (rows, slots)}`` of the tiered types), the seed type's with
+    the labels (:func:`_exchange_xy`)."""
+    tgt = sampler.input_type
+    num_shards = next(iter(sampler.sharded.values())).num_shards
+    gather_x, gather_xy = _exchange_xy(
+        axis_name, mesh_axis_sizes(mesh, axis_name),
+        (int(labels.shape[1]), num_shards), route, fused, hier_load_factor)
+    spaces = {t: _feature_space(f) for t, f in feats.items()}
+
+    def gather(out, rows_l, labels_l, staged):
+        x = {t: gather_x(out.node[t], rows_l[t], spaces[t][1],
+                         staged.get(t))
+             for t in rows_l if t != tgt}
+        x[tgt], y = gather_xy(out.node[tgt], rows_l[tgt], labels_l,
+                              spaces[tgt][1], staged.get(tgt))
+        return x, y
+
+    return {t: sp[0] for t, sp in spaces.items()}, gather
+
+
 def make_hetero_dist_train_step(
     model,
     tx,
@@ -976,87 +894,29 @@ def make_hetero_dist_train_step(
 
     ``model.edge_types`` must use the sampler's *reversed* output keys
     (``reverse_edge_type`` of the dataset's edge types), and
-    ``model.target_type`` == ``sampler.input_type``.  The target type's
-    feature gather and the label gather share one routing plan + one
-    fused payload collective (``exchange_gather_xy``).
+    ``model.target_type`` == ``sampler.input_type``.  The model runs
+    whole; the per-shard key is split into a dropout and a sampling key.
     """
     axis_name = resolve_mesh_axes(mesh, axis_name)
-    mesh_shape = mesh_axis_sizes(mesh, axis_name)
-    gspec = P(axis_name)
-    tgt = sampler.input_type
     arrays = {et: (g.indptr, g.indices, g.edge_ids)
               for et, g in sampler.sharded.items()}
-    rows = {t: f.rows for t, f in feats.items()}
-    meta = {t: (f.nodes_per_shard, f.num_shards) for t, f in feats.items()}
-    label_c = int(labels.shape[1])
-    num_shards = next(iter(sampler.sharded.values())).num_shards
-    fuse_xy = (meta[tgt][0] == label_c and meta[tgt][1] == num_shards)
+    rows, gather = _typed_gather(sampler, feats, labels, axis_name, mesh,
+                                 route, fused, hier_load_factor)
+    grads_of = loss_and_grads(model, seed_loss(batch_size),
+                              mean_over=axis_name)
 
-    def local_body(arrays_blk, rows_blk, labels_blk, seeds_blk, params,
-                   key):
-        arrays_l = jax.tree.map(lambda a: a[0], arrays_blk)
-        rows_l = {t: r[0] for t, r in rows_blk.items()}
-        labels_l, seeds = labels_blk[0], seeds_blk[0]
-        key = jax.random.fold_in(key, lax.axis_index(axis_name))
+    def local_grads(arrays_l, seeds, params, key):
+        graph_l, rows_l, labels_l = arrays_l
         kdrop, ksample = jax.random.split(key)
+        out = sampler.local_sample(graph_l, seeds, ksample)
+        x, y = gather(out, rows_l, labels_l, {})
+        edge_index, edge_mask, aux = graph_inputs(out)
+        return grads_of(params, x, edge_index, edge_mask, y, aux, kdrop)
 
-        out = sampler.local_sample(arrays_l, seeds, ksample)
-        x, y = {}, None
-        for t in rows_l:
-            if t == tgt and fuse_xy:
-                x[t], y = exchange_gather_xy(
-                    out.node[t], rows_l[t], labels_l, meta[t][0],
-                    meta[t][1], axis_name, route=route, fused=fused,
-                    mesh_shape=mesh_shape,
-                    hier_load_factor=hier_load_factor)
-            else:
-                x[t] = exchange_gather(out.node[t], rows_l[t], meta[t][0],
-                                       meta[t][1], axis_name, route=route,
-                                       mesh_shape=mesh_shape,
-                                       hier_load_factor=hier_load_factor)
-        if y is None:
-            y = exchange_gather(out.node[tgt],
-                                labels_l[:, None].astype(jnp.int32),
-                                label_c, num_shards, axis_name,
-                                route=route, mesh_shape=mesh_shape,
-                                hier_load_factor=hier_load_factor)[:, 0]
-        y = jnp.where(out.node[tgt] >= 0, y, PADDING_ID)
-        edge_index = {et: jnp.stack([out.row[et], out.col[et]])
-                      for et in out.row}
-
-        def loss_fn(prm):
-            logits = model.apply(prm, x, edge_index, out.edge_mask,
-                                 train=True, rngs={"dropout": kdrop})
-            return seed_cross_entropy(logits, y, batch_size,
-                                      out.node_mask[tgt])
-
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params)
-        grads = lax.pmean(grads, axis_name)
-        loss = lax.pmean(loss, axis_name)
-        acc = lax.pmean(acc, axis_name)
-        return loss, acc, grads
-
-    arr_specs = jax.tree.map(lambda _: gspec, arrays)
-    row_specs = {t: gspec for t in rows}
-    shard_fn = jax.shard_map(
-        local_body, mesh=mesh,
-        in_specs=(arr_specs, row_specs, gspec, gspec, P(), P()),
-        out_specs=(P(), P(), P()),
-        check_vma=False)
-
-    # Global arrays as jit arguments (multi-host: no closure capture).
-    @jax.jit
-    def _step(arrays_arg, rows_arg, labels_blk, state: TrainState,
-              seeds: jnp.ndarray, key: jax.Array):
-        loss, acc, grads = shard_fn(arrays_arg, rows_arg, labels_blk,
-                                    seeds, state.params, key)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1), loss, acc
+    _step = _sharded_step(tx, mesh, axis_name, local_grads, _any_seed)
 
     def step(state: TrainState, seeds: jnp.ndarray, key: jax.Array):
-        return _step(arrays, rows, labels, state, seeds, key)
+        return _step((arrays, rows, labels), state, seeds, key)
 
     return step
 
@@ -1087,100 +947,26 @@ def make_hetero_tiered_train_step(
     tiered types only.
     """
     axis_name = resolve_mesh_axes(mesh, axis_name)
-    mesh_shape = mesh_axis_sizes(mesh, axis_name)
-    gspec = P(axis_name)
-    tgt = sampler.input_type
     tiered = sorted(t for t, f in feats.items()
                     if isinstance(f, TieredShardedFeature))
-    hot_rows = {t: (f.hot if isinstance(f, TieredShardedFeature)
-                    else f.rows) for t, f in feats.items()}
-    meta = {t: (f.nodes_per_shard,
-                (f.hot_per_shard if isinstance(f, TieredShardedFeature)
-                 else f.nodes_per_shard),
-                f.num_shards) for t, f in feats.items()}
-    label_c = int(labels.shape[1])
-    num_shards = next(iter(sampler.sharded.values())).num_shards
-    fuse_xy = (meta[tgt][0] == label_c and meta[tgt][2] == num_shards)
+    hot_rows, gather = _typed_gather(sampler, feats, labels, axis_name,
+                                     mesh, route, fused, hier_load_factor)
+    grads_of = loss_and_grads(model, seed_loss(batch_size),
+                              mean_over=axis_name)
 
-    def local_body(hot_blk, labels_blk, out, srows_blk, sslots_blk, params,
-                   key):
-        hot_l = {t: r[0] for t, r in hot_blk.items()}
-        labels_l = labels_blk[0]
-        srows = {t: r[0] for t, r in srows_blk.items()}
-        sslots = {t: r[0] for t, r in sslots_blk.items()}
-        out = jax.tree.map(lambda x: x[0], out)
-        key = jax.random.fold_in(key, lax.axis_index(axis_name))
+    def local_grads(arrays_l, batch, params, key):
+        hot_l, labels_l = arrays_l
+        out, staged = batch
+        x, y = gather(out, hot_l, labels_l, staged)
+        edge_index, edge_mask, aux = graph_inputs(out)
+        return grads_of(params, x, edge_index, edge_mask, y, aux, key)
 
-        x, y = {}, None
-        for t in hot_l:
-            c, h, s = meta[t]
-            if t == tgt and fuse_xy:
-                # Target-type features (hot tier + staged cold when
-                # tiered) and labels ride one routing plan + one fused
-                # payload collective.
-                x[t], y = exchange_gather_xy(
-                    out.node[t], hot_l[t], labels_l, c, s, axis_name,
-                    hot_per_shard=h, staged_rows=srows.get(t),
-                    staged_slots=sslots.get(t), route=route, fused=fused,
-                    mesh_shape=mesh_shape,
-                    hier_load_factor=hier_load_factor)
-            elif t in srows:
-                x[t] = exchange_gather_hot(out.node[t], hot_l[t], c, h, s,
-                                           axis_name,
-                                           staged_rows=srows[t],
-                                           staged_slots=sslots[t],
-                                           route=route,
-                                           mesh_shape=mesh_shape,
-                                           hier_load_factor=hier_load_factor)
-            else:
-                x[t] = exchange_gather(out.node[t], hot_l[t], c, s,
-                                       axis_name, route=route,
-                                       mesh_shape=mesh_shape,
-                                       hier_load_factor=hier_load_factor)
-        if y is None:
-            y = exchange_gather(out.node[tgt],
-                                labels_l[:, None].astype(jnp.int32),
-                                label_c, num_shards, axis_name,
-                                route=route, mesh_shape=mesh_shape,
-                                hier_load_factor=hier_load_factor)[:, 0]
-        y = jnp.where(out.node[tgt] >= 0, y, PADDING_ID)
-        edge_index = {et: jnp.stack([out.row[et], out.col[et]])
-                      for et in out.row}
-
-        def loss_fn(prm):
-            logits = model.apply(prm, x, edge_index, out.edge_mask,
-                                 train=True, rngs={"dropout": key})
-            return seed_cross_entropy(logits, y, batch_size,
-                                      out.node_mask[tgt])
-
-        (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params)
-        grads = lax.pmean(grads, axis_name)
-        loss = lax.pmean(loss, axis_name)
-        acc = lax.pmean(acc, axis_name)
-        return loss, acc, grads
-
-    hot_specs = {t: gspec for t in hot_rows}
-    st_specs = {t: gspec for t in tiered}
-    shard_fn = jax.shard_map(
-        local_body, mesh=mesh,
-        in_specs=(hot_specs, gspec, gspec, st_specs, st_specs, P(), P()),
-        out_specs=(P(), P(), P()),
-        check_vma=False)
-
-    @jax.jit
-    def _train(hot_arg, labels_blk, state: TrainState, out, srows, sslots,
-               key: jax.Array):
-        loss, acc, grads = shard_fn(hot_arg, labels_blk, out, srows,
-                                    sslots, state.params, key)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1), loss, acc
+    _step = _sharded_step(tx, mesh, axis_name, local_grads,
+                          lambda batch: _any_seed_of(batch[0]))
 
     def train(state: TrainState, out, staged, key: jax.Array):
-        srows = {t: staged[t][0] for t in tiered}
-        sslots = {t: staged[t][1] for t in tiered}
-        return _train(hot_rows, labels, state, out, srows, sslots, key)
+        return _step((hot_rows, labels), state,
+                     (out, {t: tuple(staged[t]) for t in tiered}), key)
 
     return train
 
@@ -1296,16 +1082,12 @@ class HeteroTieredTrainPipeline(_ColdStagePipeline):
 
 def init_hetero_dist_state(model, tx, sampler, feats,
                            rng: jax.Array) -> TrainState:
-    """Replicated params/opt-state from the sampler's static shapes.
+    """Replicated params/opt-state
+    (:func:`~glt_tpu.models.train.init_hetero_state` over the sharded
+    tables; ``feats`` values may be :class:`ShardedFeature` or
+    :class:`TieredShardedFeature`)."""
+    from ..models.train import init_hetero_state
 
-    ``feats`` values may be :class:`ShardedFeature` or
-    :class:`TieredShardedFeature`."""
-    from ..models.train import hetero_init_shapes
-
-    def _rows(f):
-        return f.hot if isinstance(f, TieredShardedFeature) else f.rows
-
-    x, ei, mask = hetero_init_shapes(sampler, feats, _rows)
-    params = model.init({"params": rng}, x, ei, mask)
-    return TrainState(params=params, opt_state=tx.init(params),
-                      step=jnp.zeros((), jnp.int32))
+    return init_hetero_state(
+        model, tx, sampler,
+        {t: _feature_space(f)[0] for t, f in feats.items()}, rng)

@@ -317,6 +317,25 @@ def _run_traced_fleet(tmp_path, monkeypatch, num_workers):
     return files, merged, epoch_tid
 
 
+def _sync_error_bound_us(merged) -> float:
+    """What the merge's offsets can be wrong by: for each (process, peer)
+    pair half the smallest round trip ``(t3 - t0) - (t2 - t1)`` among its
+    ``obs.clock_sync`` samples (the NTP bound on the sample
+    ``obs/merge.py`` picks), summed over the pairs because offsets
+    compose."""
+    best = {}
+    for ev in merged["traceEvents"]:
+        if ev.get("name") != "obs.clock_sync":
+            continue
+        a = ev["args"]
+        delta = ((float(a["t3_us"]) - float(a["t0_us"]))
+                 - (float(a["t2_us"]) - float(a["t1_us"])))
+        pair = (ev["pid"], int(a["peer_pid"]))
+        best[pair] = min(best.get(pair, float("inf")), max(delta, 0.0))
+    assert best, "no clock-sync sample in the merged trace"
+    return sum(best.values()) / 2.0
+
+
 def test_distributed_trace_merge_end_to_end(tmp_path, monkeypatch):
     """ISSUE 7 acceptance: a remote-sampling run exports per-process
     traces that `obs merge` stitches into one valid Chrome trace, with
@@ -330,8 +349,10 @@ def test_distributed_trace_merge_end_to_end(tmp_path, monkeypatch):
     assert {"client", "server"} <= roles      # one file per process
     assert obs.validate_chrome_trace(merged) == []
     # Server stage spans nest inside the client fetch spans that caused
-    # them (5 ms slack: loopback RTT bounds the alignment error).
-    assert obs.span_tree_check(merged, tol_us=5_000.0) == []
+    # them, within the alignment's own error bound: a loaded machine
+    # stretches every sampled round trip, and the bound with it.
+    assert obs.span_tree_check(
+        merged, tol_us=5_000.0 + _sync_error_bound_us(merged)) == []
     by_name = {}
     for ev in merged["traceEvents"]:
         by_name.setdefault(ev.get("name"), []).append(ev)
