@@ -18,11 +18,13 @@ index -1 and never appear among the unique ids.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from ..obs import metrics as _metrics
 from ..obs.scopes import scoped
 
 _INT32_MAX = jnp.iinfo(jnp.int32).max
@@ -142,14 +144,17 @@ def dense_induce(state: DenseInduceState, cand: jnp.ndarray
 
     This is the hash-table inducer's contract
     (``CUDAInducer::InduceNext``, csrc/cuda/inducer.cu:95) implemented
-    with dense scatters instead of sorts: on TPU, an O(N) id->local map
-    beats the O(M log^2 M) bitonic argsorts of
-    :func:`unique_first_occurrence` by ~4x at frontier widths >= 100k.
-    Random element-ops (~7ns each on v5-lite regardless of table size)
-    dominate, so the hop costs exactly FOUR per candidate — scatter-max
-    of an encoded marker, read-back, commit scatter, resolve read — via
-    a single map whose value encoding makes existing assignments beat
-    in-batch provisional markers under max.  New nodes receive
+    with dense scatters into an O(N) id->local map.  A random element op
+    is what it pays for: on a TPU v5e a gather or scatter of 768,000
+    int32 elements takes 3.7-8.0 ms (4.9-10.4 ns an element; the
+    scatter-max into the map grows with the map, 4.9 ms at 2.45 M nodes
+    and 8.0 ms at 27.8 M) where a two-operand ``lax.sort`` of 937,984
+    takes 1.0 ms (my chip run, PR 29, scripts/induce_micro.py).  So the
+    hop costs exactly FOUR such passes per candidate — scatter-max of an
+    encoded marker, read-back, commit scatter, resolve read — via a
+    single map whose value encoding makes existing assignments beat
+    in-batch provisional markers under max.  The last hop, the widest,
+    does not come here (:func:`induce_final`).  New nodes receive
     consecutive local ids in first-occurrence order, so per-hop frontier
     slices of ``node_buf`` are exactly the newly discovered nodes, and
     seeds placed first keep ``node_buf[:batch] == seeds``.
@@ -194,16 +199,20 @@ def dense_induce(state: DenseInduceState, cand: jnp.ndarray
 @scoped("glt.sample.induce")
 def dense_induce_final(state: DenseInduceState, cand: jnp.ndarray
                        ) -> tuple:
-    """Last-hop :func:`dense_induce`: same contract, one fewer map op.
+    """Last-hop :func:`dense_induce` on the id map: same contract, one
+    fewer map op.  The form :func:`induce_final` keeps for a buffer that
+    may already have overflowed, and the reference its tests hold the
+    sorted form to.
 
     After the final hop no later hop reads the ``seen`` map, so the
     commit scatter (op 3 of :func:`dense_induce`) is dead work; losers of
     the provisional scatter-max resolve through an ``[m]``-sized gather
     of the winner's freshly assigned id instead of re-reading the map.
-    Saves one full-width random scatter at the widest frontier (the
-    single most expensive op of the whole pipeline).  The returned
-    ``state.seen`` is stale (still holds provisional markers) and MUST
-    NOT be fed to another induce call; ``node_buf``/``count`` are exact.
+    Still four random passes with the node-buffer scatter: 20.1-22.5 ms
+    at 768,000 candidates (my chip run, PR 29, scripts/induce_micro.py).
+    The returned ``state.seen`` is stale (still holds provisional
+    markers) and MUST NOT be fed to another induce call;
+    ``node_buf``/``count`` are exact.
     """
     seen, node_buf, count = state
     n2 = seen.shape[0]
@@ -236,6 +245,140 @@ def dense_induce_final(state: DenseInduceState, cand: jnp.ndarray
     node_buf = node_buf.at[slot].set(jnp.where(is_first, cand, -1))
     count = count + jnp.sum(is_first.astype(jnp.int32))
     return DenseInduceState(seen, node_buf, count), local
+
+
+# Row width of the blocked segmented fill: eight 128-lane tiles.
+_FILL_COLS = 1024
+
+
+def _fill_doubling(head: jnp.ndarray, value: jnp.ndarray, axis: int):
+    """Inclusive "latest head wins" scan along ``axis`` as log2(size)
+    shift-and-select steps; returns ``(any head so far, its value)``."""
+    size, d = head.shape[axis], 1
+    while d < size:
+        shift = [(0, 0)] * head.ndim
+        shift[axis] = (d, 0)
+        keep = [slice(None)] * head.ndim
+        keep[axis] = slice(0, size - d)
+        value = jnp.where(head, value, jnp.pad(value[tuple(keep)], shift))
+        head = head | jnp.pad(head[tuple(keep)], shift)
+        d *= 2
+    return head, value
+
+
+def _run_fill(head: jnp.ndarray, value: jnp.ndarray) -> jnp.ndarray:
+    """``value`` of the nearest ``head`` at or before each position (a
+    segmented copy scan; positions before the first head are undefined).
+
+    Two levels of shift-and-select steps, inside ``_FILL_COLS``-wide rows
+    and once over the rows' last columns: 0.27 ms at 937,984 slots where
+    ``lax.associative_scan``, which recurses over stride-2 slices of a
+    1-D array, takes 2.0 ms (my chip run, PR 29, scripts/induce_micro.py).
+    """
+    n = head.shape[0]
+    rows = -(-n // _FILL_COLS)
+    grid = lambda a: jnp.pad(a, (0, rows * _FILL_COLS - n)).reshape(
+        rows, _FILL_COLS)
+    head, value = _fill_doubling(grid(head), grid(value), 1)
+    # What each row hands on: its last head's value, or what it was handed.
+    _, carry = _fill_doubling(head[:, -1], value[:, -1], 0)
+    carry = jnp.concatenate([carry[:1], carry[:-1]])
+    return jnp.where(head, value, carry[:, None]).reshape(-1)[:n]
+
+
+def sorted_final_slots(known: int, capacity: int, m: int) -> int:
+    """Keys the sorted last-hop inducer handles for a call of static
+    shape, ``known + m``, or 0 where :func:`induce_final` takes the map
+    form (the ``glt.sample.induce_sorted_slots`` gauge)."""
+    return known + m if known <= capacity else 0
+
+
+def record_sorted_slots(hop: int, slots: int) -> None:
+    """Engagement of the sorted last-hop inducer, a trace-time fact the
+    samplers record when their program is built: the static
+    :func:`sorted_final_slots` of hop ``hop`` (summed over node types in
+    the typed sampler), 0 where the map form was chosen."""
+    _metrics.gauge("glt.sample.induce_sorted_slots", "keys the sorted "
+                   "last-hop inducer handles in the last sampler built",
+                   {"hop": str(hop)}).set(slots)
+
+
+def induce_final(state: DenseInduceState, cand: jnp.ndarray, known: int
+                 ) -> tuple:
+    """Last-hop :func:`dense_induce`: same ``local``, ``node_buf`` and
+    ``count``; the form is chosen at trace time from static shapes.
+
+    ``known`` is the caller's static bound on ``state.count``: seeds plus
+    every candidate of the earlier hops.  After the final hop nothing
+    reads the id map again, so where every known node is sure to sit in
+    ``node_buf`` (``known <= capacity``) the hop runs as sorts and scans
+    over ``node_buf[:known] ++ cand`` and never touches ``seen``
+    (:func:`_sorted_induce_final`: 4.2-4.6 ms where the map form takes
+    20.1-22.5 at the GraphSAGE cells' 169,984 + 768,000 keys, 0.57-0.58
+    against 0.90-1.42 at the typed cell's 39,840-67,712, and the two
+    agree bit for bit at every one of those widths; my chip run, PR 29,
+    scripts/induce_micro.py — no width was found at which the map
+    wins).  Where the buffer may already have overflowed, the map alone
+    remembers the nodes past its end, and :func:`dense_induce_final`
+    keeps the numbering exact.  Either way the returned ``state.seen``
+    MUST NOT be fed to another induce call.
+    """
+    capacity = state.node_buf.shape[0] - 1
+    if sorted_final_slots(known, capacity, cand.shape[0]):
+        return _sorted_induce_final(state, cand, known)
+    return dense_induce_final(state, cand)
+
+
+@scoped("glt.sample.induce")
+def _sorted_induce_final(state: DenseInduceState, cand: jnp.ndarray,
+                         known: int) -> tuple:
+    """:func:`dense_induce_final` without a random pass: four sorts, a
+    segmented scan and one contiguous store (see :func:`induce_final`)."""
+    seen, node_buf, count = state
+    cap = node_buf.shape[0] - 1
+    m = cand.shape[0]
+    n = known + m
+    cand = cand.astype(jnp.int32)
+    ids = jnp.concatenate([node_buf[:known], cand])
+    keys = jnp.where(ids >= 0, ids, _INT32_MAX)          # padding last
+    pos = jnp.arange(n, dtype=jnp.int32)
+
+    # No sort here needs to be stable: every key that matters is distinct
+    # (XLA's stable sort carries one more operand to break ties by).
+    sort = functools.partial(jax.lax.sort, is_stable=False)
+    # Sort 1, by (id, position): equal ids form a run whose head is the
+    # earliest position — a buffer slot (its local id) where the node is
+    # known, else the candidate that saw it first.
+    sk, sp = sort((keys, pos), num_keys=2)
+    head = (sk != jnp.concatenate([jnp.full((1,), -1, jnp.int32), sk[:-1]])
+            ) & (sk != _INT32_MAX)
+    new_head = head & (sp >= known)
+    num_new = jnp.sum(new_head.astype(jnp.int32))
+
+    # Sort 2, new heads by position (all else behind them, in any order):
+    # the front is the new stretch of node_buf in first-occurrence order
+    # and the index in it the rank.  Sort 3 carries the rank back to sort
+    # 1's order.
+    _, new_ids, back = sort(
+        (jnp.where(new_head, sp, _INT32_MAX), sk, pos), num_keys=1)
+    _, rank = sort((back, pos), num_keys=1)
+
+    # One segmented fill hands each head's local id to its run; sort 4
+    # returns to candidate order.
+    run_local = _run_fill(head, jnp.where(new_head, count + rank, sp))
+    _, local = sort((sp, run_local), num_keys=1)
+    local = jnp.where(cand >= 0, local[known:], -1)
+
+    # Slots from `count` on hold -1, so the store is one [m] window at
+    # `count` (padded: dynamic_update_slice clamps its start); ids that
+    # land past the capacity are numbered above and dropped here.
+    window = jnp.where(jnp.arange(m, dtype=jnp.int32) < num_new,
+                       new_ids[:m], -1)
+    stored = jax.lax.dynamic_update_slice(
+        jnp.concatenate([node_buf[:cap], jnp.full((m,), -1, jnp.int32)]),
+        window, (count,))
+    node_buf = jnp.concatenate([stored[:cap], node_buf[cap:]])
+    return DenseInduceState(seen, node_buf, count + num_new), local
 
 
 @scoped("glt.sample.induce")

@@ -37,10 +37,12 @@ from ..obs.trace import span as _span
 from ..ops.neighbor_sample import _row_offsets_and_degrees, sample_neighbors
 from ..ops.unique import (
     dense_induce,
-    dense_induce_final,
     dense_induce_init,
     dense_map_fits,
+    induce_final,
+    record_sorted_slots,
     relabel_by_reference,
+    sorted_final_slots,
     unique_first_occurrence,
 )
 from ..sampler.base import NegativeSampling, SamplerOutput
@@ -1027,9 +1029,9 @@ def dist_sample_multi_hop(
     :func:`exchange_one_hop` (or its ring variant, ``collective='ring'``)
     as the one-hop primitive.  ``dedup`` selects the inducer like the
     single-device sampler: 'dense' keeps a per-shard O(N_global) id map
-    (4B per global node per shard — measured ~4x cheaper than the
-    argsorts at wide frontiers), 'sort' the growing argsort buffer;
-    'auto' prefers dense up to a ~1GB map.
+    (4B per global node per shard) for the hops before the last, which
+    runs as sorts and scans (``ops/unique.py::induce_final``), 'sort' the
+    growing argsort buffer; 'auto' prefers dense up to a ~1GB map.
 
     ``exchange_load_factor`` (α) opts into capacity-bounded exchanges:
     each hop's per-owner request buckets hold ``ceil(α * width /
@@ -1127,10 +1129,15 @@ def dist_sample_multi_hop(
                 node_buf = jnp.concatenate([node_buf, leaf_ids])
             new_count = count + jnp.sum(leaf_mask.astype(jnp.int32))
         elif dense:
-            # The final hop never re-reads the id map: dense_induce_final
-            # drops the dead commit scatter (see ops/unique.py).
-            induce = dense_induce_final if last else dense_induce
-            state, nbr_local = induce(state, nbrs.ravel())
+            if last:
+                # Seeds plus every candidate of the earlier hops.
+                known = hop_bounds(widths[0], fanouts,
+                                   frontier_cap).node_bounds[i]
+                state, nbr_local = induce_final(state, nbrs.ravel(), known)
+                record_sorted_slots(
+                    i + 1, sorted_final_slots(known, cap, w * f))
+            else:
+                state, nbr_local = dense_induce(state, nbrs.ravel())
             node_buf = state.node_buf
             new_count = state.count
             nbr_local = nbr_local.reshape(w, f)

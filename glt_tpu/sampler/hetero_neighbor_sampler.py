@@ -23,9 +23,11 @@ from ..ops.negative_sample import sample_negative_edges, weighted_draw
 from ..ops.neighbor_sample import sample_neighbors
 from ..ops.unique import (
     dense_induce,
-    dense_induce_final,
     dense_induce_init,
     dense_map_fits,
+    induce_final,
+    record_sorted_slots,
+    sorted_final_slots,
     unique_first_occurrence,
 )
 from ..typing import EdgeType, NodeType, PADDING_ID, reverse_edge_type
@@ -376,6 +378,7 @@ class HeteroNeighborSampler(BaseSampler):
 
         keys = jax.random.split(key, self.num_hops * len(self.edge_types))
         overflow = jnp.zeros((), bool)
+        sorted_slots = 0    # of the last hop's inducers, over the types
 
         for hop in range(self.num_hops):
             # 1) sample every active edge type from its src frontier
@@ -438,11 +441,17 @@ class HeteroNeighborSampler(BaseSampler):
                         jnp.zeros((buflen - leaf_off - total_wf,), bool)])
                     fast_leaf[t] = (leaf_off, leaf_region, count[t])
                 elif t in dense_state:
-                    # Final hop: nothing re-reads the id map afterwards,
-                    # so skip the commit scatter (ops/unique.py).
-                    induce = (dense_induce_final
-                              if hop + 1 == self.num_hops else dense_induce)
-                    dense_state[t], locs = induce(dense_state[t], cands)
+                    if hop + 1 == self.num_hops:
+                        # No more nodes are known than the type has.
+                        known = min(raw_interior[t],
+                                    self._num_nodes_by_type[t])
+                        dense_state[t], locs = induce_final(
+                            dense_state[t], cands, known)
+                        sorted_slots += sorted_final_slots(
+                            known, max(cap[t], 1), total_wf)
+                    else:
+                        dense_state[t], locs = dense_induce(
+                            dense_state[t], cands)
                     uniques_src = dense_state[t].node_buf
                     merged_count = dense_state[t].count
                     inverse_tail = locs
@@ -497,6 +506,7 @@ class HeteroNeighborSampler(BaseSampler):
                 # the hop frontier is consumed; only newly discovered
                 # nodes expand next hop
                 frontier[t] = new_frontier.get(t)
+        record_sorted_slots(self.num_hops, sorted_slots)
 
         def cat_or_empty(lst, width_hint=1):
             if lst:

@@ -41,10 +41,12 @@ from ..ops.negative_sample import sample_negative_edges, weighted_draw
 from ..ops.subgraph import node_subgraph
 from ..ops.unique import (
     dense_induce,
-    dense_induce_final,
     dense_induce_init,
     dense_map_fits,
+    induce_final,
+    record_sorted_slots,
     relabel_by_reference,
+    sorted_final_slots,
     unique_first_occurrence,
 )
 from ..typing import PADDING_ID
@@ -186,9 +188,11 @@ class NeighborSampler(BaseSampler):
       seed: base PRNG seed; each ``sample_from_nodes`` call advances a
         counter so batches are independent yet reproducible (the analog of
         the curand Philox stream setup, random_sampler.cu:71-73).
-      dedup: 'dense' (O(N) scatter-map inducer, ~10x faster at wide
-        frontiers), 'sort' (O(M log^2 M) argsort-based, no O(N) state), or
-        'auto' (dense unless the id map would exceed ~1GB).
+      dedup: what carries the hops before the last: 'dense' (an O(N)
+        id map, four random passes a candidate), 'sort' (argsort-based,
+        no O(N) state, two argsorts and seven random passes), or 'auto'
+        (dense unless the id map would exceed ~1GB).  The last hop of
+        'dense' runs as sorts and scans (``ops/unique.py::induce_final``).
       last_hop_dedup: when False, final-hop neighbors skip the inducer
         entirely and land in a contiguous leaf block of the node list
         (duplicates allowed).  The sampled edge multiset, every edge's
@@ -199,9 +203,9 @@ class NeighborSampler(BaseSampler):
         the interior node's sampled out-edges (the tree-unrolled
         semantics of the original GraphSAGE algorithm).  The node list
         may repeat leaf ids, so ``num_sampled_nodes[-1]`` counts sampled
-        (not unique) leaves.  Cuts the widest frontier from six random
-        element-ops per candidate to one (the neighbor read); its effect
-        on the chip is not measured.  Default True = exact reference
+        (not unique) leaves.  Leaves the widest frontier no inducer at
+        all (four sorts and a fill in exact mode); its effect on the
+        chip is not measured.  Default True = exact reference
         semantics (unique node list, csrc/cuda/inducer.cu:95).
     """
 
@@ -298,10 +302,11 @@ class NeighborSampler(BaseSampler):
         """One fused multi-hop sample. seeds: [batch_size], -1 padded.
 
         Dedup strategy ('dense' default): an O(N) scatter-map inducer
-        (:func:`dense_induce`) replaces per-hop argsorts — on TPU the
-        sorts were ~10x the rest of the pipeline at hop-3 frontier
-        widths.  'sort' keeps the growing-buffer argsort path for graphs
-        too large for the dense id map.
+        (:func:`dense_induce`) for every hop but the last, which runs as
+        sorts and scans and never touches the map
+        (:func:`induce_final`, which has the chip's numbers).  'sort'
+        keeps the growing-buffer argsort path for graphs too large for
+        the dense id map.
         """
         fanouts = self.num_neighbors
         widths = self._widths
@@ -383,8 +388,16 @@ class NeighborSampler(BaseSampler):
                     node_buf = jnp.concatenate([node_buf, leaf_ids])
                 new_count = count + jnp.sum(leaf_mask.astype(jnp.int32))
             elif dense:
-                induce = dense_induce_final if last else dense_induce
-                state, nbr_local = induce(state, cand)
+                if last:
+                    # Seeds plus every candidate of the earlier hops: all
+                    # the nodes the buffer can hold before this one.
+                    known = hop_bounds(widths[0], fanouts,
+                                       self.frontier_cap).node_bounds[i]
+                    state, nbr_local = induce_final(state, cand, known)
+                    record_sorted_slots(
+                        i + 1, sorted_final_slots(known, cap, w * f))
+                else:
+                    state, nbr_local = dense_induce(state, cand)
                 node_buf = state.node_buf
                 new_count = state.count
                 nbr_local = nbr_local.reshape(w, f)

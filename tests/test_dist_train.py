@@ -326,6 +326,36 @@ def test_dist_hop_blocks_keep_their_static_bounds(lhd, variant):
                               s.hop_bounds)
 
 
+@pytest.mark.parametrize("variant", [
+    {}, {"frontier_cap": 8}, {"collective": "ring"}])
+def test_dist_sampler_output_equals_the_map_forms(variant, monkeypatch):
+    """Every shard's ``SamplerOutput`` on a four-shard mesh with the sorted
+    last hop inside ``shard_map`` against the parent's program."""
+    import glt_tpu.parallel.dist_sampler as mod
+    from glt_tpu.parallel import DistNeighborSampler
+    from tests.test_neighbor_sampler import assert_outputs_equal, map_form
+
+    n_dev, bs = 4, 4
+    mesh = Mesh(np.array(jax.devices()[:n_dev]), ("shard",))
+    rng = np.random.default_rng(1)
+    src = np.repeat(np.arange(512), rng.integers(0, 9, 512))
+    g = shard_graph(CSRTopo(np.stack([src, rng.integers(0, 512, src.size)]),
+                            num_nodes=512), n_dev)
+
+    def sample():
+        s = DistNeighborSampler(g, mesh, num_neighbors=[3, 3, 2],
+                                batch_size=bs, **variant)
+        return [s.sample_from_nodes(jnp.asarray(
+            _dist_seeds(g, bs, it, pad_shard=it)[:n_dev]))
+            for it in range(2)]
+    got = sample()
+    with map_form(monkeypatch, mod) as parent:
+        want = sample()
+    assert len(parent) == 1
+    for a, b in zip(got, want):
+        assert_outputs_equal(a, b)
+
+
 @pytest.mark.parametrize("scanned", [False, True])
 def test_dist_train_step_trims_and_equals_whole_steps(scanned):
     """N steps of ``make_dist_train_step`` / ``make_scanned_dist_train_step``

@@ -533,3 +533,43 @@ def test_scanned_hetero_step_matches_eager():
         e_losses.append(float(loss))
     assert g_losses == pytest.approx(e_losses, rel=1e-5), (g_losses,
                                                            e_losses)
+
+
+@pytest.mark.parametrize("layout", ["uncapped", "overflowing", "as_the_cell",
+                                    "no_frontier"])
+def test_typed_sampler_output_equals_the_map_forms(layout, monkeypatch):
+    """The typed sampler's whole output with the sorted last hop against
+    the parent's program, same relations, seeds and key: a type whose
+    capacity can hold every node known before the last hop sorts, one
+    whose buffer may already have overflowed keeps the id map."""
+    import glt_tpu.sampler.hetero_neighbor_sampler as mod
+    from tests.test_neighbor_sampler import assert_outputs_equal, map_form
+    from tests.test_rgat_igbh import TRIM_LAYOUTS, igbh_graphs
+
+    caps, fronts, overflows = TRIM_LAYOUTS[layout]
+    graphs, slots = igbh_graphs(seed=2), []
+    real = mod.sorted_final_slots
+    monkeypatch.setattr(mod, "sorted_final_slots",
+                        lambda *a: slots.append(real(*a)) or slots[-1])
+
+    def sample():
+        samp = HeteroNeighborSampler(graphs, [3, 2, 2], "paper",
+                                     batch_size=4, seed=0,
+                                     node_capacity=caps,
+                                     frontier_capacity=fronts)
+        return [samp.sample_from_nodes(NodeSamplerInput(np.asarray(seeds)),
+                                       key=jax.random.PRNGKey(k))
+                for k, seeds in enumerate(([0, 7, 21, 40], [3, 3, 59, -1]))]
+    got = sample()
+    # Tight capacities leave types under their bound on known nodes
+    # (in sorted order of the types: the last is paper).
+    assert [bool(n) for n in slots] == {
+        "overflowing": [False] * 4, "as_the_cell": [True] * 3 + [False],
+    }.get(layout, [True] * 4)
+    with map_form(monkeypatch, mod) as parent:
+        want = sample()
+    assert len(parent) == len(slots) // 2
+    assert any(bool((o.metadata or {}).get("overflow", False))
+               for o in got) == overflows
+    for a, b in zip(got, want):
+        assert_outputs_equal(a, b)

@@ -4,6 +4,8 @@ Mirrors the reference's sampler tests (test/python/test_neighbor_sampler.py):
 tiny CSR graphs with closed-form expectations, checking dedup order,
 relabel consistency, direction transpose, and link-path metadata.
 """
+import contextlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -375,3 +377,77 @@ def test_hop_blocks_keep_their_static_bounds(dedup, lhd, variant):
     assert s.hop_bounds.node_bounds[-1] == s.node_capacity
     for seed in range(3):
         assert_hop_layout(hop_sample(s, variant, seed), s.hop_bounds)
+
+
+# -- the last hop's inducer: sorted form against the parent's program ------
+@contextlib.contextmanager
+def map_form(monkeypatch, module):
+    """Inside the block the samplers of ``module`` trace the parent's last
+    hop, ``dense_induce_final``; yields the list of its uses, so a test
+    can tell a fresh trace from a cached program."""
+    from glt_tpu.ops.unique import dense_induce_final
+    uses = []
+
+    def parent(state, cand, known):
+        uses.append(known)
+        return dense_induce_final(state, cand)
+    with monkeypatch.context() as patch:
+        patch.setattr(module, "induce_final", parent)
+        yield uses
+
+
+def assert_outputs_equal(got, want):
+    """Every array of two sampler outputs, bit for bit."""
+    a, ta = jax.tree.flatten(got)
+    b, tb = jax.tree.flatten(want)
+    assert ta == tb
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.fixture
+def sorted_slots():
+    """``hop -> glt.sample.induce_sorted_slots{hop}``, read from a registry
+    that records for the length of the test."""
+    from glt_tpu.obs import metrics
+    metrics.reset()
+    metrics.enable()
+    yield lambda hop: metrics.snapshot().get(
+        "glt.sample.induce_sorted_slots{hop=%d}" % hop)
+    metrics.disable()
+    metrics.reset()
+
+
+INDUCE_VARIANTS = dict(
+    {name: make(True) for name, make in HOP_VARIANTS.items()},
+    # The bound on known nodes (80) passes the capacity: nodes past the
+    # buffer's end live in the id map alone, so the map form stays.
+    known_past_capacity={"frontier_cap": 16, "node_capacity": 76})
+
+
+@pytest.mark.parametrize("variant", sorted(INDUCE_VARIANTS))
+@pytest.mark.parametrize("with_edge", [False, True])
+def test_sampler_output_equals_the_map_forms(variant, with_edge,
+                                             monkeypatch, sorted_slots):
+    """The whole ``SamplerOutput`` with the sorted last hop against the
+    parent's program, same graph, seeds and key: uncapped, under a
+    frontier cap, an occupancy capacity that overflows, padded seeds."""
+    import glt_tpu.sampler.neighbor_sampler as mod
+
+    def build():
+        return NeighborSampler(hop_graph(), HOP_FANOUT, batch_size=HOP_BATCH,
+                               with_edge=with_edge,
+                               **INDUCE_VARIANTS[variant])
+    new = build()
+    got = [hop_sample(new, variant, seed) for seed in range(3)]
+    w = new._widths
+    known, m = w[0] + w[0] * 3 + w[1] * 3, w[2] * 2
+    sorts = variant != "known_past_capacity"
+    assert sorts == (known <= new.node_capacity)
+    assert sorted_slots(3) == (known + m if sorts else 0)
+    with map_form(monkeypatch, mod) as parent:
+        old = build()
+        want = [hop_sample(old, variant, seed) for seed in range(3)]
+    assert len(parent) == 1
+    for a, b in zip(got, want):
+        assert_outputs_equal(a, b)

@@ -150,6 +150,20 @@ def test_every_scope_of_the_taxonomy_is_in_the_compiled_hlo(
     assert found <= SAMPLE | STEP | ROUTE, found - (SAMPLE | STEP | ROUTE)
 
 
+@pytest.mark.parametrize("program", sorted(LOWER))
+def test_the_sorted_last_hop_stays_under_the_inducers_scope(
+        compiled_text, program):
+    """The four sorts of ``ops/unique.py::induce_final`` are in every
+    cell's program and carry ``glt.sample.induce``, so
+    ``sample_induce_ms`` reads them and ``unscoped_share`` does not."""
+    text, _ = compiled_text(program)
+    sorts = [re.findall(r'op_name="([^"]*)"', line)
+             for line in text.splitlines() if re.search(r"\bsort\(", line)]
+    assert len(sorts) == 4
+    assert all(names and names[0].endswith("glt.sample.induce/sort")
+               for names in sorts), sorts
+
+
 def _without_debug_info(text):
     """An HLO module's text less its metadata, with every ``%name``
     numbered by first appearance: XLA derives instruction names from the

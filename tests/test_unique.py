@@ -103,3 +103,138 @@ def test_relabel_missing_id_returns_minus_one():
     ref = jnp.array([5, 3, -1], jnp.int32)
     q = jnp.array([3, 8, 5], jnp.int32)
     assert np.asarray(relabel_by_reference(ref, q)).tolist() == [1, -1, 0]
+
+
+# -- the last hop's inducer: sorted form against the map form -------------
+
+def _induce_case(name):
+    """``(num_nodes, capacity, prior, cand)`` of one named input."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    n, prior = 500, rng.choice(500, 40, replace=False)
+    if name == "heavy_repeats":
+        cap, cand = 400, (rng.random(256) ** 3 * n).astype(np.int64)
+        cand[rng.random(256) < 0.1] = -1
+    elif name == "prior_partly_full":
+        prior = np.concatenate([prior[:17], np.full(23, -1)])
+        cap, cand = 300, rng.integers(-1, n, 200)
+    elif name == "all_padding":
+        cap, cand = 100, np.full(64, -1)
+    elif name == "all_one_id":
+        cap, cand = 100, np.full(64, 499)
+    elif name == "all_known":
+        cap, cand = 100, rng.choice(prior, 96)
+    elif name == "capacity_exceeded":
+        # 40 known + ~150 new into 64 slots: ids past the capacity are
+        # still numbered, none of them written.
+        cap, cand = 64, rng.integers(0, n, 192)
+    elif name == "capacity_met_exactly":
+        cap, cand = 72, np.concatenate([np.arange(32) + 460, prior[:9]])
+    elif name == "width_not_power_of_two":
+        cap, cand = 250, rng.integers(-1, n, 197)
+    else:
+        raise KeyError(name)
+    return n, cap, prior.astype(np.int32), np.asarray(cand, np.int32)
+
+
+_INDUCE_CASES = ["heavy_repeats", "prior_partly_full", "all_padding",
+                 "all_one_id", "all_known", "capacity_exceeded",
+                 "capacity_met_exactly", "width_not_power_of_two"]
+
+
+def _under(wrap, fn, state, cands):
+    """``fn(state, cand)`` for every row of ``cands`` [4, m] from the same
+    ``state``: jitted calls, the body of one ``lax.scan``, or the four
+    shards of one ``shard_map``."""
+    if wrap == "jit":
+        outs = [jax.jit(fn)(state, c) for c in cands]
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    if wrap == "scan":
+        return jax.jit(lambda s, cs: jax.lax.scan(
+            lambda carry, c: (carry, fn(s, c)), 0, cs)[1])(state, cands)
+    from jax.sharding import Mesh, PartitionSpec as P
+    mesh = Mesh(np.array(jax.devices()[:4]), ("shard",))
+    return jax.jit(jax.shard_map(
+        lambda s, cs: jax.tree.map(lambda x: x[None], fn(s, cs[0])),
+        mesh=mesh, in_specs=(P(), P("shard")), out_specs=P("shard")))(
+            state, cands)
+
+
+@pytest.mark.parametrize("wrap", ["jit", "scan", "shard_map"])
+@pytest.mark.parametrize("case", _INDUCE_CASES)
+def test_sorted_induce_final_equals_map_form(case, wrap):
+    """``induce_final`` in its sorted form against ``dense_induce_final``,
+    field for field: the same local id for every candidate, the same
+    node buffer, the same count (the buffer's last slot is the map form's
+    write dump and holds no node)."""
+    from glt_tpu.ops.unique import (dense_induce, dense_induce_final,
+                                    dense_induce_init, induce_final,
+                                    sorted_final_slots)
+    n, cap, prior, cand = _induce_case(case)
+    known, m = prior.shape[0], cand.shape[0]
+    assert sorted_final_slots(known, cap, m) == known + m
+    state, _ = dense_induce(dense_induce_init(n, cap), jnp.asarray(prior))
+    # Four candidate rows from the one prior state: the case itself, two
+    # rotations of it and its reverse (other first occurrences).
+    cands = jnp.asarray(np.stack([cand, np.roll(cand, 7),
+                                  np.roll(cand, -31), cand[::-1]]))
+    (want_s, want_l) = _under(wrap, dense_induce_final, state, cands)
+    (got_s, got_l) = _under(
+        wrap, lambda s, c: induce_final(s, c, known), state, cands)
+    np.testing.assert_array_equal(np.asarray(got_l), np.asarray(want_l))
+    np.testing.assert_array_equal(np.asarray(got_s.node_buf)[:, :cap],
+                                  np.asarray(want_s.node_buf)[:, :cap])
+    np.testing.assert_array_equal(np.asarray(got_s.count),
+                                  np.asarray(want_s.count))
+    if case == "capacity_exceeded":
+        assert (np.asarray(got_s.count) > cap).all()
+        assert (np.asarray(got_l) >= cap).any()
+
+
+def test_induce_final_keeps_the_map_where_the_buffer_may_have_overflowed():
+    """Where the static bound on known nodes passes the capacity, nodes
+    past the buffer's end live in the id map alone: the map form runs,
+    the gauge's value is 0, and a node that overflowed earlier keeps its
+    number."""
+    from glt_tpu.ops.unique import (dense_induce, dense_induce_final,
+                                    dense_induce_init, induce_final,
+                                    sorted_final_slots)
+    prior = jnp.arange(10, 22, dtype=jnp.int32)          # 12 into 8 slots
+    cand = jnp.asarray([21, 3, 10, 21, 4], jnp.int32)
+    assert sorted_final_slots(12, 8, 5) == 0
+    state, _ = dense_induce(dense_induce_init(30, 8), prior)
+    got_s, got_l = induce_final(state, cand, 12)
+    want_s, want_l = dense_induce_final(state, cand)
+    assert np.asarray(got_l).tolist() == [11, 12, 0, 11, 13]
+    np.testing.assert_array_equal(np.asarray(got_l), np.asarray(want_l))
+    assert int(got_s.count) == int(want_s.count) == 14
+
+
+def test_sorted_induce_final_makes_no_random_pass_and_leaves_the_map():
+    """The sorted form as lowered: four sorts, no gather, no scatter, and
+    the id map handed through unread (its one use is the result)."""
+    from glt_tpu.ops.unique import (dense_induce_final, dense_induce_init,
+                                    induce_final)
+    state = dense_induce_init(1000, 300)
+    cand = jnp.zeros((200,), jnp.int32)
+
+    def lowered(fn):
+        return jax.jit(fn).lower(state, cand).as_text()
+    text = lowered(lambda s, c: induce_final(s, c, 40))
+    assert text.count("stablehlo.sort") == 4
+    assert "gather" not in text and "scatter" not in text
+    uses = [line for line in text.splitlines() if "tensor<1002xi32>" in line]
+    assert all("func.func" in line or "return" in line for line in uses), uses
+    old = lowered(dense_induce_final)
+    assert "stablehlo.scatter" in old and "stablehlo.gather" in old
+
+
+@pytest.mark.parametrize("n", [1, 7, 1024, 1025, 5000])
+def test_run_fill_copies_each_heads_value_down_its_run(n):
+    from glt_tpu.ops.unique import _run_fill
+    rng = np.random.default_rng(n)
+    head = rng.random(n) < 0.01
+    head[0] = True
+    value = rng.integers(0, 1 << 30, n).astype(np.int32)
+    want = value[np.maximum.accumulate(np.where(head, np.arange(n), 0))]
+    got = jax.jit(_run_fill)(jnp.asarray(head), jnp.asarray(value))
+    np.testing.assert_array_equal(np.asarray(got), want)
