@@ -62,6 +62,27 @@ def seed_loss(batch_size: int):
     return loss
 
 
+@scoped("glt.step.loss")
+def pair_bce_loss(z, meta):
+    """Upstream's unsupervised objective (examples/graph_sage_unsup_ppi.py,
+    dist_sage_unsup.py): ``binary_cross_entropy_with_logits((z_src *
+    z_dst).sum(-1), edge_label)`` over the pairs of
+    ``meta['edge_label_index']`` (rows of ``z``) whose ``edge_label`` is
+    not padding.  Returns ``(loss, acc)``, ``acc`` the share of pairs
+    whose logit has the label's sign."""
+    eli, label = meta["edge_label_index"], meta["edge_label"]
+    valid = (eli[0] >= 0) & (eli[1] >= 0) & (label >= 0)
+    last = z.shape[0] - 1
+    logits = (z[jnp.clip(eli[0], 0, last)]
+              * z[jnp.clip(eli[1], 0, last)]).sum(-1)
+    ce = optax.sigmoid_binary_cross_entropy(
+        logits, (label > 0).astype(logits.dtype))
+    n = jnp.maximum(valid.sum(), 1)
+    loss = jnp.where(valid, ce, 0).sum() / n
+    acc = jnp.where(valid, (logits > 0) == (label > 0), False).sum() / n
+    return loss, acc
+
+
 def graph_inputs(out):
     """``(edge_index, edge_mask, aux)`` of a sampler output, homogeneous
     or typed (dicts by relation; ``aux`` is the seed type's)."""
@@ -109,8 +130,9 @@ def loss_and_grads(model, loss, hops=None, mean_over=None):
     (loss, acc, grads)``: forward, loss and backward.
 
     ``loss(z, y, aux) -> (loss, acc)`` is the only part that differs by
-    task (:func:`seed_loss`; the link and subgraph steps wrap the
-    caller's).  ``hops`` is whatever layout the sampler has: a model that
+    task (:func:`seed_loss`; :func:`pair_bce_loss` or the caller's for
+    the link step, whose ``y`` is the batch's pair metadata; the
+    subgraph step wraps the caller's).  ``hops`` is whatever layout the sampler has: a model that
     trims runs trimmed (:func:`hop_trimming`).  ``dropout_key=None`` is
     the evaluation-mode forward.  ``mean_over`` names the mesh axes of an
     enclosing ``shard_map``: gradients, loss and accuracy are then

@@ -28,8 +28,8 @@ from ..obs import profiler as _profiler
 from ..obs.trace import span as _span
 from ..typing import PADDING_ID
 from .step import (TrainState, gated_update, graph_inputs,  # noqa: F401
-                   hop_trimming, loss_and_grads, seed_cross_entropy,
-                   seed_loss)
+                   hop_trimming, loss_and_grads, pair_bce_loss,
+                   seed_cross_entropy, seed_loss)
 
 # Epoch-driver instrumentation (docs/observability.md).  Only the HOST
 # loops are instrumented — the jitted step bodies must stay span-free
@@ -103,7 +103,8 @@ def make_gather_xy(id2index=None, dedup: bool = False,
     Feature rows and labels ride as arguments (not closures) so callers
     can jit without re-marshalling GB-scale captured arrays; ``id2index``
     (the hotness-reorder indirection) applies to feature ROWS only —
-    labels stay indexed by global id.
+    labels stay indexed by global id; ``labels=None`` (a task without
+    node labels: seed edges) gives ``y = None``.
 
     ``dedup=True`` fetches each unique row from HBM once and scatters it
     back to every batch position (bit-identical ``x``; see
@@ -136,6 +137,8 @@ def make_gather_xy(id2index=None, dedup: bool = False,
                         else jnp.take(id2index, gid, axis=0, mode="clip"))
                 x = gather_rows(rows_arg, ridx, force=force)
                 x = jnp.where(valid[:, None], x, 0)
+        if labels_arg is None:
+            return x, None
         with jax.named_scope("glt.gather.label"):
             y = jnp.where(valid,
                           jnp.take(labels_arg, gid, axis=0, mode="clip"),
@@ -216,13 +219,29 @@ def _check_cache(feature_cache, rows_dtype, dim):
             f"feature_cache dim {feature_cache.dim} != feature dim {dim}")
 
 
-def _scanned_supervised(model, tx, batch_size: int, dropout_seed: int,
+def _batch_flags(out):
+    """What a scanned step counts of a batch beside loss and accuracy:
+    the capacity-overflow flag (0 for a sampler without a capacity) and,
+    for a batch with negative pairs, a second column, the slots filled by
+    the non-strict padding pass."""
+    meta = out.metadata or {}
+    ovf = (meta["overflow"].astype(jnp.int32) if "overflow" in meta
+           else jnp.zeros((), jnp.int32))
+    if "neg_strict" not in meta:
+        return ovf
+    return jnp.stack([ovf, jnp.sum(~meta["neg_strict"], dtype=jnp.int32)])
+
+
+def _scanned_supervised(model, tx, loss, dropout_seed: int,
                         hops, batch_of, arrays_of, label: str,
                         feature_cache=None):
-    """The wrapper of both scanned supervised steps: ONE jitted
-    ``lax.scan`` of the body over ``seeds_blk [G, B]``, as
-    ``step(state, seeds_blk, key) -> (state, losses [G], accs [G],
-    overflows [G])`` compiling under the compilewatch ``label``.
+    """The wrapper of the scanned steps over sampled seed blocks: ONE
+    jitted ``lax.scan`` of the body over ``seeds_blk [G, B]`` (seed
+    edges: ``[G, 2, q]``), as ``step(state, seeds_blk, key) -> (state,
+    losses [G], accs [G], overflows [G])`` compiling under the
+    compilewatch ``label``; ``loss(z, y, aux) -> (loss, acc)`` is the
+    task's (:func:`~glt_tpu.models.step.seed_loss`, or the pair loss of
+    the link step).
 
     ``batch_of(arrays, cache, seeds, key) -> (cache, out, x, y)`` is the
     factory's own part: one batch sampled and gathered out of
@@ -236,7 +255,7 @@ def _scanned_supervised(model, tx, batch_size: int, dropout_seed: int,
     batch's capacity-overflow flag (zeros for an uncapped sampler): a
     flagged batch trained with its excess nodes' edges masked.
     """
-    grads_of = loss_and_grads(model, seed_loss(batch_size), hops)
+    grads_of = loss_and_grads(model, loss, hops)
     update = gated_update(tx)
 
     @partial(jax.jit, donate_argnums=(2,))
@@ -251,9 +270,7 @@ def _scanned_supervised(model, tx, batch_size: int, dropout_seed: int,
             loss, acc, grads = grads_of(st.params, x, edge_index,
                                         edge_mask, y, aux, rng)
             st = update(st, grads, jnp.any(seeds >= 0))
-            ovf = (out.metadata["overflow"].astype(jnp.int32)
-                   if out.metadata else jnp.zeros((), jnp.int32))
-            return (st, cache), (loss, acc, ovf)
+            return (st, cache), (loss, acc, _batch_flags(out))
 
         keys = jax.random.split(key, seeds_blk.shape[0])
         (state, cache), (losses, accs, ovfs) = jax.lax.scan(
@@ -320,7 +337,8 @@ def make_scanned_node_train_step(model, tx, sampler, rows, labels,
         return cache, out, x, y
 
     return _scanned_supervised(
-        model, tx, batch_size, dropout_seed, sampler.hop_bounds, batch_of,
+        model, tx, seed_loss(batch_size), dropout_seed, sampler.hop_bounds,
+        batch_of,
         lambda: (g.indptr, g.indices, g.gather_edge_ids, hot_rows, labels),
         "scanned_node_step", feature_cache)
 
@@ -342,8 +360,12 @@ def node_seed_blocks(train_idx, batch_size: int, group: int, rng):
 def run_scanned_epoch(step, state, train_idx, batch_size: int,
                       group: int, rng, base_key, start_block: int = 0,
                       on_block=None):
-    """One epoch through a scanned train step (node or hetero variant).
+    """One epoch through a scanned train step (node, hetero or link
+    variant).
 
+    ``train_idx`` is ``[n]`` seed nodes, or ``[2, n]`` seed edges for a
+    :func:`make_scanned_link_train_step` (blocks ``[G, 2, q]``,
+    :func:`link_seed_blocks`).
     Shuffles ``train_idx`` into ``[G, B]`` blocks, pre-stages them to
     the device, drives ``step`` per block, and reduces the metrics with
     ONE device concat + ONE host fetch — per-element ``list(ls)`` slices
@@ -351,7 +373,9 @@ def run_scanned_epoch(step, state, train_idx, batch_size: int,
     path.  Returns ``(state, losses [n_real], accs [n_real],
     overflow_count)`` as host numpy (the fetch is the epoch's sync
     point); ``overflow_count`` is 0 for steps without an overflow
-    channel.
+    channel.  A step whose flags have further columns (the link step's
+    padded negative slots) names a counter for each in
+    ``step.flag_counters``; they are summed in the same fetch.
 
     ``start_block``/``on_block`` are the resume seam
     (:class:`~glt_tpu.ckpt.driver.TrainLoop`): the first ``start_block``
@@ -367,9 +391,11 @@ def run_scanned_epoch(step, state, train_idx, batch_size: int,
 
     import numpy as np
 
+    seed_blocks = (link_seed_blocks if np.ndim(train_idx) == 2
+                   else node_seed_blocks)
     blocks = [jax.device_put(jnp.asarray(b.astype(np.int32)))
-              for b in node_seed_blocks(train_idx, batch_size, group, rng)]
-    n_real = -(-len(train_idx) // batch_size)
+              for b in seed_blocks(train_idx, batch_size, group, rng)]
+    n_real = -(-np.shape(train_idx)[-1] // batch_size)
     # Real batches already consumed before the resume point: the loss
     # trim below only accounts for the blocks this call actually runs.
     n_real = max(0, n_real - int(start_block) * group)
@@ -419,12 +445,15 @@ def run_scanned_epoch(step, state, train_idx, batch_size: int,
             else np.zeros((0,), np.float32))
     accs = (np.asarray(jax.device_get(jnp.concatenate(accs)))[:n_real]
             if accs else np.zeros((0,), np.float32))
-    ovf = (int(np.asarray(jax.device_get(
-        jnp.concatenate(ovfs))).sum()) if ovfs else 0)
-    counter = getattr(step, "overflow_counter", None)
-    if counter is not None:
-        counter.inc(ovf)
-    return state, losses, accs, ovf
+    if not ovfs:
+        return state, losses, accs, 0
+    # [batches] overflow flags, or [batches, k] with the overflow flag in
+    # column 0: one fetch, one sum a column.
+    flags = np.asarray(jax.device_get(jnp.concatenate(ovfs)))
+    sums = flags.reshape(flags.shape[0], -1).sum(axis=0).tolist()
+    for counter, n in zip(getattr(step, "flag_counters", ()), sums):
+        counter.inc(n)
+    return state, losses, accs, sums[0]
 
 
 def _resident_rows(f, whole: bool = True):
@@ -549,10 +578,10 @@ def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
                                                batch_size)
 
     step = _scanned_supervised(
-        model, tx, batch_size, dropout_seed, hops if seed_hops else None,
-        batch_of, lambda: (graph_arrays, rows, labels_tgt),
-        "scanned_hetero_step")
-    step.overflow_counter = _M_HETERO_OVF
+        model, tx, seed_loss(batch_size), dropout_seed,
+        hops if seed_hops else None, batch_of,
+        lambda: (graph_arrays, rows, labels_tgt), "scanned_hetero_step")
+    step.flag_counters = (_M_HETERO_OVF,)
     return step
 
 
@@ -569,8 +598,8 @@ def _take_rows(rows_arg, id2index, node):
 
 
 def _scan_unsupervised(tx, grads_of, batch_of):
-    """The jitted scan of the link and subgraph steps, which carry
-    ``(params, opt_state)`` and report losses only: ``run(arrays, params,
+    """The jitted scan of the subgraph step, which carries ``(params,
+    opt_state)`` and reports losses only: ``run(arrays, params,
     opt_state, blocks, key)`` with ``batch_of(arrays, *slot, key) -> (out, x, y,
     aux, any_valid)`` one batch of the block's leading axis.  The model
     runs in evaluation mode (no dropout key), as it always has here."""
@@ -594,62 +623,104 @@ def _scan_unsupervised(tx, grads_of, batch_of):
     return run
 
 
-def make_scanned_link_train_step(model, tx, sampler, rows, loss_fn,
+def init_train_state(model, tx, feature_dim: int, rng,
+                     dtype=jnp.float32) -> TrainState:
+    """Params/opt-state of a homogeneous model from the rows' width
+    alone (one row, one padded edge slot), as
+    :func:`init_hetero_state` does for the typed ones: parameter shapes
+    do not follow the batch, so initialising never runs it."""
+    params = model.init({"params": rng}, jnp.zeros((1, feature_dim), dtype),
+                        jnp.full((2, 1), PADDING_ID, jnp.int32),
+                        jnp.zeros((1,), bool))
+    return TrainState(params=params, opt_state=tx.init(params),
+                      step=jnp.zeros((), jnp.int32))
+
+
+_M_LINK_OVF = _metrics.counter(
+    "glt.link.overflowed_batches",
+    "scanned link batches whose seed-union sample overflowed its node "
+    "capacity (counted by run_scanned_epoch at its loss fetch)")
+_M_LINK_PADDED = _metrics.counter(
+    "glt.link.neg_padded_slots",
+    "negative slots of scanned link batches that no strict trial filled "
+    "(the non-strict padding pass; same fetch)")
+
+
+def make_scanned_link_train_step(model, tx, sampler, rows,
+                                 loss_fn=pair_bce_loss,
                                  neg_sampling=None, group: int = 8):
-    """ONE jitted program trains ``group`` consecutive seed-edge batches:
-    negative sampling (strict trials + padding), multi-hop sampling,
-    feature gather and the step's body under ``lax.scan`` — the TPU
-    answer to the reference's per-worker in-flight batch concurrency
-    (dist_options.py:21-100): link-prediction batches are small enough
-    that dispatch latency rivals their device time.
+    """ONE jitted program trains ``G`` consecutive seed-edge batches:
+    negative sampling (strict trials + padding), multi-hop sampling from
+    the seed union, :func:`make_gather_xy` and the step's body under
+    ``lax.scan`` (:func:`_scanned_supervised`) — the TPU answer to the
+    reference's per-worker in-flight batch concurrency
+    (dist_options.py:21-100).  The model is trimmed to the union's
+    ``hop_bounds`` where it can be: the loss reads seed rows only.
 
     Args:
-      sampler: :class:`~glt_tpu.sampler.neighbor_sampler.NeighborSampler`.
+      sampler: :class:`~glt_tpu.sampler.neighbor_sampler.NeighborSampler`
+        of ``q`` seed edges a batch; its ``node_capacity``, if any, is
+        the seed union's (``calibrate_node_capacity(..., neg_sampling=...)``).
       rows: device-resident feature matrix / Feature (split_ratio 1.0).
-      loss_fn: ``(z, meta) -> scalar`` given node embeddings ``z`` and
-        the batch metadata (``edge_label_index``, ``edge_label`` for
-        binary mode, triplet indices for triplet mode).
+      loss_fn: ``(z, meta) -> (loss, acc)`` (or a scalar loss) given the
+        seed rows' embeddings ``z`` (every row, for a model that does not
+        trim) and the batch metadata (``edge_label_index``,
+        ``edge_label`` for binary mode, triplet indices for triplet
+        mode).  The default is upstream's unsupervised objective,
+        :func:`~glt_tpu.models.step.pair_bce_loss`.
       neg_sampling: the loader's :class:`NegativeSampling` (or None).
 
-    Returns ``step(params, opt_state, src [G, q], dst [G, q], key) ->
-    (params, opt_state, losses [G])``; seed-edge blocks are -1 padded, and
-    a batch without a seed edge moves nothing.
+    Returns ``step(state, edges_blk [G, 2, q], key) -> (state, losses
+    [G], accs [G], flags [G] or [G, 2])``, the shape
+    :func:`run_scanned_epoch` drives; seed-edge blocks are -1 padded, and
+    a batch without a seed edge moves nothing.  ``flags`` is the overflow
+    flag; with binary negatives column 0 is the flag and column 1 the
+    negative slots left to the non-strict padding pass
+    (``glt.link.overflowed_batches`` / ``glt.link.neg_padded_slots``).
     """
     g = sampler.graph
     rows = _device_rows(rows, "link")
-    id2index = rows.id2index
+    hot_rows = rows.hot_rows
+    gather_xy = make_gather_xy(rows.id2index)
 
     mode = None if neg_sampling is None else neg_sampling.mode
     amount = 0 if neg_sampling is None else int(round(neg_sampling.amount))
     cdf = None if neg_sampling is None else neg_sampling.cdf()
-    weighted = cdf is not None
-    impl = partial(sampler._sample_edges_impl, mode, amount, weighted)
+    impl = partial(sampler._sample_edges_impl, mode, amount, cdf is not None)
     q = sampler.batch_size
+    union = sampler.seed_union(neg_sampling)
+    _metrics.gauge("glt.link.seed_union_width", "seed slots the last "
+                   "scanned link step built samples from").set(
+        union.batch_size)
+    _metrics.gauge("glt.link.node_rows", "node rows of the batch of the "
+                   "last scanned link step built").set(union.node_capacity)
 
-    def batch_of(arrays, s, d, k):
+    def batch_of(arrays, cache, edges, k):
         indptr, indices, eids, sorted_indices, rows_arg, cdf_arg = arrays
+        s, d = edges[0], edges[1]
         out = impl(indptr, indices, eids, sorted_indices, s, d, cdf_arg, k)
         meta = dict(out.metadata)
         if mode == "binary":
             pos = jnp.where(s >= 0, 1, PADDING_ID)
             meta["edge_label"] = jnp.concatenate(
                 [pos, jnp.zeros((q * amount,), jnp.int32)])
-        return (out, _take_rows(rows_arg, id2index, out.node), None, meta,
-                jnp.any(s >= 0))
+        x, _ = gather_xy(rows_arg, None, out)
+        return cache, out, x, meta
 
-    run = _scan_unsupervised(
-        tx, loss_and_grads(model, lambda z, y, meta: (loss_fn(z, meta),
-                                                      None)), batch_of)
+    def loss(z, meta, aux):
+        value = loss_fn(z, meta)
+        return value if isinstance(value, tuple) else (
+            value, jnp.zeros((), jnp.float32))
 
-    def step(params, opt_state, src_blk, dst_blk, key):
+    def arrays_of():
         sorted_ix = g.sorted_indices if mode is not None else g.indices
-        cdf_arg = (jnp.zeros((1,), jnp.float32) if cdf is None else cdf)
-        with _compilewatch.label("scanned_link_step"):
-            return run((g.indptr, g.indices, g.gather_edge_ids, sorted_ix,
-                        rows.hot_rows, cdf_arg), params, opt_state,
-                       (jnp.asarray(src_blk, jnp.int32),
-                        jnp.asarray(dst_blk, jnp.int32)), key)
+        cdf_arg = jnp.zeros((1,), jnp.float32) if cdf is None else cdf
+        return (g.indptr, g.indices, g.gather_edge_ids, sorted_ix, hot_rows,
+                cdf_arg)
 
+    step = _scanned_supervised(model, tx, loss, 0, union.hop_bounds,
+                               batch_of, arrays_of, "scanned_link_step")
+    step.flag_counters = (_M_LINK_OVF, _M_LINK_PADDED)
     return step
 
 
@@ -713,26 +784,50 @@ def make_scanned_subgraph_train_step(model, tx, sampler, rows, loss_fn,
     return step
 
 
-def link_seed_blocks(edge_index, batch_size: int, group: int, rng):
-    """Shuffled seed-edge ``[G, q]`` src/dst blocks, -1 padded.
+def shuffled_positions(n: int, rng, block: int):
+    """The positions ``0..n-1`` in a shuffled order, ``block`` at a time,
+    without holding a permutation of all of them: position ``i`` of the
+    pass is a keyed bijection of ``i`` (a four-round Feistel network over
+    the next power of four, walked until it lands under ``n``), the key
+    drawn from ``rng``.  Memory and time are those of one block."""
+    import numpy as np
 
-    Host-side epoch driver for :func:`make_scanned_link_train_step`:
-    yields ``(src_blk, dst_blk, n_batches)`` where the trailing block may
-    carry fully-padded batches (their losses are 0-valid and ignorable).
+    half = max(1, -(-(max(n, 2) - 1).bit_length() // 2))
+    low = np.uint64((1 << half) - 1)
+    keys = rng.integers(0, 1 << 32, size=4, dtype=np.uint64)
+
+    def mix(v):
+        left, right = v >> np.uint64(half), v & low
+        for k in keys:
+            f = (right * np.uint64(0x9E3779B1) + k) & np.uint64(0xFFFFFFFF)
+            f = ((f ^ (f >> np.uint64(15))) * np.uint64(0x85EBCA6B)
+                 ) & np.uint64(0xFFFFFFFF)
+            left, right = right, left ^ ((f ^ (f >> np.uint64(13))) & low)
+        return (left << np.uint64(half)) | right
+
+    for lo in range(0, n, block):
+        pos = mix(np.arange(lo, min(lo + block, n), dtype=np.uint64))
+        while True:
+            out = pos >= np.uint64(n)
+            if not out.any():
+                break
+            pos[out] = mix(pos[out])
+        yield pos.astype(np.int64)
+
+
+def link_seed_blocks(edge_index, batch_size: int, group: int, rng):
+    """Shuffled seed-edge ``[G, 2, q]`` blocks (``[:, 0]`` sources,
+    ``[:, 1]`` destinations), -1 padded: the epoch driver's blocks for
+    :func:`make_scanned_link_train_step`, as :func:`node_seed_blocks` for
+    seed nodes.  The shuffle is drawn a block at a time
+    (:func:`shuffled_positions`): a pass over a graph's own edges never
+    holds a permutation, or a permuted copy, of all of them.
     """
     import numpy as np
 
     e = np.asarray(edge_index)
-    perm = rng.permutation(e.shape[1])
-    src, dst = e[0][perm], e[1][perm]
-    n = src.shape[0]
     per_block = batch_size * group
-    for lo in range(0, n, per_block):
-        sb = np.full((group, batch_size), -1, np.int64)
-        db = np.full((group, batch_size), -1, np.int64)
-        chunk_s = src[lo: lo + per_block]
-        chunk_d = dst[lo: lo + per_block]
-        m = chunk_s.shape[0]
-        sb.reshape(-1)[:m] = chunk_s
-        db.reshape(-1)[:m] = chunk_d
-        yield sb, db, -(-m // batch_size)
+    for pos in shuffled_positions(e.shape[1], rng, per_block):
+        blk = np.full((2, per_block), -1, np.int64)
+        blk[:, : pos.shape[0]] = e[:, pos]
+        yield blk.reshape(2, group, batch_size).transpose(1, 0, 2)

@@ -18,6 +18,10 @@ call, and no flag turns them off.  The whole taxonomy:
 ``glt.sample.hop<k>``  one hop's neighbour read: degree lookup, draw,
                        ``indices``/``edge_ids`` read (k from 1)
 ``glt.sample.induce``  dedup and relabel (``ops/unique.py``)
+``glt.sample.negative`` the link path's negative draw and its membership
+                       search in the column-sorted CSR
+``glt.sample.relabel`` the link path's pair index (seed edges and
+                       negatives located among the seed rows)
 ``glt.gather.feat``    feature rows out of the table
 ``glt.gather.label``   label rows
 ``glt.route.bucket``   owner bucketing of ids (``build_routing``)

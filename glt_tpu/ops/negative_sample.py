@@ -82,6 +82,10 @@ class NegativeSampleOutput(NamedTuple):
     src: jnp.ndarray   # [num] sampled source ids (-1 where nothing found)
     dst: jnp.ndarray   # [num]
     mask: jnp.ndarray  # [num] bool
+    # [num] bool: the slot passed a strict trial, so it is not an edge;
+    # False under ``padding`` is the non-strict pass (a drawn pair that
+    # may be an edge), which ``mask`` alone cannot tell from a strict one.
+    strict: jnp.ndarray
 
 
 def sample_negative_edges(
@@ -102,7 +106,8 @@ def sample_negative_edges(
     (random_negative_sampler.cu:118): ``trials`` strict rejection rounds,
     then, when ``padding`` is set, unfilled slots fall back to their last
     (possibly positive) draw so the output is always exactly ``num`` pairs —
-    the reference's non-strict padding pass (:153-160).
+    the reference's non-strict padding pass (:153-160).  ``strict`` says
+    per slot which of the two it was.
 
     Hetero seed-edge types pass ``num_dst_nodes`` (dst drawn over the
     destination type's id space); ``src_cdf``/``dst_cdf`` switch the
@@ -131,7 +136,7 @@ def sample_negative_edges(
     pick = lambda a: jnp.take_along_axis(a, best[None, :], axis=0)[0]
     out_src, out_dst = pick(src), pick(dst)
     if padding:
-        return NegativeSampleOutput(out_src, out_dst, jnp.ones_like(ok))
+        return NegativeSampleOutput(out_src, out_dst, jnp.ones_like(ok), ok)
     out_src = jnp.where(ok, out_src, PADDING_ID)
     out_dst = jnp.where(ok, out_dst, PADDING_ID)
-    return NegativeSampleOutput(out_src, out_dst, ok)
+    return NegativeSampleOutput(out_src, out_dst, ok, ok)

@@ -125,7 +125,22 @@ def hop_bounds(batch_size: int, fanouts: Sequence[int],
                      tuple(edges))
 
 
-def measure_occupancy(sampler: "NeighborSampler", seed_batches) -> np.ndarray:
+class SampleSizes(NamedTuple):
+    """Static sizes of a sampler at one seed width (the node path's
+    ``batch_size``, or the link path's seed union):
+    :meth:`NeighborSampler.sizes_at`."""
+    batch_size: int
+    widths: Tuple[int, ...]
+    node_capacity: int
+    full_node_capacity: int
+    capped: bool
+    edge_capacity: int
+    hop_bounds: HopBounds
+
+
+def measure_occupancy(sampler: "NeighborSampler", seed_batches,
+                      neg_sampling: Optional[NegativeSampling] = None
+                      ) -> np.ndarray:
     """Unique-node counts per seed batch (ONE host fetch for all batches).
 
     The sampler's padded node buffer is sized to the zero-dedup worst case
@@ -138,12 +153,21 @@ def measure_occupancy(sampler: "NeighborSampler", seed_batches) -> np.ndarray:
 
     In leaf-block mode (``last_hop_dedup=False``) the final hop's width is
     static, so only interior hops are counted.
+
+    A ``[2, q]`` batch is a batch of seed edges: it goes through
+    ``sample_from_edges`` with ``neg_sampling``, and the count is the
+    seed union's (sample it with a sampler that holds no capacity, or
+    the counts stop at the capacity).
     """
     import jax as _jax
 
     counts = []
     for seeds in seed_batches:
-        out = sampler.sample_from_nodes(NodeSamplerInput(seeds))
+        if np.ndim(seeds) == 2:
+            out = sampler.sample_from_edges(EdgeSamplerInput(
+                row=seeds[0], col=seeds[1], neg_sampling=neg_sampling))
+        else:
+            out = sampler.sample_from_nodes(NodeSamplerInput(seeds))
         n = out.num_sampled_nodes
         if not sampler.last_hop_dedup:
             n = n[:-1]
@@ -154,7 +178,9 @@ def measure_occupancy(sampler: "NeighborSampler", seed_batches) -> np.ndarray:
 def calibrate_node_capacity(sampler: "NeighborSampler", seed_batches=None,
                             pct: float = 99.0, margin: float = 1.05,
                             multiple: int = 256,
-                            counts: Optional[np.ndarray] = None) -> int:
+                            counts: Optional[np.ndarray] = None,
+                            neg_sampling: Optional[NegativeSampling] = None
+                            ) -> int:
     """Occupancy-sized static node capacity for a calibrated workload.
 
     Samples ``seed_batches`` through ``sampler`` (typically uncapped),
@@ -165,15 +191,23 @@ def calibrate_node_capacity(sampler: "NeighborSampler", seed_batches=None,
     that exceed it are flagged via ``metadata['overflow']`` and their
     excess-node edges are masked (or exactly re-sampled by the loaders'
     full-capacity fallback).
+
+    ``[2, q]`` seed batches, or a ``neg_sampling``, calibrate the link
+    path: the counts are of the seed union under ``neg_sampling``, and
+    the result is bounded by the union's sizes, not the node path's.
     """
+    edges = neg_sampling is not None or (
+        seed_batches is not None and np.ndim(seed_batches[0]) == 2)
     if counts is None:
-        counts = measure_occupancy(sampler, seed_batches)
+        counts = measure_occupancy(sampler, seed_batches, neg_sampling)
+    sizes = (sampler.seed_union(neg_sampling) if edges
+             else sampler.sizes_at(sampler.batch_size))
     interior = float(np.percentile(counts, pct)) * margin
     leaf_w = (0 if sampler.last_hop_dedup
-              else sampler._widths[-1] * sampler.num_neighbors[-1])
+              else sizes.widths[-1] * sampler.num_neighbors[-1])
     cap = int(np.ceil(interior / multiple) * multiple) + leaf_w
-    cap = max(cap, sum(sampler._widths) + leaf_w)
-    return min(cap, sampler.full_node_capacity)
+    cap = max(cap, sum(sizes.widths) + leaf_w)
+    return min(cap, sizes.full_node_capacity)
 
 
 class NeighborSampler(BaseSampler):
@@ -242,39 +276,82 @@ class NeighborSampler(BaseSampler):
             dedup = "dense" if dense_map_fits(graph.num_nodes) else "sort"
         self.dedup = dedup
 
-        self._widths = hop_widths(self.batch_size, self.num_neighbors,
-                                  frontier_cap)
-        self.full_node_capacity = max_sampled_nodes(
-            self.batch_size, self.num_neighbors, frontier_cap)
-        if node_capacity is None:
-            # Zero-dedup worst case — the reference's sizing
-            # (_max_sampled_nodes, neighbor_sampler.py:595-612).
-            self.node_capacity = self.full_node_capacity
-        else:
-            # Occupancy-sized cap (see calibrate_node_capacity): the
-            # buffer holds only the first `node_capacity` uniques; later
-            # discoveries overflow — their edges are masked and the batch
-            # is flagged via metadata['overflow'].
-            nc = int(node_capacity)
-            leaf_w = (0 if self.last_hop_dedup
-                      else self._widths[-1] * self.num_neighbors[-1])
-            floor_cap = sum(self._widths) + leaf_w
-            if nc < floor_cap:
-                raise ValueError(
-                    f"node_capacity {nc} below the frontier floor "
-                    f"{floor_cap} (sum of hop widths + leaf block)")
-            self.node_capacity = min(nc, self.full_node_capacity)
-        self.capped = self.node_capacity < self.full_node_capacity
-        self.edge_capacity = sum(
-            w * f for w, f in zip(self._widths, self.num_neighbors))
-        self.hop_bounds = hop_bounds(self.batch_size, self.num_neighbors,
-                                     frontier_cap, self.node_capacity)
+        # The calibrated capacity as given: the node path holds it to the
+        # sizes of ``batch_size`` seeds, the link path to the sizes of its
+        # seed union (:meth:`seed_union`).
+        self._given_capacity = node_capacity
+        self._sizes = {}
+        mine = self.sizes_at(self.batch_size)
+        self._widths = list(mine.widths)
+        self.full_node_capacity = mine.full_node_capacity
+        self.node_capacity = mine.node_capacity
+        self.capped = mine.capped
+        self.edge_capacity = mine.edge_capacity
+        self.hop_bounds = mine.hop_bounds
 
         self._sample_jit = jax.jit(self._sample_impl)
         self._sample_many_jit = {}
         self._sample_edges_jit = {}
         self._subgraph_jit = {}
         self._full_sibling: Optional["NeighborSampler"] = None
+
+    def sizes_at(self, width: int, unique_bound: bool = False
+                 ) -> SampleSizes:
+        """Static sizes of this sampler run from ``width`` seed slots.
+
+        Without a capacity the node list is sized to the zero-dedup worst
+        case — the reference's sizing (``_max_sampled_nodes``,
+        neighbor_sampler.py:595-612).  With one
+        (:func:`calibrate_node_capacity`, for the width it is used at)
+        the buffer holds only the first ``node_capacity`` uniques; later
+        discoveries overflow — their edges are masked and the batch is
+        flagged via ``metadata['overflow']``.  ``unique_bound`` adds what
+        no calibration is needed for: a de-duplicated node list holds at
+        most ``num_nodes`` ids, so the capacity and every hop bound stop
+        there, and a batch at that bound cannot overflow.
+        """
+        key = (int(width), bool(unique_bound))
+        if key in self._sizes:
+            return self._sizes[key]
+        fanouts, fcap = self.num_neighbors, self.frontier_cap
+        widths = hop_widths(width, fanouts, fcap)
+        full = max_sampled_nodes(width, fanouts, fcap)
+        leaf_w = 0 if self.last_hop_dedup else widths[-1] * fanouts[-1]
+        cap = full
+        if self._given_capacity is not None:
+            nc = int(self._given_capacity)
+            floor_cap = sum(widths) + leaf_w
+            if nc < floor_cap:
+                raise ValueError(
+                    f"node_capacity {nc} below the frontier floor "
+                    f"{floor_cap} (sum of hop widths + leaf block) of "
+                    f"{width} seed slots")
+            cap = min(nc, full)
+        limit = full
+        if unique_bound and self.last_hop_dedup:
+            limit = min(full, max(self.graph.num_nodes, width))
+            cap = min(cap, limit)
+        sizes = SampleSizes(
+            batch_size=int(width), widths=tuple(widths), node_capacity=cap,
+            full_node_capacity=limit, capped=cap < limit,
+            edge_capacity=sum(w * f for w, f in zip(widths, fanouts)),
+            hop_bounds=hop_bounds(width, fanouts, fcap, cap))
+        self._sizes[key] = sizes
+        return sizes
+
+    def seed_union(self, neg_sampling: Optional[NegativeSampling] = None
+                   ) -> SampleSizes:
+        """Sizes of the link path's node sample: it runs from the seed
+        union ``[src, dst, neg_src, neg_dst]`` (binary, ``2q(1 + amount)``
+        slots; triplet ``q(2 + amount)``; none ``2q``), de-duplicated, so
+        its ``hop_bounds`` is the layout a model trims by and its
+        ``node_capacity`` the rows a link batch has."""
+        q = self.batch_size
+        mode = None if neg_sampling is None else neg_sampling.mode
+        amount = 0 if mode is None else int(round(neg_sampling.amount))
+        per_pos = {None: 2, "binary": 2 + 2 * amount,
+                   "triplet": 2 + amount}[mode]
+        return self.sizes_at(q * per_pos, unique_bound=True)
 
     def full_capacity_sibling(self) -> "NeighborSampler":
         """Uncapped twin (same graph/fanouts) for exact re-sampling of
@@ -298,8 +375,10 @@ class NeighborSampler(BaseSampler):
         return key
 
     # -- core jitted multi-hop program ------------------------------------
-    def _sample_impl(self, indptr, indices, edge_ids, seeds, key):
-        """One fused multi-hop sample. seeds: [batch_size], -1 padded.
+    def _sample_impl(self, indptr, indices, edge_ids, seeds, key,
+                     sizes: Optional[SampleSizes] = None):
+        """One fused multi-hop sample. seeds: [batch_size], -1 padded
+        (``sizes``: another seed width's, the link path's seed union).
 
         Dedup strategy ('dense' default): an O(N) scatter-map inducer
         (:func:`dense_induce`) for every hop but the last, which runs as
@@ -309,8 +388,10 @@ class NeighborSampler(BaseSampler):
         the dense id map.
         """
         fanouts = self.num_neighbors
-        widths = self._widths
-        cap = self.node_capacity
+        if sizes is None:
+            sizes = self.sizes_at(self.batch_size)
+        widths = list(sizes.widths)
+        cap = sizes.node_capacity
         dense = self.dedup == "dense"
 
         if dense:
@@ -335,7 +416,7 @@ class NeighborSampler(BaseSampler):
         # Static interior capacity: where the no-dedup leaf block starts.
         leaf_off = cap - widths[-1] * fanouts[-1]
         leaf_mask = None
-        capped = self.capped
+        capped = sizes.capped
         # Largest valid interior local index + 1: under an occupancy-sized
         # cap, nodes assigned locals past this are overflow — their edges
         # are masked and the batch flagged (the uncapped program compiles
@@ -391,8 +472,9 @@ class NeighborSampler(BaseSampler):
                 if last:
                     # Seeds plus every candidate of the earlier hops: all
                     # the nodes the buffer can hold before this one.
-                    known = hop_bounds(widths[0], fanouts,
-                                       self.frontier_cap).node_bounds[i]
+                    known = min(hop_bounds(widths[0], fanouts,
+                                           self.frontier_cap).node_bounds[i],
+                                sizes.full_node_capacity)
                     state, nbr_local = induce_final(state, cand, known)
                     record_sorted_slots(
                         i + 1, sorted_final_slots(known, cap, w * f))
@@ -623,45 +705,33 @@ class NeighborSampler(BaseSampler):
         if mode == "binary":
             # Strict rejection (trials + non-strict padding); weighted
             # draws bias both endpoints through NegativeSampling.weight.
-            negs = sample_negative_edges(indptr, sorted_indices, q * amount,
-                                         kneg, num_nodes,
-                                         src_cdf=node_cdf, dst_cdf=node_cdf)
+            with jax.named_scope("glt.sample.negative"):
+                negs = sample_negative_edges(
+                    indptr, sorted_indices, q * amount, kneg, num_nodes,
+                    src_cdf=node_cdf, dst_cdf=node_cdf)
             seed_ids = jnp.concatenate([src, dst, negs.src, negs.dst])
         elif mode == "triplet":
             # amount negative destinations per positive source
             # (cf. neighbor_sampler.py:332-381 triplet reconstruction).
-            if weighted:
-                neg_dst = weighted_draw(kneg, cdf, (q * amount,))
-            else:
-                neg_dst = jax.random.randint(kneg, (q * amount,), 0,
-                                             num_nodes, dtype=jnp.int32)
-            neg_dst = jnp.where(jnp.repeat(src >= 0, amount), neg_dst,
-                                PADDING_ID)
+            with jax.named_scope("glt.sample.negative"):
+                if weighted:
+                    neg_dst = weighted_draw(kneg, cdf, (q * amount,))
+                else:
+                    neg_dst = jax.random.randint(kneg, (q * amount,), 0,
+                                                 num_nodes, dtype=jnp.int32)
+                neg_dst = jnp.where(jnp.repeat(src >= 0, amount), neg_dst,
+                                    PADDING_ID)
             seed_ids = jnp.concatenate([src, dst, neg_dst])
         else:
             seed_ids = jnp.concatenate([src, dst])
 
-        # Dedup seeds, then run the node path with the union as the batch.
+        # Dedup seeds, then run the node path with the union as the batch,
+        # at the union's own sizes: a capacity calibrated for seed edges,
+        # and never more rows than the graph has nodes.
         seed_width = seed_ids.shape[0]
-        if seed_width != self.batch_size:
-            sub = NeighborSampler.__new__(NeighborSampler)
-            sub.__dict__.update(self.__dict__)
-            sub.batch_size = seed_width
-            sub._widths = hop_widths(seed_width, self.num_neighbors,
-                                     self.frontier_cap)
-            sub.node_capacity = max_sampled_nodes(seed_width,
-                                                  self.num_neighbors,
-                                                  self.frontier_cap)
-            # The seed union runs at its own width's full capacity; an
-            # occupancy cap on the node path does not transfer (different
-            # batch width => different occupancy distribution).
-            sub.full_node_capacity = sub.node_capacity
-            sub.capped = False
-            out = sub._sample_impl(indptr, indices, edge_ids, seed_ids,
-                                   ksample)
-        else:
-            out = self._sample_impl(indptr, indices, edge_ids, seed_ids,
-                                    ksample)
+        out = self._sample_impl(
+            indptr, indices, edge_ids, seed_ids, ksample,
+            self.sizes_at(seed_width, unique_bound=True))
 
         meta = dict(out.metadata or {})
         # Seed ids all first-occur within the hop-0 prefix of the node
@@ -669,26 +739,30 @@ class NeighborSampler(BaseSampler):
         # last_hop_dedup=False the tail leaf block may hold duplicate
         # copies of a seed, and a leaf copy has no deep embedding.
         ref = out.node[:seed_width]
-        if mode == "binary":
-            all_src = jnp.concatenate([src, negs.src])
-            all_dst = jnp.concatenate([dst, negs.dst])
-            meta["edge_label_index"] = jnp.stack([
-                relabel_by_reference(ref, all_src),
-                relabel_by_reference(ref, all_dst),
-            ])
-        elif mode == "triplet":
-            meta["src_index"] = relabel_by_reference(ref, src)
-            meta["dst_pos_index"] = relabel_by_reference(ref, dst)
-            meta["dst_neg_index"] = relabel_by_reference(
-                ref, neg_dst).reshape(q, amount)
-        else:
-            # No negative sampling still emits edge_label_index so the
-            # LinkLoader can locate seed edges in the batch
-            # (neighbor_sampler.py:366-372, the None-or-binary branch).
-            meta["edge_label_index"] = jnp.stack([
-                relabel_by_reference(ref, src),
-                relabel_by_reference(ref, dst),
-            ])
+        with jax.named_scope("glt.sample.relabel"):
+            if mode == "binary":
+                all_src = jnp.concatenate([src, negs.src])
+                all_dst = jnp.concatenate([dst, negs.dst])
+                meta["edge_label_index"] = jnp.stack([
+                    relabel_by_reference(ref, all_src),
+                    relabel_by_reference(ref, all_dst),
+                ])
+                # Which negative slots passed a strict trial (the rest are
+                # the non-strict padding pass and may be edges).
+                meta["neg_strict"] = negs.strict
+            elif mode == "triplet":
+                meta["src_index"] = relabel_by_reference(ref, src)
+                meta["dst_pos_index"] = relabel_by_reference(ref, dst)
+                meta["dst_neg_index"] = relabel_by_reference(
+                    ref, neg_dst).reshape(q, amount)
+            else:
+                # No negative sampling still emits edge_label_index so the
+                # LinkLoader can locate seed edges in the batch
+                # (neighbor_sampler.py:366-372, the None-or-binary branch).
+                meta["edge_label_index"] = jnp.stack([
+                    relabel_by_reference(ref, src),
+                    relabel_by_reference(ref, dst),
+                ])
         out.metadata = meta
         return out
 

@@ -186,15 +186,10 @@ def test_scanned_link_step_matches_serial():
             (s * d).sum(-1), (label > 0).astype(jnp.float32))
         return jnp.where(valid, ce, 0).sum() / jnp.maximum(valid.sum(), 1)
 
-    # Shapes for init: 4q seed union width, [3,3] fanout.
-    from glt_tpu.sampler.neighbor_sampler import hop_widths, max_sampled_nodes
-    sw = 4 * q
-    widths = hop_widths(sw, [3, 3], None)
-    x0 = jnp.zeros((max_sampled_nodes(sw, [3, 3], None), feat.shape[1]))
-    ecap = sum(w * f for w, f in zip(widths, [3, 3]))
-    params0 = model.init({"params": jax.random.PRNGKey(0)}, x0,
-                         jnp.full((2, ecap), -1, jnp.int32),
-                         jnp.zeros((ecap,), bool))
+    from glt_tpu.models import TrainState, init_train_state
+    state0 = init_train_state(model, tx, feat.shape[1],
+                              jax.random.PRNGKey(0))
+    params0 = state0.params
 
     rng = np.random.default_rng(0)
     src = rng.integers(0, 48, (G, q)).astype(np.int64)
@@ -203,7 +198,10 @@ def test_scanned_link_step_matches_serial():
 
     step = make_scanned_link_train_step(model, tx, sampler, feat, loss_fn,
                                         neg, group=G)
-    p1, o1, scanned_losses = step(params0, tx.init(params0), src, dst, base)
+    state1, scanned_losses, _, flags = step(
+        state0, np.stack([src, dst], axis=1), base)
+    assert isinstance(state1, TrainState) and int(state1.step) == G
+    assert flags.shape == (G, 2) and not np.asarray(flags)[:, 0].any()
     scanned_losses = [float(x) for x in np.asarray(scanned_losses)]
 
     # Serial reference with the same per-batch keys.

@@ -41,7 +41,7 @@ def test_strict_negative_sampling_avoids_edges():
         g.indptr, g.sorted_indices, num=256, key=jax.random.key(5),
         num_nodes=n, trials=8, padding=False,
     )
-    src, dst, mask = map(np.asarray, out)
+    src, dst, mask, _ = map(np.asarray, out)
     assert mask.sum() > 200  # density ~0.22 per trial; 8 trials ⇒ nearly all filled
     for s, d, m in zip(src, dst, mask):
         if m:
@@ -55,7 +55,7 @@ def test_negative_sampling_with_padding_always_fills():
         g.indptr, g.sorted_indices, num=64, key=jax.random.key(0),
         num_nodes=n, trials=3, padding=True,
     )
-    src, dst, mask = map(np.asarray, out)
+    src, dst, mask, _ = map(np.asarray, out)
     assert mask.all()
     assert ((src >= 0) & (src < n)).all() and ((dst >= 0) & (dst < n)).all()
 
@@ -84,7 +84,7 @@ def test_weighted_negative_edges_stay_in_support():
     out = sample_negative_edges(
         g.indptr, g.sorted_indices, num=128, key=jax.random.key(1),
         num_nodes=n, trials=8, padding=True, src_cdf=cdf, dst_cdf=cdf)
-    src, dst, _ = map(np.asarray, out)
+    src, dst, _, _ = map(np.asarray, out)
     assert set(np.unique(src)) <= set(support)
     assert set(np.unique(dst)) <= set(support)
 

@@ -545,10 +545,18 @@ def _link_step(h):
         return jnp.where(ok, ((s - d) ** 2).sum(-1), 0).sum() / jnp.maximum(
             ok.sum(), 1)
 
-    step = make_scanned_link_train_step(h.model, TX, h.sampler,
+    link = make_scanned_link_train_step(h.model, TX, h.sampler,
                                         Feature(h.feat), loss_fn, group=2)
+
+    def step(params, opt_state, edges, key):
+        state, losses, _, _ = link(
+            TrainState(params, opt_state, jnp.zeros((), jnp.int32)), edges,
+            key)
+        return state.params, state.opt_state, losses
+
     src = np.array([[0, 1, 2, 3], [-1, -1, -1, -1]])
-    return step, (src, (src + 1) * (src >= 0) - (src < 0))
+    dst = (src + 1) * (src >= 0) - (src < 0)
+    return step, (np.stack([src, dst], axis=1),)
 
 
 def _subgraph_step(h):
