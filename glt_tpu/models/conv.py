@@ -7,12 +7,25 @@ complete TPU framework must provide them.  These layers consume
 with -1 padding and an ``edge_mask``, ``edge_index[0]`` = message source
 (the sampler already transposed direction, neighbor_sampler.py:159-165).
 
-TPU notes: aggregation is ``jax.ops.segment_sum`` with a spill segment for
-padding edges (XLA lowers this to sorted-scatter, MXU-friendly); all matmuls
-are batched over the padded node dimension so shapes are static.
+TPU notes: all matmuls are batched over the padded node dimension so
+shapes are static.  The mean over a destination's in-edges has two forms,
+chosen at trace time by what the caller knows about ``edge_index``:
+
+* any COO (:func:`scatter_mean`, :func:`scatter_sum`,
+  :func:`segment_softmax`): ``jax.ops.segment_sum`` with a spill segment
+  for padding edges.  XLA lowers it to a scatter-add that knows nothing
+  about where its rows go; on the v5e it was the costliest operation of
+  two benchmark cells, 25-45 times over what its bytes cost (PERF.md §6,
+  PR 31).
+* the sampler's hop blocks (:func:`block_mean`; ``SAGEConv(blocks=)``,
+  which :class:`~glt_tpu.models.sage.GraphSAGE` passes whenever it has
+  the layout): the destination of every edge slot is its block's start
+  plus a static offset, so the sum is contiguous slabs added and no
+  scatter runs.
+
 ``SAGEConv`` can aggregate into a static prefix of the rows only
-(``num_dst``), which is how :class:`~glt_tpu.models.sage.GraphSAGE` trims
-each layer to the hops whose result reaches the seeds.
+(``num_dst``), which is how ``GraphSAGE`` trims each layer to the hops
+whose result reaches the seeds.
 
 Mixed precision: every layer takes ``dtype`` (e.g. ``jnp.bfloat16``) — the
 COMPUTE dtype of its Dense matmuls only.  Params stay float32, the MXU
@@ -23,11 +36,13 @@ the MXU's native input type.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
+from jax import lax
 
 from ..obs.scopes import scoped
 
@@ -82,6 +97,61 @@ def segment_softmax(scores: jnp.ndarray, seg: jnp.ndarray, num_segments: int,
     return ex / jnp.maximum(denom[seg_safe], 1e-16)
 
 
+def block_mean(x: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
+               mask: jnp.ndarray, blocks: Sequence[Tuple[int, int]],
+               num_dst: int) -> jnp.ndarray:
+    """``scatter_mean(x[src], dst, num_dst, mask)`` where the edge slots
+    are the sampler's hop blocks: no scatter.
+
+    ``blocks`` is the static ``(width, fanout)`` of each hop block the
+    slots are made of, in order (``HopBounds.blocks``); slot ``s`` of a
+    block aggregates into row ``start + s // fanout``, ``start`` being
+    the ``dst`` of the block's first slot (``-1``: an empty frontier,
+    every slot of the block is then masked).  Each block's slots are
+    read fanout-major (a transpose of 4 bytes a slot), so that ONE row
+    gather writes ``fanout`` contiguous slabs of ``[width, F]`` and the
+    sum over a destination's slots is those slabs added: a streaming
+    read of the messages where ``segment_sum`` is a scatter-add that
+    runs 25-45 times over its bytes (PERF.md §6, PR 31).  The count is
+    the mask summed the same way.  The blocks' means are then written at
+    their dynamic starts, in order, into ``num_dst`` zero rows padded by
+    the widest block (so that no update is clamped; a block without a
+    frontier lands in the padding).  A plain contiguous store serves
+    because the layout has the starts ascend and every row of a block
+    behind its live ones is zero: a later block overwrites only zeros.
+
+    The same terms as the scatter form under the same mask in float32;
+    only the order of a destination's at most ``fanout`` additions
+    differs.
+    """
+    offsets = np.cumsum([0] + [w * f for w, f in blocks])
+    if src.shape[0] != offsets[-1]:
+        raise ValueError(f"{src.shape[0]} edge slots are not the hop "
+                         f"blocks {tuple(blocks)}")
+
+    def fanout_major(a):
+        return [a[o:o + w * f].reshape(w, f).T
+                for o, (w, f) in zip(offsets, blocks)]
+
+    with jax.named_scope("glt.model.agg"):
+        src_t = jnp.concatenate([b.ravel() for b in fanout_major(src)])
+    with jax.named_scope("glt.model.msg"):
+        # Clamped inside the gather: a masked slot's -1 reads row 0 and
+        # no fill pass runs over the messages.
+        msgs = jnp.take(x, src_t, axis=0, mode="clip")
+    with jax.named_scope("glt.model.agg"):
+        out = jnp.zeros((num_dst + max(w for w, _ in blocks),
+                         msgs.shape[1]), msgs.dtype)
+        for o, (w, f), m_t in zip(offsets, blocks, fanout_major(mask)):
+            slabs = msgs[o:o + w * f].reshape(f, w, -1)
+            total = jnp.where(m_t[:, :, None], slabs, 0).sum(0)
+            cnt = m_t.sum(0).astype(msgs.dtype)
+            start = jnp.where(dst[o] >= 0, dst[o], num_dst)
+            out = lax.dynamic_update_slice(
+                out, total / jnp.maximum(cnt, 1)[:, None], (start, 0))
+        return out[:num_dst]
+
+
 class SAGEConv(nn.Module):
     """GraphSAGE convolution (mean aggregator).
 
@@ -95,6 +165,11 @@ class SAGEConv(nn.Module):
     :func:`~glt_tpu.sampler.neighbor_sampler.hop_bounds`), so rows
     ``< num_dst`` are the numbers the whole layer computes for them.
     ``None`` is the whole layer: every row of ``x`` is a destination.
+
+    ``blocks`` says that the edge slots are that layout's hop blocks
+    (their static ``(width, fanout)``, ``HopBounds.blocks``): the mean
+    is then :func:`block_mean`, a contiguous sum a block.  Without it
+    ``edge_index`` is any COO and the mean is :func:`scatter_mean`.
     """
     out_features: int
     use_bias: bool = True
@@ -102,14 +177,18 @@ class SAGEConv(nn.Module):
 
     @nn.compact
     def __call__(self, x, edge_index, edge_mask,
-                 num_dst: Optional[int] = None):
+                 num_dst: Optional[int] = None,
+                 blocks: Optional[Sequence[Tuple[int, int]]] = None):
         num_src = x.shape[0]
         if num_dst is None:
             num_dst = num_src
         src, dst = edge_index[0], edge_index[1]
-        with jax.named_scope("glt.model.msg"):
-            msgs = jnp.take(x, jnp.clip(src, 0, num_src - 1), axis=0)
-        agg = scatter_mean(msgs, dst, num_dst, edge_mask)
+        if blocks is None:
+            with jax.named_scope("glt.model.msg"):
+                msgs = jnp.take(x, jnp.clip(src, 0, num_src - 1), axis=0)
+            agg = scatter_mean(msgs, dst, num_dst, edge_mask)
+        else:
+            agg = block_mean(x, src, dst, edge_mask, blocks, num_dst)
         dt = _mm_dtype(self.dtype)
         with jax.named_scope("glt.model.dense"):
             out = (nn.Dense(self.out_features, use_bias=self.use_bias,

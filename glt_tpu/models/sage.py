@@ -23,6 +23,15 @@ a zero cotangent in the whole model and are never read.  Only rows that
 hold a real seed are exact: a partly padded seed batch leaves hop-1 nodes
 in the rows behind its seeds, and the loss must not read them
 (``seed_cross_entropy(..., num_seeds=)``).
+
+**Block aggregation.**  The same layout says where every edge slot
+aggregates to: slot ``s`` of hop block ``k`` into row ``start_k + s //
+fanout_k``, ``start_k`` one dynamic scalar a block.  With ``hops`` every
+layer therefore sums its hop blocks as contiguous slabs
+(:func:`~glt_tpu.models.conv.block_mean`) and no ``segment_sum`` runs;
+``layer_blocks`` says which blocks a layer reads.  Without ``hops`` the
+batch is any COO and the layers scatter as before.  Nothing selects
+between the two but the presence of the layout.
 """
 from __future__ import annotations
 
@@ -61,6 +70,16 @@ class GraphSAGE(nn.Module):
         return [(nb[min(d + 1, k)], eb[min(d + 1, k)], nb[min(d, k)])
                 for d in range(self.num_layers - 1, -1, -1)]
 
+    def layer_blocks(self, hops: HopBounds
+                     ) -> List[Tuple[Tuple[int, int], ...]]:
+        """The hop blocks ``(width, fanout)`` each layer aggregates in
+        block form under ``hops`` (:func:`~glt_tpu.models.conv.block_mean`),
+        first layer first: the blocks of its ``layer_extents`` edge slots.
+        """
+        k = len(hops.blocks)
+        return [hops.blocks[:min(d + 1, k)]
+                for d in range(self.num_layers - 1, -1, -1)]
+
     @nn.compact
     def __call__(self, x, edge_index, edge_mask, *, train: bool = False,
                  hops: Optional[HopBounds] = None):
@@ -71,6 +90,7 @@ class GraphSAGE(nn.Module):
                     f"batch of {x.shape[0]} rows and {edge_index.shape[1]} "
                     f"edge slots is not laid out by {hops}")
             extents = self.layer_extents(hops)
+            blocks = self.layer_blocks(hops)
         for i in range(self.num_layers):
             last = i == self.num_layers - 1
             dim = self.out_features if last else self.hidden_features
@@ -80,7 +100,8 @@ class GraphSAGE(nn.Module):
             else:
                 n_src, n_edge, n_dst = extents[i]
                 x = conv(x[:n_src], edge_index[:, :n_edge],
-                         edge_mask[:n_edge], num_dst=n_dst)
+                         edge_mask[:n_edge], num_dst=n_dst,
+                         blocks=blocks[i])
             if not last:
                 with jax.named_scope("glt.model.dense"):
                     x = nn.relu(x)

@@ -106,7 +106,10 @@ def hop_trimming(model, hops) -> dict:
     Engagement is a trace-time fact, recorded here when the step is
     built: ``glt.model.layer_edge_slots{layer=l}`` /
     ``glt.model.layer_node_rows{layer=l}`` (the rows layer ``l``
-    computes) against ``glt.model.edge_slots`` / ``.node_rows``.
+    computes) against ``glt.model.edge_slots`` / ``.node_rows``, and
+    ``glt.model.layer_block_slots{layer=l}``: the edge slots layer ``l``
+    aggregates hop block by hop block, without a scatter (a model that
+    has ``layer_blocks(hops)``: ``GraphSAGE``, every slot; else 0).
     """
     if hops is None or not hasattr(model, "layer_extents"):
         return {}
@@ -117,9 +120,15 @@ def hop_trimming(model, hops) -> dict:
           "last hop-trimmed step built").set(whole[0])
     gauge("glt.model.node_rows", "node rows of the sampled batch of the "
           "last hop-trimmed step built").set(whole[1])
-    for i, (_, n_edge, n_dst) in enumerate(model.layer_extents(hops), 1):
+    extents = model.layer_extents(hops)
+    blocks = (model.layer_blocks(hops) if hasattr(model, "layer_blocks")
+              else [()] * len(extents))
+    for i, ((_, n_edge, n_dst), blks) in enumerate(zip(extents, blocks), 1):
         gauge("glt.model.layer_edge_slots", "edge slots one layer "
               "aggregates in that step", {"layer": str(i)}).set(n_edge)
+        gauge("glt.model.layer_block_slots", "of them, aggregated hop "
+              "block by hop block without a scatter",
+              {"layer": str(i)}).set(sum(w * f for w, f in blks))
         gauge("glt.model.layer_node_rows", "rows one layer computes "
               "in that step", {"layer": str(i)}).set(n_dst)
     return {"hops": hops}

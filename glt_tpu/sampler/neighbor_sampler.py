@@ -92,6 +92,9 @@ class HopBounds(NamedTuple):
     """Static hop-block layout of a sampled batch (see :func:`hop_bounds`)."""
     node_bounds: Tuple[int, ...]
     edge_bounds: Tuple[int, ...]
+    # Static (frontier width, fanout) of hop blocks 1..len(fanouts): under
+    # a ``frontier_cap`` the width is not ``edge_bounds``' to tell.
+    blocks: Tuple[Tuple[int, int], ...]
 
 
 def hop_bounds(batch_size: int, fanouts: Sequence[int],
@@ -110,9 +113,24 @@ def hop_bounds(batch_size: int, fanouts: Sequence[int],
     seen before.  Holds for both ``dedup`` strategies, the leaf block of
     ``last_hop_dedup=False`` (it ends at the capacity), a ``frontier_cap``
     (an unexpanded node has no in-edges) and an occupancy capacity
-    (overflow edges are masked) — tests/test_neighbor_sampler.py and
-    tests/test_dist_train.py hold the samplers to it, because
-    :class:`~glt_tpu.models.sage.GraphSAGE` trims its layers by it.
+    (overflow edges are masked).
+
+    **Static destinations.**  Hop block ``k`` is ``blocks[k-1] = (w, f)``:
+    ``w`` frontier slots times ``f`` edge slots each, frontier-major, and
+    the frontier is a contiguous run of the node buffer.  So every
+    unmasked edge at slot ``s`` of the block has
+    ``col == col[first slot of the block] + s // f``.  The first slot
+    holds that start whether it is masked or not, or ``-1`` where the
+    block has no frontier (every slot of it is then masked); any other
+    masked slot may hold anything.  A destination row belongs to one
+    block only (a node is expanded once) and the starts ascend: a block
+    starts at or behind the last live row of the blocks before it.
+
+    tests/test_neighbor_sampler.py, tests/test_link_path.py and
+    tests/test_dist_train.py hold the samplers to both rules, because
+    :class:`~glt_tpu.models.sage.GraphSAGE` trims its layers by the first
+    and aggregates without a scatter by the second
+    (:func:`~glt_tpu.models.conv.block_mean`).
     """
     widths = hop_widths(batch_size, fanouts, frontier_cap)
     edges = [0]
@@ -122,7 +140,7 @@ def hop_bounds(batch_size: int, fanouts: Sequence[int],
     if node_capacity is not None:
         cap = min(cap, int(node_capacity))
     return HopBounds(tuple(min(batch_size + e, cap) for e in edges),
-                     tuple(edges))
+                     tuple(edges), tuple(zip(widths, fanouts)))
 
 
 class SampleSizes(NamedTuple):

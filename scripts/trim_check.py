@@ -26,6 +26,16 @@ and trimmed by ``sampler.hop_bounds`` as the step runs it (GraphSAGE by
   the two and between their gradients is reported in the same norm (a
   gradient's RMS floored the same way).
 
+Since PR 31 a GraphSAGE with the layout also aggregates its hop blocks as
+contiguous sums (``models/conv.py::block_mean``) where the whole model
+scatters, so for a GraphSAGE cell this is the block form against the
+scatter form as well; the benchmark's own check of ``train-scan`` and
+``dist-train`` calls the model without the layout and cannot see that.
+The link cell is not taken here: its whole model's messages are
+``f32[3747840,256]``, 3.8 GB a tensor and several alive in the backward
+pass, and its own ``correct`` already holds the model WITH the union's
+layout to the float32 reference.
+
 The typed cell's whole model does not fit beside its feature tables (the
 class-wide last layer alone is 12 KB a paper row and an edge slot), so in
 that mode the batches' rows are gathered first and the tables' buffers are

@@ -327,6 +327,30 @@ def test_dist_hop_blocks_keep_their_static_bounds(lhd, variant):
 
 
 @pytest.mark.parametrize("variant", [
+    {}, {"frontier_cap": 8}, {"exchange_load_factor": 1.0}])
+@pytest.mark.parametrize("lhd", [True, False])
+def test_dist_hop_blocks_have_static_destinations(lhd, variant):
+    """The rule ``GraphSAGE`` aggregates by without a scatter
+    (models/conv.py::block_mean), held by the dist sampler on every shard,
+    one of them with a half-padded seed batch."""
+    from glt_tpu.parallel import DistNeighborSampler
+    from tests.test_neighbor_sampler import assert_static_destinations
+
+    mesh = Mesh(np.array(jax.devices()[:N_DEV]), ("shard",))
+    g, _, _ = _random_sharded()
+    bs = 4
+    s = DistNeighborSampler(g, mesh, num_neighbors=[3, 3, 2], batch_size=bs,
+                            last_hop_dedup=lhd, **variant)
+    assert s.hop_bounds.blocks[0] == (bs, 3)
+    out = s.sample_from_nodes(jnp.asarray(_dist_seeds(g, bs, 0,
+                                                      pad_shard=1)))
+    for shard in range(N_DEV):
+        starts = assert_static_destinations(
+            jax.tree.map(lambda a: a[shard], out), s.hop_bounds)
+        assert starts[0] == 0 and starts == sorted(starts)
+
+
+@pytest.mark.parametrize("variant", [
     {}, {"frontier_cap": 8}, {"collective": "ring"}])
 def test_dist_sampler_output_equals_the_map_forms(variant, monkeypatch):
     """Every shard's ``SamplerOutput`` on a four-shard mesh with the sorted
@@ -385,6 +409,8 @@ def test_dist_train_step_trims_and_equals_whole_steps(scanned):
     assert snap["glt.model.edge_slots"] == 12 + 24 + 48
     assert snap["glt.model.node_rows"] == 4 + 12 + 24 + 48
     assert [snap["glt.model.layer_edge_slots{layer=%d}" % l]
+            for l in (1, 2, 3)] == [84, 36, 12]
+    assert [snap["glt.model.layer_block_slots{layer=%d}" % l]
             for l in (1, 2, 3)] == [84, 36, 12]
     assert [snap["glt.model.layer_node_rows{layer=%d}" % l]
             for l in (1, 2, 3)] == [40, 16, 4]

@@ -182,6 +182,30 @@ def test_a_capacity_under_the_occupancy_flags_and_masks(world):
     assert (np.asarray(out.metadata["edge_label_index"])[:, Q:] >= 0).all()
 
 
+@pytest.mark.parametrize("case", ["full", "padded_pairs", "overflow"])
+def test_the_seed_union_is_laid_out_in_hop_blocks(world, case):
+    """The union's batch obeys ``union.hop_bounds`` as a node batch obeys
+    its sampler's: the static bounds the link step trims by and the static
+    destinations it aggregates by (tests/test_neighbor_sampler.py)."""
+    from tests.test_neighbor_sampler import (assert_hop_layout,
+                                             assert_static_destinations)
+    graph, _, edges, _ = world
+    real = 5 if case == "padded_pairs" else Q
+    s = (NeighborSampler(graph, [2, 2], batch_size=Q, with_edge=False,
+                         node_capacity=200)
+         if case == "overflow" else _sampler(graph))
+    u = s.seed_union(NEG)
+    for it in range(2):
+        pick = np.random.default_rng(it).choice(edges.shape[1], real, False)
+        out = s.sample_from_edges(EdgeSamplerInput(
+            row=edges[0, pick], col=edges[1, pick], neg_sampling=NEG))
+        if case == "overflow":
+            assert bool(out.metadata["overflow"])
+        assert_hop_layout(out, u.hop_bounds)
+        starts = assert_static_destinations(out, u.hop_bounds)
+        assert starts[0] == 0 and starts == sorted(starts)
+
+
 def test_calibration_over_seed_edges_bounds_the_union(world):
     graph, _, edges, _ = world
     s = NeighborSampler(graph, [2, 2], batch_size=Q, with_edge=False)
@@ -315,3 +339,6 @@ def test_the_link_step_counts_padded_slots_and_names_its_scopes(world):
     layers = sorted(v for k, v in snap.items()
                     if k.startswith("glt.model.layer_edge_slots"))
     assert layers == [256, 1024, 2560]
+    # and every layer aggregates its hop blocks without a scatter
+    assert layers == sorted(v for k, v in snap.items()
+                            if k.startswith("glt.model.layer_block_slots"))

@@ -544,6 +544,114 @@ def test_trimmed_sage_is_the_whole_sage_on_the_seeds(dedup, lhd, variant,
                                    err_msg=str(path))
 
 
+# -- the block aggregation against scatter_mean, directly --------------------
+#: ``name -> (hop blocks (w, f), num_dst, start of each block, live frontier
+#: slots of each block)``.  A start of -1 is an empty frontier (the sampler
+#: writes -1 into every slot of such a block).
+BLOCK_CASES = {
+    "three_blocks": (((4, 3), (9, 2), (14, 2)), 27, (0, 4, 13), (4, 9, 14)),
+    "partly_live": (((8, 3), (24, 2), (48, 2)), 60, (0, 5, 17), (5, 12, 30)),
+    "all_masked_block": (((4, 3), (12, 2), (24, 2)), 30, (0, 3, -1),
+                         (3, 7, 0)),
+    "start_at_the_last_row": (((4, 3), (12, 2)), 5, (0, 4), (4, 1)),
+    "one_block": (((8, 5),), 8, (0,), (8,)),
+}
+
+
+def _block_batch(case, width=7, seed=0):
+    """``x, src, dst, mask`` laid out as the sampler lays a batch out: a
+    frontier slot past its block's live ones has ``dst`` -1 and only
+    masked slots, a live one some masked slots (degree under the fanout,
+    or an overflow-masked neighbour) holding any ``src``."""
+    blocks, num_dst, starts, lives = BLOCK_CASES[case]
+    rng = np.random.default_rng(seed)
+    num_src = num_dst + 40
+    src, dst, mask = [], [], []
+    for (w, f), start, live in zip(blocks, starts, lives):
+        slot = np.arange(w * f) // f
+        m = (slot < live) & (rng.random(w * f) < 0.7)
+        src.append(np.where(rng.random(w * f) < 0.9,
+                            rng.integers(0, num_src, w * f), -1))
+        dst.append(np.where(slot < live, start + slot, -1))
+        mask.append(m & (src[-1] >= 0))
+    x = rng.normal(size=(num_src, width)).astype(np.float32)
+    return (blocks, num_dst, jnp.asarray(x),
+            jnp.asarray(np.concatenate(src), jnp.int32),
+            jnp.asarray(np.concatenate(dst), jnp.int32),
+            jnp.asarray(np.concatenate(mask)))
+
+
+@pytest.mark.parametrize("case", sorted(BLOCK_CASES))
+def test_block_mean_is_scatter_mean_over_hop_blocks(case):
+    """Forward and the gradient w.r.t. ``x`` (what layers 2-3 pay), under
+    ``jit`` with the starts traced: the same terms, reassociated."""
+    from glt_tpu.models.conv import block_mean
+
+    blocks, num_dst, x, src, dst, mask = _block_batch(case)
+    assert bool(mask.any())
+    cot = jnp.asarray(np.random.default_rng(1).normal(
+        size=(num_dst, x.shape[1])), jnp.float32)
+
+    def scatter(x, src, dst, mask):
+        msgs = jnp.take(x, jnp.clip(src, 0, x.shape[0] - 1), axis=0)
+        return scatter_mean(msgs, dst, num_dst, mask)
+
+    def block(x, src, dst, mask):
+        return block_mean(x, src, dst, mask, blocks, num_dst)
+
+    want, got = (jax.jit(fn)(x, src, dst, mask) for fn in (scatter, block))
+    assert got.shape == (num_dst, x.shape[1])
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    (v_w, g_w), (v_g, g_g) = (
+        jax.jit(jax.value_and_grad(
+            lambda x, fn=fn: jnp.vdot(fn(x, src, dst, mask), cot)))(x)
+        for fn in (scatter, block))
+    np.testing.assert_allclose(v_g, v_w, rtol=1e-5)
+    assert np.abs(np.asarray(g_w)).max() > 0
+    np.testing.assert_allclose(g_g, g_w, rtol=1e-6, atol=1e-6)
+
+
+def test_block_mean_refuses_slots_that_are_not_its_blocks():
+    from glt_tpu.models.conv import block_mean
+
+    _, num_dst, x, src, dst, mask = _block_batch("one_block")
+    with pytest.raises(ValueError, match="not the hop blocks"):
+        block_mean(x, src, dst, mask, ((8, 4),), num_dst)
+
+
+@pytest.mark.parametrize("layers", [2, 3])
+def test_trimmed_forward_aggregates_without_a_scatter(layers):
+    """With a layout every layer's gauge says block form, and the lowered
+    forward holds no scatter at all, as the same call without one does."""
+    from glt_tpu import obs
+    from glt_tpu.models.step import hop_trimming
+
+    s, out, x, _ = _hop_batch("dense", True, "uncapped")
+    ei = jnp.stack([out.row, out.col])
+    model = GraphSAGE(hidden_features=16, out_features=5,
+                      num_layers=layers, dropout_rate=0.0)
+    params = model.init(jax.random.PRNGKey(0), x, ei, out.edge_mask)
+    obs.metrics.reset()
+    obs.metrics.enable()
+    try:
+        trim = hop_trimming(model, s.hop_bounds)
+        snap = obs.metrics.snapshot()
+    finally:
+        obs.metrics.disable()
+        obs.metrics.reset()
+    for l in range(1, layers + 1):
+        assert (snap["glt.model.layer_block_slots{layer=%d}" % l]
+                == snap["glt.model.layer_edge_slots{layer=%d}" % l] > 0)
+
+    def lowered(**kw):
+        return jax.jit(lambda p, x, ei, em: model.apply(
+            p, x, ei, em, **kw)).lower(params, x, ei, out.edge_mask
+                                       ).as_text()
+    assert '"stablehlo.scatter"' in lowered()
+    assert "scatter" not in lowered(**trim)
+    assert '"stablehlo.gather"' in lowered(**trim)
+
+
 def test_sage_hops_must_be_the_batchs_own_layout():
     from glt_tpu.sampler import hop_bounds
 
@@ -683,6 +791,8 @@ def test_scanned_node_step_trims_and_equals_whole_steps():
     assert snap["glt.model.node_rows"] == 176
     assert [snap["glt.model.layer_edge_slots{layer=%d}" % l]
             for l in (1, 2, 3)] == [168, 72, 24]
+    assert [snap["glt.model.layer_block_slots{layer=%d}" % l]
+            for l in (1, 2, 3)] == [168, 72, 24]
     assert [snap["glt.model.layer_node_rows{layer=%d}" % l]
             for l in (1, 2, 3)] == [80, 32, 8]
 
@@ -692,6 +802,8 @@ def test_scanned_node_step_trims_and_equals_whole_steps():
         whole = make_scanned_node_train_step(Whole(model), tx, sampler,
                                              feat, labels, bs)
         assert obs.metrics.snapshot()["glt.model.edge_slots"] == 0
+        assert not any(v for k, v in obs.metrics.snapshot().items()
+                       if k.startswith("glt.model.layer_block_slots"))
     finally:
         obs.metrics.disable()
         obs.metrics.reset()

@@ -355,12 +355,40 @@ def assert_hop_layout(out, bounds):
         assert m.sum() == int(np.asarray(out.num_sampled_edges)[k - 1])
 
 
+def assert_static_destinations(out, bounds):
+    """``hop_bounds``' second rule, which ``models/conv.py::block_mean``
+    stands on: every unmasked edge at slot ``s`` of hop block ``k`` has
+    ``col == col[first slot of the block] + s // fanout_k``, the first
+    slot holding that start masked or not, or ``-1`` (then nothing in the
+    block is unmasked); no destination row belongs to two blocks and a
+    block starts at or behind the live rows of those before it.  Returns
+    the start of each block."""
+    col, mask = np.asarray(out.col), np.asarray(out.edge_mask)
+    assert sum(w * f for w, f in bounds.blocks) == col.shape[0]
+    starts, seen_upto, offset = [], 0, 0
+    for k, (w, f) in enumerate(bounds.blocks, 1):
+        assert bounds.edge_bounds[k] - bounds.edge_bounds[k - 1] == w * f
+        blk = slice(offset, offset + w * f)
+        c, m = col[blk], mask[blk]
+        starts.append(int(c[0]))
+        assert c[0] == -1 or c[0] >= seen_upto, (k, c[0], seen_upto)
+        if m.any():
+            want = c[0] + np.arange(w * f) // f
+            assert c[0] >= 0 and (c[m] == want[m]).all(), k
+            seen_upto = int(c[m].max()) + 1
+        offset += w * f
+    return starts
+
+
 def test_hop_bounds_of_the_products_shape():
     from glt_tpu.sampler import hop_bounds
 
     b = hop_bounds(1024, [15, 10, 5], None, 402944)
     assert b.node_bounds == (1024, 16384, 169984, 402944)
     assert b.edge_bounds == (0, 15360, 168960, 936960)
+    assert b.blocks == ((1024, 15), (15360, 10), (153600, 5))
+    assert hop_bounds(1024, [15, 10, 5], 8192).blocks == (
+        (1024, 15), (8192, 10), (8192, 5))
     assert hop_bounds(1024, [15, 10, 5]).node_bounds[-1] == 937984
     capped = hop_bounds(1024, [15, 10, 5], 8192)
     assert capped.node_bounds == (1024, 16384, 98304, 139264)
@@ -377,6 +405,30 @@ def test_hop_blocks_keep_their_static_bounds(dedup, lhd, variant):
     assert s.hop_bounds.node_bounds[-1] == s.node_capacity
     for seed in range(3):
         assert_hop_layout(hop_sample(s, variant, seed), s.hop_bounds)
+
+
+@pytest.mark.parametrize("variant", sorted(HOP_VARIANTS) + ["empty_frontier"])
+@pytest.mark.parametrize("lhd", [True, False])
+@pytest.mark.parametrize("dedup", ["dense", "sort"])
+def test_hop_blocks_have_static_destinations(dedup, lhd, variant):
+    """What the block aggregation stands on (models/conv.py::block_mean):
+    where an edge slot aggregates to is its block's start plus its static
+    position, in every sampler variant the model can meet."""
+    graph = hop_graph()
+    if variant == "empty_frontier":
+        # Seeds without a neighbour: hop 1 samples nothing and hops 2-3
+        # have no frontier at all.
+        s = hop_sampler(graph, dedup, lhd, "uncapped")
+        lone = np.flatnonzero(np.diff(np.asarray(graph.indptr)) == 0)
+        out = s.sample_from_nodes(NodeSamplerInput(lone[:HOP_BATCH]))
+        assert not np.asarray(out.edge_mask).any()
+        assert assert_static_destinations(out, s.hop_bounds) == [0, -1, -1]
+        return
+    s = hop_sampler(graph, dedup, lhd, variant)
+    for seed in range(3):
+        starts = assert_static_destinations(hop_sample(s, variant, seed),
+                                            s.hop_bounds)
+        assert starts[0] == 0 and starts == sorted(starts)
 
 
 # -- the last hop's inducer: sorted form against the parent's program ------
