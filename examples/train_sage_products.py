@@ -6,6 +6,7 @@ The TPU rebuild of the reference's flagship example
 subgraphs/sec.
 
     python examples/train_sage_products.py --scale 0.01 --epochs 3
+    python examples/train_sage_products.py --scale 0.01 --split-ratio 0.5
 """
 import argparse
 import sys
@@ -79,6 +80,14 @@ def main():
     # the leaf-block fast mode (tree-unrolled GraphSAGE semantics).
     ap.add_argument("--last-hop-dedup",
                     action=argparse.BooleanOptionalAction, default=True)
+    # Upstream's tiering (Dataset.init_node_features(split_ratio=,
+    # sort_func=sort_by_in_degree)): that share of the feature rows,
+    # hottest first by in-degree, stays in HBM, the rest in host memory,
+    # gathered per batch inside the loader's collate.  The scanned step
+    # needs resident rows, so a ratio under 1 takes the loader path.
+    ap.add_argument("--split-ratio", type=float, default=1.0,
+                    help="share of the feature rows kept in HBM "
+                         "(< 1: the eager loader loop, --group 0)")
     ap.add_argument("--data-root", default=None,
                     help="dir holding converted real datasets "
                          "(scripts/convert_ogb.py); overrides "
@@ -90,6 +99,17 @@ def main():
         _exds.DATA_ROOT = args.data_root
 
     ds, train_idx = synthetic_products(scale=args.scale)
+    if args.split_ratio < 1.0:
+        from glt_tpu.data import sort_by_in_degree
+
+        whole = ds.get_node_feature()
+        ds.init_node_features(whole.cpu_get(np.arange(whole.size)),
+                              split_ratio=args.split_ratio,
+                              sort_func=sort_by_in_degree)
+        args.group = 0
+        print(f"split_ratio {args.split_ratio}: "
+              f"{ds.get_node_feature().hot_count} of {whole.size} rows "
+              f"in HBM by in-degree, the rest in host memory")
     model = GraphSAGE(hidden_features=args.hidden, out_features=47,
                       num_layers=len(args.fanout),
                       dtype=jax.numpy.bfloat16 if args.bf16 else None)
@@ -167,7 +187,22 @@ def main():
                                 node_capacity=node_cap)
         first = next(iter(loader))
         state = create_train_state(model, jax.random.PRNGKey(0), first, tx)
-        step = make_train_step(model, tx, batch_size=args.batch_size)
+        feat = ds.get_node_feature()
+        if feat.plans_gathers:
+            from glt_tpu.data import calibrate_cold_width
+
+            # A static cold width, as the node capacity is static: every
+            # batch then runs the same two gather programs.
+            cal = [b.node for b, _ in zip(loader, range(args.cap_batches))]
+            feat.set_cold_width(calibrate_cold_width(feat, cal))
+            print(f"cold width {feat.cold_width} rows a batch")
+        # The loader's batches keep the sampler's static layout, so the
+        # model runs trimmed as the scanned step's does (a replayed batch
+        # under the full-capacity sibling's).
+        step = make_train_step(
+            model, tx, batch_size=args.batch_size,
+            hops=(loader.sampler.hop_bounds,
+                  loader.sampler.full_capacity_sibling().hop_bounds))
 
         def run_epoch(state, epoch):
             losses, accs = [], []

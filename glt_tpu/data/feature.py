@@ -12,9 +12,13 @@ TPU redesign — no UVA, no IPC handles:
   across a mesh is the :mod:`glt_tpu.parallel` layer's job, the analog of
   the reference's ``DeviceGroup`` replication, feature.py:31-45);
 * the **cold tier** stays in host numpy and is gathered eagerly on the
-  host — and ONLY at the batch positions that actually resolve cold: the
-  host moves ``n_cold`` rows, not ``B`` rows, and the hot/cold merge is a
-  padded device scatter instead of a double full-batch materialization;
+  host — and ONLY at the batch positions that actually resolve cold.
+  The device resolves the ids (``id2index``, which tier, the cold rows'
+  positions: the *plan*, one small program whose result the host
+  fetches), the host moves ``n_cold`` rows into one reused host buffer
+  and sends it, and a second program gathers the hot rows and places
+  the cold ones (:meth:`Feature.gather` has the details, and the static
+  cold width that keeps it to those two programs);
 * an optional **cross-batch HBM cache** (:mod:`.feature_cache`) fronts the
   cold tier: recently fetched cold rows stay device-resident, so repeat
   lookups (hub nodes under power-law sampling) skip the host entirely —
@@ -38,14 +42,20 @@ VALUES are range-checked before the cast — silent truncation raises
 """
 from __future__ import annotations
 
+import contextlib
+import os
+import threading
 import warnings
-from typing import Optional
+from concurrent.futures import ThreadPoolExecutor
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import metrics as _metrics
 from ..obs.scopes import scoped
+from ..obs.trace import span as _span
 from .feature_cache import cache_init, cache_insert, cache_lookup
 
 _I32_MAX = np.iinfo(np.int32).max
@@ -70,6 +80,37 @@ def require_int32_ids(ids) -> None:
             raise OverflowError(
                 f"node ids [{mn}, {mx}] overflow int32; the id space must "
                 f"fit int32 (relabel/partition first — GLT004)")
+
+
+# Tiered-gather instrumentation (docs/observability.md): rows by the tier
+# that served them, and the rows that crossed the host link with their
+# padding.  Host counts only: both come back with the plan's fetch.
+_M_HOT_ROWS = _metrics.counter(
+    "glt.feature.hot_rows", "rows a tiered gather served from HBM")
+_M_COLD_ROWS = _metrics.counter(
+    "glt.feature.cold_rows", "rows a tiered gather served from the host")
+_M_COLD_SENT = _metrics.counter(
+    "glt.feature.cold_rows_sent",
+    "row slots sent host to device for them, padding included")
+_M_HOT_COUNT = _metrics.gauge(
+    "glt.feature.hot_count",
+    "rows of the hot tier of the last tiered Feature built")
+
+# The cold fetch's threads (four read 100 k rows in 3.7 ms where one takes
+# 9.2 and eight 2.9; scripts/tier_micro.py), and the rows below which one
+# np.take beats handing runs to the pool.
+_COLD_THREADS = 4
+_POOL_MIN_ROWS = 8192
+
+
+class ColdPlan(NamedTuple):
+    """The device's half of a tiered gather's bookkeeping
+    (:meth:`Feature.plan_gather`): device arrays, the last two on their
+    way to the host."""
+    hot_idx: jax.Array      # [B] row in the hot tier, -1 where not hot
+    cold_pos: jax.Array     # [W] batch positions of cold rows, >= B: none
+    cold_local: jax.Array   # [W] their rows in the cold tier
+    counts: jax.Array       # [2] hot rows, cold rows of the whole batch
 
 
 def _pow2_pad(k: int) -> int:
@@ -123,12 +164,67 @@ class Feature:
         self._host_full = feature_array  # for cpu_get / save paths
         self._store = None               # optional disk tier (glt_tpu.store)
         self._stager = None
+        self._init_gather_state()
+
+    def _init_gather_state(self):
+        """What every constructor leaves the gather paths with."""
         self.bytes_from_hbm = 0          # hot-tier bytes served (tiered path)
         self._gather_jit = None
         self._cache = None               # optional cold-tier HBM cache
         self._cache_lookup_jit = None
         self._merge_cached_jit = None
         self._merge_jit = None
+        self._plan_jits = {}             # by static cold width
+        self._patch_jit = None
+        self._cold_width = None          # set_cold_width
+        self._stage_buf = None           # its host buffer
+        self._stage_lock = threading.Lock()
+        self._pool = None                # the cold fetch's threads, lazily
+        if self._cold_count:
+            _M_HOT_COUNT.set(self._hot_count)
+
+    @classmethod
+    def from_tiers(cls, hot_rows, cold_rows: np.ndarray, id2index=None,
+                   dtype=None, dedup: bool = False) -> "Feature":
+        """Constructor from tiers that already exist: ``hot_rows``
+        ``[hot, d]`` on the device (or a host array, placed once) and
+        ``cold_rows`` ``[N - hot, d]`` in host memory, both in the order
+        ``id2index`` gives (row ``id2index[v]`` of the two stacked is node
+        ``v``'s; a device or host ``[N]`` array, or None for the identity:
+        :func:`~glt_tpu.data.reorder.in_degree_order` makes one without
+        the table).  For a table that is made, loaded or converted tier
+        by tier and is larger than either memory alone: the two arrays
+        are adopted as they are, no second copy of any row is held, and
+        ``split_ratio`` reads ``hot / N``.  Gathers are those of the
+        constructor from one array, bit for bit."""
+        self = cls.__new__(cls)
+        cold_rows = np.asarray(cold_rows)
+        if cold_rows.ndim != 2 or np.ndim(hot_rows) != 2 \
+                or hot_rows.shape[1] != cold_rows.shape[1]:
+            raise ValueError(
+                f"hot rows {np.shape(hot_rows)} and cold rows "
+                f"{cold_rows.shape} must be two row blocks of one width")
+        self._hot_count, self._dim = map(int, hot_rows.shape)
+        self._cold_count = int(cold_rows.shape[0])
+        self._n = self._hot_count + self._cold_count
+        if id2index is not None and id2index.shape != (self._n,):
+            raise ValueError(f"id2index {id2index.shape} for {self._n} rows")
+        self.split_ratio = self._hot_count / max(self._n, 1)
+        self.dtype = dtype or jnp.asarray(cold_rows[:1]).dtype
+        self.dedup = bool(dedup)
+        self._quant = None
+        self._hot = jnp.asarray(hot_rows, self.dtype)
+        self._cold = cold_rows if cold_rows.flags.c_contiguous \
+            else np.ascontiguousarray(cold_rows)
+        self._cold_np_dtype = self._cold.dtype
+        self._id2index = (
+            None if id2index is None else jnp.asarray(id2index, jnp.int32))
+        self._id2index_np = None         # fetched on a host lookup, if ever
+        self._host_full = None           # cpu_get reads the tiers
+        self._store = None
+        self._stager = None
+        self._init_gather_state()
+        return self
 
     @classmethod
     def from_store(cls, store, dram_budget_bytes: int,
@@ -192,12 +288,7 @@ class Feature:
             scores[:] = np.asarray(prefetch_scores, np.float64)
             scores[: self._hot_count] = 0.0   # hot prefix never staged
             self._stager.warm(scores)
-        self.bytes_from_hbm = 0
-        self._gather_jit = None
-        self._cache = None
-        self._cache_lookup_jit = None
-        self._merge_cached_jit = None
-        self._merge_jit = None
+        self._init_gather_state()
         return self
 
     def _fetch_cold(self, local_ids: np.ndarray) -> np.ndarray:
@@ -209,6 +300,39 @@ class Feature:
                 np.asarray(local_ids, np.int64) + self._hot_count)
         return self._cold[local_ids]
 
+    def _fetch_cold_into(self, local_ids: np.ndarray, out: np.ndarray
+                         ) -> None:
+        """:meth:`_fetch_cold` written straight into ``out`` (the buffer
+        that crosses the host link): one pass over the rows, split into
+        contiguous runs over a few threads where there are enough of them
+        (``np.take`` releases the GIL; ``mode="clip"`` because
+        ``"raise"`` buffers ``out``, a second copy; the ids are in range
+        by construction)."""
+        n = local_ids.shape[0]
+        if self._stager is not None:
+            out[:] = self._fetch_cold(local_ids)
+            return
+        threads = min(_COLD_THREADS, len(os.sched_getaffinity(0)))
+        if threads <= 1 or n < _POOL_MIN_ROWS:
+            np.take(self._cold, local_ids, axis=0, out=out, mode="clip")
+            return
+        if self._pool is None:
+            self._pool = ThreadPoolExecutor(
+                threads, thread_name_prefix="glt-cold-fetch")
+        step = -(-n // threads)
+        for fut in [self._pool.submit(
+                np.take, self._cold, local_ids[lo: lo + step], 0,
+                out[lo: lo + step], "clip") for lo in range(0, n, step)]:
+            fut.result()
+
+    def _host_id2index(self) -> Optional[np.ndarray]:
+        """``id2index`` as a host array, for the host-side lookups (a
+        feature built from tiers holds it on the device alone until one
+        asks)."""
+        if self._id2index_np is None and self._id2index is not None:
+            self._id2index_np = np.asarray(self._id2index)
+        return self._id2index_np
+
     def stage_ahead(self, ids) -> None:
         """Hint upcoming global ``ids`` to the DRAM stager (async; no-op
         for DRAM-resident features).  The loader calls this at sample
@@ -217,8 +341,8 @@ class Feature:
             return
         ids = np.asarray(ids).reshape(-1)
         ids = ids[ids >= 0].astype(np.int64)
-        if self._id2index_np is not None:
-            ids = self._id2index_np[ids].astype(np.int64)
+        if self._id2index is not None:
+            ids = self._host_id2index()[ids].astype(np.int64)
         self._stager.stage_ahead(ids[ids >= self._hot_count])
 
     def store_stats(self) -> Optional[dict]:
@@ -231,9 +355,13 @@ class Feature:
         return stats
 
     def close(self) -> None:
-        """Release the staging threads of a store-backed feature."""
+        """Release the staging threads of a store-backed feature and the
+        cold fetch's."""
         if self._stager is not None:
             self._stager.close()
+        if self._pool is not None:
+            self._pool.shutdown()
+            self._pool = None
 
     @scoped("glt.gather.feat")
     def _gather_hot_impl(self, hot, id2index, ids):
@@ -323,15 +451,26 @@ class Feature:
         return _stats(self._cache)
 
     # -- gather ------------------------------------------------------------
-    def gather(self, ids: jnp.ndarray) -> jnp.ndarray:
+    def gather(self, ids: jnp.ndarray,
+               plan: Optional[ColdPlan] = None) -> jnp.ndarray:
         """Gather rows for global ``ids`` (-1 padded).
 
         Fully device-resident stores (``split_ratio == 1.0``) are jit-safe.
         Tiered stores run the hot gather on device and the cold gather on
-        host — touching each tier only at its own batch positions — and
-        merge with a padded device scatter; callable only eagerly (the
-        loader stages it before the jitted train step).  Padding rows are
-        zeros.
+        host — touching each tier only at its own batch positions —
+        callable only eagerly (the loader stages it before the jitted
+        train step).  Every row is the stored row bit for bit whatever
+        tier holds it, padding rows are zeros, no row is dropped.
+
+        The tiered path, stage by stage (spans ``glt.feature.*``, device
+        scopes ``glt.gather.*``): the *plan* (:meth:`plan_gather`, taken
+        from ``plan`` when the caller dispatched it earlier) resolves the
+        ids on the device; the host waits for its two small outputs
+        (``ids_wait``), copies the cold rows out of host memory into one
+        buffer (``cold_fetch``), sends it (``cold_put``), and one program
+        gathers the hot rows (``glt.gather.feat``) and places the cold
+        ones (``glt.gather.merge``).  :meth:`set_cold_width` makes the
+        buffer's width static.
         """
         if self._cold_count == 0:
             if isinstance(ids, jax.core.Tracer):
@@ -352,72 +491,217 @@ class Feature:
                 "stage and cannot run under jit; gather before the jitted "
                 "step or use split_ratio=1.0")
         require_int32_ids(ids)
-        ids_np = np.asarray(ids).astype(np.int64)
+        if self._cache is not None:
+            return self._gather_tiered_cached(np.asarray(ids))
+        if plan is None:
+            plan = self.plan_gather(ids)
+        b = plan.hot_idx.shape[0]
+        with _span("feature.ids_wait"):
+            # The one place a tiered gather waits for the device: the
+            # plan is an output of a program behind the sampler's.
+            n_hot, n_cold = map(int, np.asarray(plan.counts))
+            local = np.asarray(plan.cold_local)
+        width = local.shape[0]
+        cold_pos = plan.cold_pos
+        if self._cold_width is None:
+            # No static width: a power-of-two bucket of the count, one
+            # merge program a bucket (at most log2(B) of them).
+            width = min(_pow2_pad(n_cold), b)
+            cold_pos = cold_pos[:width]
+        self.bytes_from_hbm += n_hot * self._dim \
+            * jnp.dtype(self._hot.dtype).itemsize
+        _M_HOT_ROWS.inc(n_hot)
+        _M_COLD_ROWS.inc(n_cold)
+        out, done = None, 0
+        while True:
+            take = min(n_cold - done, width)
+            rows = self._stage_cold(local[:take], width)
+            if out is None:
+                out = self._merge_tiered(plan.hot_idx, cold_pos, rows)
+            else:
+                out = self._patch_tiered(out, cold_pos, rows)
+            done += take
+            if done >= n_cold:
+                return out
+            # A batch past the static width: the rows behind the first
+            # ``width`` in further rounds of the same width, through
+            # programs that are compiled already.
+            more = self.plan_gather(ids, offset=done)
+            cold_pos, local = more.cold_pos, np.asarray(more.cold_local)
+
+    def set_cold_width(self, width: Optional[int]) -> None:
+        """Fix the number of cold row slots a tiered gather sends a round
+        (None: back to power-of-two buckets of each batch's count).
+
+        With a width, every batch of one length runs the same two
+        programs whatever its cold count, so nothing compiles once each
+        has run (:meth:`warm_gather`), and the transfer carries
+        ``width - n_cold`` rows of padding where a bucket carries up to
+        ``n_cold``.  A batch with more cold rows than ``width`` is served
+        in further rounds, exactly.  :func:`calibrate_cold_width` finds
+        one."""
+        if width is not None and int(width) < 1:
+            raise ValueError(f"cold width {width} must be positive")
+        self._cold_width = None if width is None else int(width)
+        self._stage_buf = None
+        if width is not None:
+            self._stage_buf = np.empty((self._cold_width, self._dim),
+                                       self._cold_np_dtype)
+            self._stage_buf.fill(0)      # touch every page now
+
+    @property
+    def cold_width(self) -> Optional[int]:
+        return self._cold_width
+
+    @property
+    def plans_gathers(self) -> bool:
+        """Whether :meth:`gather` takes a plan dispatched ahead of it (a
+        tiered store without the cold cache, whose lookups the host
+        resolves)."""
+        return self._cold_count > 0 and self._cache is None
+
+    def plan_gather(self, ids, offset: int = 0) -> ColdPlan:
+        """Dispatch the device's half of a tiered gather's bookkeeping
+        for ``ids`` and start its fetch: which row of which tier every id
+        resolves to, the batch positions of the cold ones and their rows
+        in the cold tier (those of rank ``offset`` on, as many as the
+        cold width), and the two counts.  Nothing waits: a loader calls
+        this when it dispatches the sample, and hands the plan to
+        :meth:`gather` when the batch is collated."""
+        ids = jnp.asarray(ids, jnp.int32)
+        width = min(self._cold_width or ids.shape[0], ids.shape[0])
+        fn = self._plan_jits.get(width)
+        if fn is None:
+            def _plan(id2index, ids, offset):
+                return self._plan_impl(id2index, ids, offset, width=width)
+
+            fn = self._plan_jits[width] = jax.jit(_plan)
+        plan = ColdPlan(*fn(self._id2index, ids, jnp.int32(offset)))
+        plan.counts.copy_to_host_async()
+        plan.cold_local.copy_to_host_async()
+        return plan
+
+    def _plan_impl(self, id2index, ids, offset, *, width: int):
+        b = ids.shape[0]
+        valid = ids >= 0
+        with jax.named_scope("glt.gather.feat"):
+            idx = jnp.where(valid, ids, 0)
+            if id2index is not None:
+                idx = jnp.take(id2index, idx, axis=0, mode="clip")
+        with jax.named_scope("glt.gather.merge"):
+            hot = valid & (idx < self._hot_count)
+            cold = valid & ~hot
+            keep = cold & (jnp.cumsum(cold.astype(jnp.int32)) > offset)
+            # The kept positions first, in order: a sort, where a
+            # scatter by rank costs five times as much on a TPU.
+            at = jnp.arange(b, dtype=jnp.int32)
+            _, pos, loc = jax.lax.sort(
+                ((~keep).astype(jnp.int32), at, idx - self._hot_count),
+                num_keys=2)
+            slot = at[:width]
+            # Padding slots point past the batch, each at its own row,
+            # so the placement's indices stay sorted and unique.
+            cold_pos = jnp.where(slot < jnp.sum(keep, dtype=jnp.int32),
+                                 pos[:width], b + slot)
+            cold_local = loc[:width]
+            counts = jnp.stack([jnp.sum(hot, dtype=jnp.int32),
+                                jnp.sum(cold, dtype=jnp.int32)])
+        return jnp.where(hot, idx, -1), cold_pos, cold_local, counts
+
+    def _stage_cold(self, local: np.ndarray, width: int):
+        """``local``'s cold rows as a ``[width, d]`` device array: a host
+        buffer written once (the slots behind the rows are never read:
+        their position is out of range) and sent.  At the static width
+        the buffer is the feature's own, allocated and touched once (a
+        fresh 50 MB ``np.empty`` costs 60 ms of page faults a batch on
+        the chip's host; scripts/tier_micro.py), so the transfer is
+        waited for before the next round may write it."""
+        mine = self._stage_buf is not None \
+            and self._stage_buf.shape[0] == width
+        with self._stage_lock if mine else contextlib.nullcontext():
+            with _span("feature.cold_fetch"):
+                buf = self._stage_buf if mine else np.empty(
+                    (width, self._dim), self._cold_np_dtype)
+                self._fetch_cold_into(local, buf[: local.shape[0]])
+            with _span("feature.cold_put"):
+                _M_COLD_SENT.inc(width)
+                rows = jax.device_put(buf)
+                if mine:
+                    rows.block_until_ready()
+                return rows
+
+    def _merge_impl(self, hot, hot_idx, cold_pos, cold_rows):
+        """Device merge: hot gather at hot slots + cold-row scatter."""
+        from ..store import quant
+
+        spec, dtype = self._quant, self.dtype
+        with jax.named_scope("glt.gather.feat"):
+            if hot.shape[0]:
+                rows = jnp.take(hot, hot_idx, axis=0, mode="clip")
+                if spec is not None:
+                    rows = quant.dequantize(rows, spec)
+                out = jnp.where((hot_idx >= 0)[:, None],
+                                rows.astype(dtype), 0)
+            else:
+                # Fully host-resident (split_ratio == 0, e.g. a
+                # shared-memory attach in a sampling worker).
+                out = jnp.zeros((hot_idx.shape[0], cold_rows.shape[1]),
+                                dtype)
+        return self._patch_impl(out, cold_pos, cold_rows)
+
+    @scoped("glt.gather.merge")
+    def _patch_impl(self, out, cold_pos, cold_rows):
+        """The rows the host sent, placed among ``out``'s (compressed
+        rows cross the host link at storage width and widen here)."""
+        if self._quant is not None:
+            from ..store import quant
+
+            cold_rows = quant.dequantize(cold_rows, self._quant)
+        return out.at[cold_pos].set(
+            cold_rows.astype(out.dtype), mode="drop",
+            indices_are_sorted=True, unique_indices=True)
+
+    def _merge_tiered(self, hot_idx, cold_pos, cold_rows):
+        if self._merge_jit is None:
+            self._merge_jit = jax.jit(self._merge_impl)
+        return self._merge_jit(self._hot, hot_idx, cold_pos, cold_rows)
+
+    def _patch_tiered(self, out, cold_pos, cold_rows):
+        """A further round's cold rows placed into ``out`` (donated)."""
+        if self._patch_jit is None:
+            self._patch_jit = jax.jit(self._patch_impl, donate_argnums=0)
+        return self._patch_jit(out, cold_pos, cold_rows)
+
+    def warm_gather(self, num_ids: int) -> None:
+        """Compile (or read from the cache) and run every program a
+        tiered gather of ``num_ids`` ids can need at the static cold
+        width, the further round's among them, so that a measured window
+        or a served request never meets a compilation."""
+        if self._cold_count == 0 or self._cold_width is None:
+            return
+        ids = jnp.full((int(num_ids),), -1, jnp.int32)
+        plan = self.plan_gather(ids, offset=1)
+        rows = self._stage_cold(np.zeros((0,), np.int32),
+                                plan.cold_pos.shape[0])
+        out = self._merge_tiered(plan.hot_idx, plan.cold_pos, rows)
+        jax.block_until_ready(self._patch_tiered(out, plan.cold_pos, rows))
+
+    def _gather_tiered_cached(self, ids_np):
+        """Tiered gather with the HBM cold cache in front of the host.
+
+        The ids are resolved on the host here; one device->host sync
+        (the hit mask); the host stages only cache misses, and the merge
+        program inserts them into the cache for the next batch (the
+        previous cache buffers are donated in place).
+        """
+        ids_np = ids_np.astype(np.int64)
         valid = ids_np >= 0
         idx = np.where(valid, ids_np, 0)
-        if self._id2index_np is not None:
-            idx = self._id2index_np[idx].astype(np.int64)
+        if self._id2index is not None:
+            idx = self._host_id2index()[idx].astype(np.int64)
         is_hot = idx < self._hot_count
         hot_mask = valid & is_hot
         cold_mask = valid & ~is_hot
-        if self._cache is not None:
-            return self._gather_tiered_cached(idx, hot_mask, cold_mask)
-        cold_pos = np.nonzero(cold_mask)[0]
-        # Host moves ONLY the cold rows (was: full-batch np.take of both
-        # tiers + masked merge).  Hot bytes count at the WIRE width — a
-        # compressed hot tier serves compressed bytes.
-        self.bytes_from_hbm += int(hot_mask.sum()) * self._dim \
-            * jnp.dtype(self._hot.dtype).itemsize
-        cold_np = self._fetch_cold(idx[cold_pos] - self._hot_count)
-        cap = _pow2_pad(cold_pos.shape[0])
-        b = ids_np.shape[0]
-        pos_pad = np.full((cap,), b, np.int32)      # b = out-of-range: drop
-        pos_pad[: cold_pos.shape[0]] = cold_pos
-        rows_pad = np.zeros((cap, self._dim), self._cold_np_dtype)
-        rows_pad[: cold_pos.shape[0]] = cold_np
-        # Compressed rows cross the host->device wire at storage width
-        # and widen inside the jitted merge; raw rows cast to the target
-        # dtype host-side as before.
-        rows_dev = (jnp.asarray(rows_pad) if self._quant is not None
-                    else jnp.asarray(rows_pad, self.dtype))
-        return self._merge_tiered(
-            jnp.asarray(np.where(hot_mask, idx, 0), jnp.int32),
-            jnp.asarray(hot_mask), jnp.asarray(pos_pad), rows_dev)
-
-    def _merge_tiered(self, idx, hot_mask, cold_pos, cold_rows):
-        """Device merge: hot gather at hot slots + cold-row scatter."""
-        if self._merge_jit is None:
-            from ..store import quant
-
-            spec = self._quant
-
-            @jax.jit
-            def merge(hot, idx, hot_mask, cold_pos, cold_rows):
-                if spec is not None:
-                    cold_rows = quant.dequantize(cold_rows, spec)
-                if hot.shape[0]:
-                    rows = jnp.take(hot, idx, axis=0, mode="clip")
-                    if spec is not None:
-                        rows = quant.dequantize(rows, spec)
-                    out = jnp.where(hot_mask[:, None], rows, 0)
-                else:
-                    # Fully host-resident (split_ratio == 0, e.g. a
-                    # shared-memory attach in a sampling worker).
-                    out = jnp.zeros((idx.shape[0], cold_rows.shape[1]),
-                                    cold_rows.dtype)
-                return out.at[cold_pos].set(cold_rows, mode="drop")
-
-            self._merge_jit = merge
-        return self._merge_jit(self._hot, idx, hot_mask, cold_pos,
-                               cold_rows)
-
-    def _gather_tiered_cached(self, idx, hot_mask, cold_mask):
-        """Tiered gather with the HBM cold cache in front of the host.
-
-        One device->host sync (the hit mask); the host stages only cache
-        misses, and the merge program inserts them into the cache for the
-        next batch (the previous cache buffers are donated in place).
-        """
         b = idx.shape[0]
         cold_ids = np.where(cold_mask, idx - self._hot_count, -1).astype(
             np.int32)
@@ -496,8 +780,17 @@ class Feature:
         valid = ids >= 0
         idx = np.where(valid, ids, 0)
         if self._id2index is not None:
-            idx = np.asarray(self._id2index)[idx]
-        if self._host_full is None:
+            idx = self._host_id2index()[idx]
+        if self._host_full is not None:
+            rows = self._host_full[idx]
+        elif self._store is None:
+            # Built from tiers: each row out of the tier that holds it.
+            idx = np.asarray(idx, np.int64)
+            hot = idx < self._hot_count
+            rows = np.empty((idx.shape[0], self._dim), self._cold_np_dtype)
+            rows[hot] = np.asarray(self._hot[idx[hot]])
+            rows[~hot] = self._cold[idx[~hot] - self._hot_count]
+        else:
             rows = self._store.read_rows(np.asarray(idx, np.int64))
             if self._quant is not None:
                 from ..store import quant
@@ -505,8 +798,6 @@ class Feature:
                 # Host decode mirrors the device formula; padding rows
                 # re-zero below (decode(0) != 0 for int8).
                 rows = quant.decode(rows, self._quant)
-        else:
-            rows = self._host_full[idx]
         rows = np.where(valid[:, None], rows, 0)
         return rows
 
@@ -516,3 +807,25 @@ class Feature:
     def __repr__(self) -> str:
         return (f"Feature(shape={self.shape}, split_ratio={self.split_ratio},"
                 f" hot={self._hot_count})")
+
+
+def calibrate_cold_width(feature: Feature, node_batches, pct: float = 99.0,
+                         margin: float = 1.05, multiple: int = 1024,
+                         counts: Optional[np.ndarray] = None) -> int:
+    """A static cold width for :meth:`Feature.set_cold_width`, sized as
+    :func:`~glt_tpu.sampler.calibrate_node_capacity` sizes a node buffer:
+    the ``pct`` percentile of the cold rows per batch over
+    ``node_batches`` (node lists as the sampler returns them, ``-1``
+    padded; or their ``counts``, :func:`cold_rows_of`), times ``margin``,
+    rounded up to ``multiple`` rows.  A batch past it costs a further
+    round, never a row."""
+    if counts is None:
+        counts = cold_rows_of(feature, node_batches)
+    width = float(np.percentile(np.asarray(counts), pct)) * margin
+    return max(int(np.ceil(width / multiple) * multiple), multiple)
+
+
+def cold_rows_of(feature: Feature, node_batches) -> np.ndarray:
+    """Cold rows per node list (one host fetch for all of them)."""
+    counts = [feature.plan_gather(ids).counts[1] for ids in node_batches]
+    return np.asarray(jax.device_get(jnp.stack(counts)))

@@ -190,6 +190,11 @@ class NodeLoader:
         batches = self._epoch_seed_batches()
         feat = self.data.get_node_feature() if self.data is not None else None
         stage = getattr(feat, "stage_ahead", None)
+        # A tiered feature resolves a batch's ids on the device
+        # (Feature.plan_gather): dispatched here behind the sample, the
+        # plan has reached the host by the time the batch is collated.
+        plan_of = (feat.plan_gather
+                   if getattr(feat, "plans_gathers", False) else None)
         try:
             while True:
                 while len(pending) < self.prefetch:
@@ -213,13 +218,16 @@ class NodeLoader:
                     # overlapped the prefetch window instead of paying a
                     # blocking round trip per batch.
                     self._prime_overflow_flag(out)
-                    pending.append((out, seeds.shape[0]))
+                    pending.append((out, seeds.shape[0], None if plan_of
+                                    is None else plan_of(out.node)))
                 if not pending:
                     return
-                out, nseeds = pending.popleft()
-                out = self._maybe_refetch_overflow(out)
+                sampled, nseeds, plan = pending.popleft()
+                out = self._maybe_refetch_overflow(sampled)
+                if out is not sampled:
+                    plan = None         # replayed: another node list
                 with _span("loader.collate"), _M_COLLATE_MS.time():
-                    batch = self._collate_fn(out, nseeds)
+                    batch = self._collate_fn(out, nseeds, plan)
                 _M_BATCHES.inc()
                 yield batch
         finally:
@@ -274,11 +282,12 @@ class NodeLoader:
                 NodeSamplerInput(out.batch))
 
     # -- collate (cf. node_loader.py:85 ``_collate_fn``) -------------------
-    def _collate_fn(self, out, num_seeds: int) -> Batch:
+    def _collate_fn(self, out, num_seeds: int, plan=None) -> Batch:
         x = None
         feat = self.data.get_node_feature()
         if feat is not None:
-            x = feat.gather(out.node)
+            x = (feat.gather(out.node) if plan is None
+                 else feat.gather(out.node, plan=plan))
         y = None
         labels = self.data.get_node_label()
         if labels is not None:

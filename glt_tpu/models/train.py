@@ -14,6 +14,7 @@ node list by the sampler's first-occurrence contract.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Callable
 
@@ -63,36 +64,85 @@ def _batch_inputs(batch):
         batch.node_mask, unique_first_occurrence(batch.batch).count[None])
 
 
-def make_train_step(model, tx, batch_size: int,
-                    dropout_seed: int = 0) -> Callable:
+def _layout_of(hops, batch):
+    """The layout among ``hops`` (one, several or none) that lays out
+    ``batch``: the one with its node rows and edge slots.  A loader hands
+    out batches of two shapes, its sampler's and the full-capacity
+    sibling's that replays an overflowing batch, each under its own."""
+    if hops is None:
+        return None
+    shape = (batch.node.shape[0], batch.edge_index.shape[1])
+    layouts = [hops] if hasattr(hops, "node_bounds") else list(hops)
+    for layout in layouts:
+        if (layout.node_bounds[-1], layout.edge_bounds[-1]) == shape:
+            return layout
+    raise ValueError(
+        f"no layout for a batch of {shape[0]} node rows and {shape[1]} "
+        f"edge slots among {layouts}")
+
+
+def _at_width(batch, batch_size: int):
+    """``batch`` with its seed count set to the step's static width.  The
+    count is static data of the ``Batch`` pytree, and a loader's trailing
+    batch of an epoch has fewer seeds: left as it is, it would compile
+    the step a second time.  The step reads the seed ids, never the
+    count."""
+    if getattr(batch, "batch_size", batch_size) == batch_size:
+        return batch
+    return dataclasses.replace(batch, batch_size=batch_size)
+
+
+def make_train_step(model, tx, batch_size: int, dropout_seed: int = 0,
+                    hops=None) -> Callable:
     """Build a jitted ``(state, batch) -> (state, loss, acc)`` step over a
-    loader's ``Batch`` (no sampler layout, so the model runs whole)."""
-    grads_of = loss_and_grads(model, seed_loss(batch_size))
+    loader's ``Batch``.
+
+    ``hops`` is the static layout of the loader's batches,
+    ``sampler.hop_bounds`` (or several: the sampler's and its
+    ``full_capacity_sibling()``'s, :func:`_layout_of` picks by the
+    batch's shape when the step is traced): a model that trims runs
+    trimmed to it as the scanned steps do
+    (:func:`~glt_tpu.models.step.hop_trimming`), exact against the whole
+    model.  Without a layout the model runs whole."""
+    loss = seed_loss(batch_size)
     update = gated_update(tx)
 
     @jax.jit
-    def train_step(state: TrainState, batch):
+    def step(state: TrainState, batch):
         rng = jax.random.fold_in(jax.random.PRNGKey(dropout_seed), state.step)
         edge_index, edge_mask, aux = _batch_inputs(batch)
-        loss, acc, grads = grads_of(state.params, batch.x, edge_index,
-                                    edge_mask, batch.y, aux, rng)
-        return update(state, grads, jnp.any(batch.batch >= 0)), loss, acc
+        grads_of = loss_and_grads(model, loss, _layout_of(hops, batch))
+        loss_v, acc, grads = grads_of(state.params, batch.x, edge_index,
+                                      edge_mask, batch.y, aux, rng)
+        return update(state, grads, jnp.any(batch.batch >= 0)), loss_v, acc
 
+    def train_step(state: TrainState, batch):
+        return step(state, _at_width(batch, batch_size))
+
+    train_step.lower = lambda state, batch: step.lower(
+        state, _at_width(batch, batch_size))
     return train_step
 
 
-def make_eval_step(model, batch_size: int) -> Callable:
+def make_eval_step(model, batch_size: int, hops=None) -> Callable:
     """``(params, batch) -> (loss, acc)``: the same loss on an
-    evaluation-mode forward."""
+    evaluation-mode forward, trimmed to ``hops`` as
+    :func:`make_train_step`'s."""
     loss = seed_loss(batch_size)
 
     @jax.jit
-    def eval_step(params, batch):
+    def step(params, batch):
         edge_index, edge_mask, aux = _batch_inputs(batch)
         logits = model.apply(params, batch.x, edge_index, edge_mask,
-                             train=False)
+                             train=False,
+                             **hop_trimming(model, _layout_of(hops, batch)))
         return loss(logits, batch.y, aux)
 
+    def eval_step(params, batch):
+        return step(params, _at_width(batch, batch_size))
+
+    eval_step.lower = lambda params, batch: step.lower(
+        params, _at_width(batch, batch_size))
     return eval_step
 
 

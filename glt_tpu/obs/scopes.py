@@ -24,6 +24,8 @@ call, and no flag turns them off.  The whole taxonomy:
                        negatives located among the seed rows)
 ``glt.gather.feat``    feature rows out of the table
 ``glt.gather.label``   label rows
+``glt.gather.merge``   a tiered gather's bookkeeping and the placement of
+                       the rows the host sent among the hot ones
 ``glt.route.bucket``   owner bucketing of ids (``build_routing``)
 ``glt.route.payload``  assembling exchange payloads, un-permuting replies
 ``glt.route.exchange`` the ``all_to_all``/``ppermute`` calls themselves
