@@ -90,9 +90,13 @@ def unique_first_occurrence(ids: jnp.ndarray) -> UniqueResult:
 
 
 class DenseInduceState(NamedTuple):
-    """Carry of the dense (scatter-based) incremental inducer.
+    """Carry of the incremental inducer, one chain of hops (seeds first).
 
-    ``seen`` is a ``[num_nodes + 2]`` int32 map: 0 = unseen, else the
+    ``seen`` is ``None`` in a sorted chain (:func:`induce_init`): every
+    hop works on ``node_buf`` alone and the program holds no
+    ``[num_nodes + 2]`` array.  In a map chain it is the dense
+    (scatter-based) inducer's ``[num_nodes + 2]`` int32 map: 0 = unseen,
+    else the
     committed encoding ``_LOCAL_BASE - local_id`` (decode with
     ``_LOCAL_BASE - seen[id]``; between the two scatters of a
     :func:`dense_induce` call it may transiently hold provisional
@@ -114,8 +118,9 @@ def dense_map_fits(num_nodes: int, budget_bytes: int = 1 << 30) -> bool:
 
 @scoped("glt.sample.induce")
 def dense_induce_init(num_nodes: int, capacity: int) -> DenseInduceState:
-    """Fresh per-batch state (the analog of ``Inducer::Reset``,
-    csrc/cpu/inducer.cc; allocating zeros is a ~4B/node memset)."""
+    """Fresh per-batch state of a map chain (the analog of
+    ``Inducer::Reset``, csrc/cpu/inducer.cc; allocating zeros is a
+    ~4B/node memset).  Samplers come through :func:`induce_init`."""
     return DenseInduceState(
         seen=jnp.zeros((num_nodes + 2,), jnp.int32),
         node_buf=jnp.full((capacity + 1,), -1, jnp.int32),
@@ -144,20 +149,24 @@ def dense_induce(state: DenseInduceState, cand: jnp.ndarray
 
     This is the hash-table inducer's contract
     (``CUDAInducer::InduceNext``, csrc/cuda/inducer.cu:95) implemented
-    with dense scatters into an O(N) id->local map.  A random element op
-    is what it pays for: on a TPU v5e a gather or scatter of 768,000
-    int32 elements takes 3.7-8.0 ms (4.9-10.4 ns an element; the
-    scatter-max into the map grows with the map, 4.9 ms at 2.45 M nodes
-    and 8.0 ms at 27.8 M) where a two-operand ``lax.sort`` of 937,984
-    takes 1.0 ms (my chip run, PR 29, scripts/induce_micro.py).  So the
-    hop costs exactly FOUR such passes per candidate — scatter-max of an
-    encoded marker, read-back, commit scatter, resolve read — via a
-    single map whose value encoding makes existing assignments beat
-    in-batch provisional markers under max.  The last hop, the widest,
-    does not come here (:func:`induce_final`).  New nodes receive
-    consecutive local ids in first-occurrence order, so per-hop frontier
-    slices of ``node_buf`` are exactly the newly discovered nodes, and
-    seeds placed first keep ``node_buf[:batch] == seeds``.
+    with dense scatters into an O(N) id->local map: the form a chain
+    keeps only where a capacity lies under its bound on known nodes
+    (:func:`chain_is_sorted`), because there the map alone remembers a
+    node past the buffer's end; every other chain runs every hop as sorts
+    and scans (:func:`induce`), and this is the reference its tests hold
+    that form to.  A random element op is what it pays for: on a TPU v5e
+    a gather or scatter of 768,000 int32 elements takes 3.7-8.0 ms
+    (4.9-10.4 ns an element; the scatter-max into the map grows with the
+    map, 4.9 ms at 2.45 M nodes and 8.0 ms at 27.8 M) where a
+    two-operand ``lax.sort`` of 937,984 takes 1.0 ms (my chip run, PR
+    29, scripts/induce_micro.py).  So the hop costs exactly FOUR such
+    passes per candidate — scatter-max of an encoded marker, read-back,
+    commit scatter, resolve read — via a single map whose value encoding
+    makes existing assignments beat in-batch provisional markers under
+    max.  New nodes receive consecutive local ids in first-occurrence
+    order, so per-hop frontier slices of ``node_buf`` are exactly the
+    newly discovered nodes, and seeds placed first keep
+    ``node_buf[:batch] == seeds``.
     """
     seen, node_buf, count = state
     n2 = seen.shape[0]
@@ -200,9 +209,9 @@ def dense_induce(state: DenseInduceState, cand: jnp.ndarray
 def dense_induce_final(state: DenseInduceState, cand: jnp.ndarray
                        ) -> tuple:
     """Last-hop :func:`dense_induce` on the id map: same contract, one
-    fewer map op.  The form :func:`induce_final` keeps for a buffer that
-    may already have overflowed, and the reference its tests hold the
-    sorted form to.
+    fewer map op: the last hop of a chain that keeps the map
+    (:func:`induce`), and the reference the sorted form's tests hold its
+    last hop to.
 
     After the final hop no later hop reads the ``seen`` map, so the
     commit scatter (op 3 of :func:`dense_induce`) is dead work; losers of
@@ -286,56 +295,91 @@ def _run_fill(head: jnp.ndarray, value: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(head, value, carry[:, None]).reshape(-1)[:n]
 
 
-def sorted_final_slots(known: int, capacity: int, m: int) -> int:
-    """Keys the sorted last-hop inducer handles for a call of static
-    shape, ``known + m``, or 0 where :func:`induce_final` takes the map
-    form (the ``glt.sample.induce_sorted_slots`` gauge)."""
-    return known + m if known <= capacity else 0
+def chain_is_sorted(known_last: int, capacity: int) -> bool:
+    """Whether a chain of inducer calls (one sampler program; one node
+    type of the typed sampler) runs as sorts and scans at every hop.
 
-
-def record_sorted_slots(hop: int, slots: int) -> None:
-    """Engagement of the sorted last-hop inducer, a trace-time fact the
-    samplers record when their program is built: the static
-    :func:`sorted_final_slots` of hop ``hop`` (summed over node types in
-    the typed sampler), 0 where the map form was chosen."""
-    _metrics.gauge("glt.sample.induce_sorted_slots", "keys the sorted "
-                   "last-hop inducer handles in the last sampler built",
-                   {"hop": str(hop)}).set(slots)
-
-
-def induce_final(state: DenseInduceState, cand: jnp.ndarray, known: int
-                 ) -> tuple:
-    """Last-hop :func:`dense_induce`: same ``local``, ``node_buf`` and
-    ``count``; the form is chosen at trace time from static shapes.
-
-    ``known`` is the caller's static bound on ``state.count``: seeds plus
-    every candidate of the earlier hops.  After the final hop nothing
-    reads the id map again, so where every known node is sure to sit in
-    ``node_buf`` (``known <= capacity``) the hop runs as sorts and scans
-    over ``node_buf[:known] ++ cand`` and never touches ``seen``
-    (:func:`_sorted_induce_final`: 4.2-4.6 ms where the map form takes
-    20.1-22.5 at the GraphSAGE cells' 169,984 + 768,000 keys, 0.57-0.58
-    against 0.90-1.42 at the typed cell's 39,840-67,712, and the two
-    agree bit for bit at every one of those widths; my chip run, PR 29,
-    scripts/induce_micro.py — no width was found at which the map
-    wins).  Where the buffer may already have overflowed, the map alone
-    remembers the nodes past its end, and :func:`dense_induce_final`
-    keeps the numbering exact.  Either way the returned ``state.seen``
-    MUST NOT be fed to another induce call.
-    """
-    capacity = state.node_buf.shape[0] - 1
-    if sorted_final_slots(known, capacity, cand.shape[0]):
-        return _sorted_induce_final(state, cand, known)
-    return dense_induce_final(state, cand)
+    ``known_last`` is the static bound on the nodes known before the
+    chain's last hop: seeds plus every candidate of the earlier hops.
+    The bound grows with the hops, so where it fits the node buffer every
+    known node of every hop is sure to sit in ``node_buf`` and no hop
+    needs the id map.  Where it does not, a node past the buffer's end is
+    remembered by the map alone, and the whole chain keeps the map."""
+    return known_last <= capacity
 
 
 @scoped("glt.sample.induce")
-def _sorted_induce_final(state: DenseInduceState, cand: jnp.ndarray,
-                         known: int) -> tuple:
-    """:func:`dense_induce_final` without a random pass: four sorts, a
-    segmented scan and one contiguous store (see :func:`induce_final`)."""
+def induce_init(num_nodes: int, capacity: int, known_last: int
+                ) -> DenseInduceState:
+    """Fresh per-batch state of a chain: no id map (``seen=None``, nothing
+    of ``O(num_nodes)`` in the program) where :func:`chain_is_sorted`,
+    else :func:`dense_induce_init`'s."""
+    if not chain_is_sorted(known_last, capacity):
+        return dense_induce_init(num_nodes, capacity)
+    return DenseInduceState(
+        seen=None,
+        node_buf=jnp.full((capacity + 1,), -1, jnp.int32),
+        count=jnp.zeros((), jnp.int32),
+    )
+
+
+def sorted_slots(state: DenseInduceState, known: int, m: int) -> int:
+    """Keys the sorted inducer handles for a call of static shape,
+    ``known + m``, or 0 where the chain of ``state`` keeps the id map (the
+    ``glt.sample.induce_sorted_slots`` gauge)."""
+    return known + m if state.seen is None else 0
+
+
+def record_sorted_slots(hop: int, slots: int) -> None:
+    """Engagement of the sorted inducer, a trace-time fact the samplers
+    record when their program is built: the static :func:`sorted_slots`
+    of hop ``hop`` (0 the seeds' own dedup; summed over node types in the
+    typed sampler), 0 where the chain kept the id map."""
+    _metrics.gauge("glt.sample.induce_sorted_slots", "keys the sorted "
+                   "inducer handles in the last sampler built",
+                   {"hop": str(hop)}).set(slots)
+
+
+def induce(state: DenseInduceState, cand: jnp.ndarray, known: int,
+           last: bool) -> tuple:
+    """One hop of a chain, :func:`dense_induce`'s contract bit for bit:
+    ``local``, ``node_buf``, ``count``, ids numbered past the capacity
+    and dropped from the store, ``-1`` padding.  The form is the
+    chain's, chosen once at trace time from static shapes
+    (:func:`induce_init`), never per hop.
+
+    ``known`` is the caller's static bound on ``state.count``: 0 for the
+    seeds' own dedup, else seeds plus every candidate of the earlier
+    hops.  A sorted chain runs the hop as sorts and scans over
+    ``node_buf[:known] ++ cand`` (:func:`_sorted_induce`) and has no id
+    map to touch.  On the chip (scripts/induce_micro.py): the last hop
+    takes 4.2-4.6 ms where the map form takes 20.1-22.5 at the GraphSAGE
+    cells' 169,984 + 768,000 keys, 0.57-0.58 against 0.90-1.42 at the
+    typed cell's 39,840-67,712 (my chip run, PR 29); the hops before it
+    are in PERF.md section 6 (PR 33).  The two forms agree bit for bit at
+    every one of those widths.  A map chain runs :func:`dense_induce`,
+    and :func:`dense_induce_final` at its ``last`` hop, after which the
+    returned ``state.seen`` MUST NOT be fed to another call.
+    """
+    if state.seen is None:
+        return _sorted_induce(state, cand, known)
+    return (dense_induce_final if last else dense_induce)(state, cand)
+
+
+@scoped("glt.sample.induce")
+def _sorted_induce(state: DenseInduceState, cand: jnp.ndarray,
+                   known: int) -> tuple:
+    """:func:`dense_induce` without the id map or a random pass: four
+    sorts, a segmented scan and one contiguous store (see
+    :func:`induce`).  New nodes land at ``count`` in first-occurrence
+    order, so the next frontier is still the slice of ``node_buf`` there.
+    """
     seen, node_buf, count = state
     cap = node_buf.shape[0] - 1
+    if known > cap:
+        raise ValueError(f"the sorted inducer reads its known nodes from "
+                         f"the node buffer: bound {known} past capacity "
+                         f"{cap}")
     m = cand.shape[0]
     n = known + m
     cand = cand.astype(jnp.int32)

@@ -36,13 +36,12 @@ from ..obs.scopes import scoped
 from ..obs.trace import span as _span
 from ..ops.neighbor_sample import _row_offsets_and_degrees, sample_neighbors
 from ..ops.unique import (
-    dense_induce,
-    dense_induce_init,
     dense_map_fits,
-    induce_final,
+    induce,
+    induce_init,
     record_sorted_slots,
     relabel_by_reference,
-    sorted_final_slots,
+    sorted_slots,
     unique_first_occurrence,
 )
 from ..sampler.base import NegativeSampling, SamplerOutput
@@ -1028,10 +1027,12 @@ def dist_sample_multi_hop(
     first-occurrence dedup, relabeled COO — with
     :func:`exchange_one_hop` (or its ring variant, ``collective='ring'``)
     as the one-hop primitive.  ``dedup`` selects the inducer like the
-    single-device sampler: 'dense' keeps a per-shard O(N_global) id map
-    (4B per global node per shard) for the hops before the last, which
-    runs as sorts and scans (``ops/unique.py::induce_final``), 'sort' the
-    growing argsort buffer; 'auto' prefers dense up to a ~1GB map.
+    single-device sampler: 'dense' runs every hop, the seeds' own dedup
+    included, as sorts and scans over the node buffer
+    (``ops/unique.py::induce``; the buffer here is the worst case, so it
+    covers the bound on known nodes and no shard holds an O(N_global) id
+    map), 'sort' the growing argsort buffer; 'auto' takes dense wherever
+    the single-device sampler's id map would stay under ~1GB.
 
     ``exchange_load_factor`` (α) opts into capacity-bounded exchanges:
     each hop's per-owner request buckets hold ``ceil(α * width /
@@ -1063,8 +1064,11 @@ def dist_sample_multi_hop(
     dense = dedup == "dense"
 
     if dense:
-        state = dense_induce_init(num_global, cap)
-        state, _ = dense_induce(state, seeds)
+        # Seeds plus every candidate of the earlier hops, before each hop.
+        knowns = hop_bounds(widths[0], fanouts, frontier_cap).node_bounds
+        state = induce_init(num_global, cap, knowns[-2])
+        record_sorted_slots(0, sorted_slots(state, 0, widths[0]))
+        state, _ = induce(state, seeds, 0, False)
         node_buf = state.node_buf
         count = state.count
         frontier = node_buf[: widths[0]]
@@ -1129,15 +1133,8 @@ def dist_sample_multi_hop(
                 node_buf = jnp.concatenate([node_buf, leaf_ids])
             new_count = count + jnp.sum(leaf_mask.astype(jnp.int32))
         elif dense:
-            if last:
-                # Seeds plus every candidate of the earlier hops.
-                known = hop_bounds(widths[0], fanouts,
-                                   frontier_cap).node_bounds[i]
-                state, nbr_local = induce_final(state, nbrs.ravel(), known)
-                record_sorted_slots(
-                    i + 1, sorted_final_slots(known, cap, w * f))
-            else:
-                state, nbr_local = dense_induce(state, nbrs.ravel())
+            record_sorted_slots(i + 1, sorted_slots(state, knowns[i], w * f))
+            state, nbr_local = induce(state, nbrs.ravel(), knowns[i], last)
             node_buf = state.node_buf
             new_count = state.count
             nbr_local = nbr_local.reshape(w, f)

@@ -22,12 +22,11 @@ from ..data.graph import Graph
 from ..ops.negative_sample import sample_negative_edges, weighted_draw
 from ..ops.neighbor_sample import sample_neighbors
 from ..ops.unique import (
-    dense_induce,
-    dense_induce_init,
     dense_map_fits,
-    induce_final,
+    induce,
+    induce_init,
     record_sorted_slots,
-    sorted_final_slots,
+    sorted_slots,
     unique_first_occurrence,
 )
 from ..typing import EdgeType, NodeType, PADDING_ID, reverse_edge_type
@@ -321,14 +320,39 @@ class HeteroNeighborSampler(BaseSampler):
         exchange here, keeping this multi-hop body single-source."""
         node_types = sorted(cap.keys())
 
-        # Per-type inducer choice: dense O(N_t) scatter map when the
-        # type's node count is known and the map is small enough
-        # (mirrors NeighborSampler's dedup='auto'); sort otherwise.
-        dense_state = {}
+        # Worst-case uniques per type before each hop (and, last, before
+        # the last hop: the interior): seeds + every RAW candidate of the
+        # hops before it, and no more than the type has.  With
+        # frontier_cap the capacity budgets *capped* widths while the
+        # inducer inserts raw candidates, so the interior can outgrow the
+        # leaf block — the fast path must stay off for such types (exact
+        # mode masks overflow into the buffer tail instead).
+        raw_known = {t: [widths[0].get(t, 0)] for t in node_types}
+        for h in range(self.num_hops - 1):
+            for t in node_types:
+                raw_known[t].append(raw_known[t][-1])
+            for et in self.edge_types:
+                fo = self.num_neighbors[et]
+                f = fo[h] if h < len(fo) else 0
+                if f > 0:
+                    raw_known[et[2]][-1] += widths[h][et[0]] * f
+        raw_interior = {t: raw_known[t][-1] for t in node_types}
+
+        # Per-type inducer choice: the dense chain when the type's node
+        # count is known and an id map of it is small enough (mirrors
+        # NeighborSampler's dedup='auto'); sort otherwise.  A dense chain
+        # holds the map only where the type's capacity lies under its
+        # bound on known nodes (ops/unique.py::chain_is_sorted).
+        dense_state, knowns = {}, {}
         for t in node_types:
             n_t = self._num_nodes_by_type.get(t)
             if n_t is not None and dense_map_fits(n_t):
-                dense_state[t] = dense_induce_init(n_t, max(cap[t], 1))
+                knowns[t] = [min(k, n_t) for k in raw_known[t]]
+                dense_state[t] = induce_init(n_t, max(cap[t], 1),
+                                             knowns[t][-1])
+        # Keys of the sorted inducers, hop by hop (0: the seeds), over
+        # the types.
+        sorted_keys = [0] * (self.num_hops + 1)
 
         node_buf = {
             t: (dense_state[t].node_buf[: max(cap[t], 1)]
@@ -342,7 +366,10 @@ class HeteroNeighborSampler(BaseSampler):
 
         for t0, seeds in seeds_dict.items():
             if t0 in dense_state:
-                dense_state[t0], _ = dense_induce(dense_state[t0], seeds)
+                sorted_keys[0] += sorted_slots(dense_state[t0], 0,
+                                               seeds.shape[0])
+                dense_state[t0], _ = induce(dense_state[t0], seeds, 0,
+                                            False)
                 buflen0 = node_buf[t0].shape[0]
                 node_buf[t0] = dense_state[t0].node_buf[:buflen0]
                 count[t0] = jnp.minimum(dense_state[t0].count, buflen0)
@@ -362,23 +389,8 @@ class HeteroNeighborSampler(BaseSampler):
         # t -> (leaf_off, full-leaf-region validity mask, interior count)
         # for types whose final hop used the no-dedup leaf block.
         fast_leaf = {}
-        # Worst-case interior uniques per type: seeds + every RAW
-        # candidate of hops before the last.  With frontier_cap the
-        # capacity budgets *capped* widths while the inducer inserts raw
-        # candidates, so the interior can outgrow the leaf block — the
-        # fast path must stay off for such types (exact mode masks
-        # overflow into the buffer tail instead).
-        raw_interior = {t: widths[0].get(t, 0) for t in node_types}
-        for h in range(self.num_hops - 1):
-            for et in self.edge_types:
-                fo = self.num_neighbors[et]
-                f = fo[h] if h < len(fo) else 0
-                if f > 0:
-                    raw_interior[et[2]] += widths[h][et[0]] * f
-
         keys = jax.random.split(key, self.num_hops * len(self.edge_types))
         overflow = jnp.zeros((), bool)
-        sorted_slots = 0    # of the last hop's inducers, over the types
 
         for hop in range(self.num_hops):
             # 1) sample every active edge type from its src frontier
@@ -441,17 +453,12 @@ class HeteroNeighborSampler(BaseSampler):
                         jnp.zeros((buflen - leaf_off - total_wf,), bool)])
                     fast_leaf[t] = (leaf_off, leaf_region, count[t])
                 elif t in dense_state:
-                    if hop + 1 == self.num_hops:
-                        # No more nodes are known than the type has.
-                        known = min(raw_interior[t],
-                                    self._num_nodes_by_type[t])
-                        dense_state[t], locs = induce_final(
-                            dense_state[t], cands, known)
-                        sorted_slots += sorted_final_slots(
-                            known, max(cap[t], 1), total_wf)
-                    else:
-                        dense_state[t], locs = dense_induce(
-                            dense_state[t], cands)
+                    known = knowns[t][hop]
+                    sorted_keys[hop + 1] += sorted_slots(
+                        dense_state[t], known, total_wf)
+                    dense_state[t], locs = induce(
+                        dense_state[t], cands, known,
+                        hop + 1 == self.num_hops)
                     uniques_src = dense_state[t].node_buf
                     merged_count = dense_state[t].count
                     inverse_tail = locs
@@ -506,7 +513,8 @@ class HeteroNeighborSampler(BaseSampler):
                 # the hop frontier is consumed; only newly discovered
                 # nodes expand next hop
                 frontier[t] = new_frontier.get(t)
-        record_sorted_slots(self.num_hops, sorted_slots)
+        for hop, slots in enumerate(sorted_keys):
+            record_sorted_slots(hop, slots)
 
         def cat_or_empty(lst, width_hint=1):
             if lst:

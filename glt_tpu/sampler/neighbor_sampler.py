@@ -40,13 +40,12 @@ from ..ops.neighbor_sample import sample_neighbors
 from ..ops.negative_sample import sample_negative_edges, weighted_draw
 from ..ops.subgraph import node_subgraph
 from ..ops.unique import (
-    dense_induce,
-    dense_induce_init,
     dense_map_fits,
-    induce_final,
+    induce,
+    induce_init,
     record_sorted_slots,
     relabel_by_reference,
-    sorted_final_slots,
+    sorted_slots,
     unique_first_occurrence,
 )
 from ..typing import PADDING_ID
@@ -240,11 +239,14 @@ class NeighborSampler(BaseSampler):
       seed: base PRNG seed; each ``sample_from_nodes`` call advances a
         counter so batches are independent yet reproducible (the analog of
         the curand Philox stream setup, random_sampler.cu:71-73).
-      dedup: what carries the hops before the last: 'dense' (an O(N)
-        id map, four random passes a candidate), 'sort' (argsort-based,
-        no O(N) state, two argsorts and seven random passes), or 'auto'
-        (dense unless the id map would exceed ~1GB).  The last hop of
-        'dense' runs as sorts and scans (``ops/unique.py::induce_final``).
+      dedup: the inducer of every hop, the seeds' own dedup included:
+        'dense' (``ops/unique.py::induce``: four sorts, a fill and one
+        store a hop and no O(N) state wherever the node buffer covers
+        the static bound on nodes known before the last hop; under an
+        occupancy capacity below that bound an O(N) id map at every hop,
+        four random passes a candidate), 'sort' (argsort-based, no O(N)
+        state, two argsorts and seven random passes), or 'auto' (dense
+        unless the id map would exceed ~1GB).
       last_hop_dedup: when False, final-hop neighbors skip the inducer
         entirely and land in a contiguous leaf block of the node list
         (duplicates allowed).  The sampled edge multiset, every edge's
@@ -398,12 +400,14 @@ class NeighborSampler(BaseSampler):
         """One fused multi-hop sample. seeds: [batch_size], -1 padded
         (``sizes``: another seed width's, the link path's seed union).
 
-        Dedup strategy ('dense' default): an O(N) scatter-map inducer
-        (:func:`dense_induce`) for every hop but the last, which runs as
-        sorts and scans and never touches the map
-        (:func:`induce_final`, which has the chip's numbers).  'sort'
-        keeps the growing-buffer argsort path for graphs too large for
-        the dense id map.
+        Dedup strategy ('dense' default): one chain of
+        :func:`~glt_tpu.ops.unique.induce` calls, the seeds first.  Its
+        form is a fact of the static shapes: sorts and scans at every
+        hop, and no id map in the program, where the node buffer covers
+        the bound on nodes known before the last hop; the O(N)
+        scatter-map inducer at every hop where an occupancy capacity
+        lies under that bound (``ops/unique.py`` has the chip's
+        numbers).  'sort' keeps the growing-buffer argsort path.
         """
         fanouts = self.num_neighbors
         if sizes is None:
@@ -413,8 +417,13 @@ class NeighborSampler(BaseSampler):
         dense = self.dedup == "dense"
 
         if dense:
-            state = dense_induce_init(self.graph.num_nodes, cap)
-            state, _ = dense_induce(state, seeds)
+            # Static bounds on the nodes known before each hop: seeds plus
+            # every candidate of the earlier hops.
+            knowns = [min(b, sizes.full_node_capacity) for b in hop_bounds(
+                widths[0], fanouts, self.frontier_cap).node_bounds]
+            state = induce_init(self.graph.num_nodes, cap, knowns[-2])
+            record_sorted_slots(0, sorted_slots(state, 0, widths[0]))
+            state, _ = induce(state, seeds, 0, False)
             node_buf = state.node_buf
             count = state.count
             frontier = node_buf[: widths[0]]
@@ -487,17 +496,9 @@ class NeighborSampler(BaseSampler):
                     node_buf = jnp.concatenate([node_buf, leaf_ids])
                 new_count = count + jnp.sum(leaf_mask.astype(jnp.int32))
             elif dense:
-                if last:
-                    # Seeds plus every candidate of the earlier hops: all
-                    # the nodes the buffer can hold before this one.
-                    known = min(hop_bounds(widths[0], fanouts,
-                                           self.frontier_cap).node_bounds[i],
-                                sizes.full_node_capacity)
-                    state, nbr_local = induce_final(state, cand, known)
-                    record_sorted_slots(
-                        i + 1, sorted_final_slots(known, cap, w * f))
-                else:
-                    state, nbr_local = dense_induce(state, cand)
+                record_sorted_slots(
+                    i + 1, sorted_slots(state, knowns[i], w * f))
+                state, nbr_local = induce(state, cand, knowns[i], last)
                 node_buf = state.node_buf
                 new_count = state.count
                 nbr_local = nbr_local.reshape(w, f)

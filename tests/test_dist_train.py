@@ -6,6 +6,8 @@ import optax
 import pytest
 from jax.sharding import Mesh
 
+from tests.test_neighbor_sampler import sorted_slots  # noqa: F401 (fixture)
+
 from glt_tpu.data.topology import CSRTopo
 from glt_tpu.models import GraphSAGE
 from glt_tpu.parallel import (
@@ -352,9 +354,12 @@ def test_dist_hop_blocks_have_static_destinations(lhd, variant):
 
 @pytest.mark.parametrize("variant", [
     {}, {"frontier_cap": 8}, {"collective": "ring"}])
-def test_dist_sampler_output_equals_the_map_forms(variant, monkeypatch):
-    """Every shard's ``SamplerOutput`` on a four-shard mesh with the sorted
-    last hop inside ``shard_map`` against the parent's program."""
+def test_dist_sampler_output_equals_the_map_forms(variant, monkeypatch,
+                                                   sorted_slots):
+    """Every shard's ``SamplerOutput`` on a four-shard mesh with every hop
+    sorted inside ``shard_map`` (the buffer is the worst case, so the
+    chain always is) against the parent's program; and the engagement
+    gauge of every hop, 0 the seeds."""
     import glt_tpu.parallel.dist_sampler as mod
     from glt_tpu.parallel import DistNeighborSampler
     from tests.test_neighbor_sampler import assert_outputs_equal, map_form
@@ -373,9 +378,15 @@ def test_dist_sampler_output_equals_the_map_forms(variant, monkeypatch):
             _dist_seeds(g, bs, it, pad_shard=it)[:n_dev]))
             for it in range(2)]
     got = sample()
+    # seeds, then each hop's candidates; one chain, its bound the seeds
+    # plus the candidates of hops 1-2
+    w1, w2 = (8, 8) if variant.get("frontier_cap") else (12, 36)
+    widths = [bs, bs * 3, w1 * 3, w2 * 2]
+    assert [sorted_slots(k) for k in range(4)] == [
+        sum(widths[:k + 1]) for k in range(4)]
     with map_form(monkeypatch, mod) as parent:
         want = sample()
-    assert len(parent) == 1
+    assert parent == [sum(widths[:3])]
     for a, b in zip(got, want):
         assert_outputs_equal(a, b)
 

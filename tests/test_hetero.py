@@ -15,6 +15,7 @@ from glt_tpu.loader.hetero_neighbor_loader import HeteroNeighborLoader
 from glt_tpu.models.rgat import RGAT
 from glt_tpu.sampler import NodeSamplerInput
 from glt_tpu.sampler.hetero_neighbor_sampler import HeteroNeighborSampler
+from tests.test_neighbor_sampler import sorted_slots  # noqa: F401 (fixture)
 
 U, I = 12, 8
 ET_UI = ("user", "clicks", "item")
@@ -537,20 +538,27 @@ def test_scanned_hetero_step_matches_eager():
 
 @pytest.mark.parametrize("layout", ["uncapped", "overflowing", "as_the_cell",
                                     "no_frontier"])
-def test_typed_sampler_output_equals_the_map_forms(layout, monkeypatch):
-    """The typed sampler's whole output with the sorted last hop against
-    the parent's program, same relations, seeds and key: a type whose
-    capacity can hold every node known before the last hop sorts, one
-    whose buffer may already have overflowed keeps the id map."""
+def test_typed_sampler_output_equals_the_map_forms(layout, monkeypatch,
+                                                    sorted_slots):
+    """The typed sampler's whole output with every hop of a type's chain
+    sorted against the parent's program, same relations, seeds and key: a
+    type whose capacity can hold every node known before the last hop
+    sorts at every hop, one whose buffer may already have overflowed
+    keeps the id map at every hop; the gauge sums the sorted chains'
+    keys hop by hop."""
     import glt_tpu.sampler.hetero_neighbor_sampler as mod
     from tests.test_neighbor_sampler import assert_outputs_equal, map_form
     from tests.test_rgat_igbh import TRIM_LAYOUTS, igbh_graphs
 
     caps, fronts, overflows = TRIM_LAYOUTS[layout]
-    graphs, slots = igbh_graphs(seed=2), []
-    real = mod.sorted_final_slots
-    monkeypatch.setattr(mod, "sorted_final_slots",
-                        lambda *a: slots.append(real(*a)) or slots[-1])
+    graphs, chains = igbh_graphs(seed=2), []
+    real = mod.induce_init
+
+    def init(num_nodes, capacity, known_last):
+        state = real(num_nodes, capacity, known_last)
+        chains.append((state.seen is None, capacity, known_last))
+        return state
+    monkeypatch.setattr(mod, "induce_init", init)
 
     def sample():
         samp = HeteroNeighborSampler(graphs, [3, 2, 2], "paper",
@@ -561,14 +569,22 @@ def test_typed_sampler_output_equals_the_map_forms(layout, monkeypatch):
                                        key=jax.random.PRNGKey(k))
                 for k, seeds in enumerate(([0, 7, 21, 40], [3, 3, 59, -1]))]
     got = sample()
+    gauge = [sorted_slots(k) for k in range(4)]
     # Tight capacities leave types under their bound on known nodes
     # (in sorted order of the types: the last is paper).
-    assert [bool(n) for n in slots] == {
+    assert [c[0] for c in chains] == {
         "overflowing": [False] * 4, "as_the_cell": [True] * 3 + [False],
     }.get(layout, [True] * 4)
+    assert all(is_sorted == (known <= cap)
+               for is_sorted, cap, known in chains)
+    # The seeds are paper's: hop 0 reads their 4 slots where paper's chain
+    # is sorted; a hop's keys are over the sorted chains alone.
+    assert gauge[0] == (4 if chains[-1][0] else 0)
+    assert all(gauge) == all(c[0] for c in chains)
+    assert any(gauge) == any(c[0] for c in chains)
     with map_form(monkeypatch, mod) as parent:
         want = sample()
-    assert len(parent) == len(slots) // 2
+    assert parent == [c[2] for c in chains]
     assert any(bool((o.metadata or {}).get("overflow", False))
                for o in got) == overflows
     for a, b in zip(got, want):

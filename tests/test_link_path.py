@@ -206,6 +206,47 @@ def test_the_seed_union_is_laid_out_in_hop_blocks(world, case):
         assert starts[0] == 0 and starts == sorted(starts)
 
 
+@pytest.mark.parametrize("case", ["clamped", "capped_sorted", "capped_map"])
+def test_link_sampler_output_equals_the_map_forms(world, case, monkeypatch):
+    """The link path's whole output (node batch, pair index, labels) with
+    every hop of the seed union's chain sorted against the parent's
+    program.  The union's endpoints repeat, so hop 0, the seeds' own
+    dedup, has work to do; a capacity under the bound on known nodes
+    keeps the id map at every hop."""
+    import glt_tpu.sampler.neighbor_sampler as mod
+    from glt_tpu.ops.unique import chain_is_sorted
+    from tests.test_neighbor_sampler import assert_outputs_equal, map_form
+
+    graph, _, edges, _ = world
+    kw = {"clamped": {}, "capped_sorted": {"node_capacity": 200},
+          "capped_map": {"frontier_cap": 32, "node_capacity": 150}}[case]
+    fanout = FANOUT if case == "clamped" else [2, 2]
+
+    def sample():
+        s = NeighborSampler(graph, fanout, batch_size=Q, with_edge=False,
+                            **kw)
+        # Consecutive CSR positions: the sources repeat, and so do the
+        # destinations of the second batch (the first's, reversed).
+        return s, [s.sample_from_edges(EdgeSamplerInput(
+            row=r, col=c, neg_sampling=NEG), key=jax.random.PRNGKey(it))
+            for it, (r, c) in enumerate([
+                (edges[0, :Q], edges[1, :Q]),
+                (edges[1, :Q], edges[0, :Q])])]
+    s, got = sample()
+    u = s.seed_union(NEG)
+    known_last = min(sum(u.hop_bounds.edge_bounds[:-1][-1:]) + u.batch_size,
+                     u.full_node_capacity)
+    assert chain_is_sorted(known_last, u.node_capacity) == (
+        case != "capped_map")
+    for out in got:
+        assert int(np.asarray(out.num_sampled_nodes)[0]) < 3 * Q
+    with map_form(monkeypatch, mod) as parent:
+        _, want = sample()
+    assert parent == [known_last]
+    for a, b in zip(got, want):
+        assert_outputs_equal(a, b)
+
+
 def test_calibration_over_seed_edges_bounds_the_union(world):
     graph, _, edges, _ = world
     s = NeighborSampler(graph, [2, 2], batch_size=Q, with_edge=False)

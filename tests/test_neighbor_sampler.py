@@ -431,20 +431,22 @@ def test_hop_blocks_have_static_destinations(dedup, lhd, variant):
         assert starts[0] == 0 and starts == sorted(starts)
 
 
-# -- the last hop's inducer: sorted form against the parent's program ------
+# -- every hop's inducer: the sorted chain against the parent's program ----
 @contextlib.contextmanager
 def map_form(monkeypatch, module):
-    """Inside the block the samplers of ``module`` trace the parent's last
-    hop, ``dense_induce_final``; yields the list of its uses, so a test
-    can tell a fresh trace from a cached program."""
-    from glt_tpu.ops.unique import dense_induce_final
+    """Inside the block the samplers of ``module`` trace the parent's
+    program: every chain holds the id map, so ``dense_induce`` runs at
+    every hop and ``dense_induce_final`` at the last.  Yields the list of
+    the chains' bounds, one entry a chain, so a test can tell a fresh
+    trace from a cached program."""
+    from glt_tpu.ops.unique import dense_induce_init
     uses = []
 
-    def parent(state, cand, known):
-        uses.append(known)
-        return dense_induce_final(state, cand)
+    def parent(num_nodes, capacity, known_last):
+        uses.append(known_last)
+        return dense_induce_init(num_nodes, capacity)
     with monkeypatch.context() as patch:
-        patch.setattr(module, "induce_final", parent)
+        patch.setattr(module, "induce_init", parent)
         yield uses
 
 
@@ -481,9 +483,10 @@ INDUCE_VARIANTS = dict(
 @pytest.mark.parametrize("with_edge", [False, True])
 def test_sampler_output_equals_the_map_forms(variant, with_edge,
                                              monkeypatch, sorted_slots):
-    """The whole ``SamplerOutput`` with the sorted last hop against the
+    """The whole ``SamplerOutput`` with every hop sorted against the
     parent's program, same graph, seeds and key: uncapped, under a
-    frontier cap, an occupancy capacity that overflows, padded seeds."""
+    frontier cap, an occupancy capacity that overflows, padded seeds;
+    and the engagement gauge of every hop, 0 the seeds."""
     import glt_tpu.sampler.neighbor_sampler as mod
 
     def build():
@@ -493,13 +496,16 @@ def test_sampler_output_equals_the_map_forms(variant, with_edge,
     new = build()
     got = [hop_sample(new, variant, seed) for seed in range(3)]
     w = new._widths
-    known, m = w[0] + w[0] * 3 + w[1] * 3, w[2] * 2
+    widths = [w[0], w[0] * 3, w[1] * 3, w[2] * 2]   # seeds, then each hop
+    knowns = [0] + list(np.cumsum(widths[:-1]))
     sorts = variant != "known_past_capacity"
-    assert sorts == (known <= new.node_capacity)
-    assert sorted_slots(3) == (known + m if sorts else 0)
+    assert sorts == (knowns[-1] <= new.node_capacity)
+    assert [sorted_slots(hop) for hop in range(4)] == [
+        known + m if sorts else 0 for known, m in zip(knowns, widths)]
     with map_form(monkeypatch, mod) as parent:
         old = build()
         want = [hop_sample(old, variant, seed) for seed in range(3)]
-    assert len(parent) == 1
+    assert parent == [knowns[-1]]
+    assert [sorted_slots(hop) for hop in range(4)] == [0] * 4
     for a, b in zip(got, want):
         assert_outputs_equal(a, b)

@@ -22,6 +22,7 @@ import pytest
 from chipbench import data
 from glt_tpu import obs
 from glt_tpu.obs.scopes import scoped
+from tests.test_neighbor_sampler import sorted_slots  # noqa: F401 (fixture)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -153,15 +154,51 @@ def test_every_scope_of_the_taxonomy_is_in_the_compiled_hlo(
 @pytest.mark.parametrize("program", sorted(LOWER))
 def test_the_sorted_last_hop_stays_under_the_inducers_scope(
         compiled_text, program):
-    """The four sorts of ``ops/unique.py::induce_final`` are in every
-    cell's program and carry ``glt.sample.induce``, so
-    ``sample_induce_ms`` reads them and ``unscoped_share`` does not."""
+    """The four sorts a call of ``ops/unique.py::induce`` (the seeds and
+    three hops; XLA folds two of the seeds' away) are in every cell's
+    program and carry ``glt.sample.induce``, so ``sample_induce_ms``
+    reads them and ``unscoped_share`` does not."""
     text, _ = compiled_text(program)
     sorts = [re.findall(r'op_name="([^"]*)"', line)
              for line in text.splitlines() if re.search(r"\bsort\(", line)]
-    assert len(sorts) == 4
+    assert 4 * 3 < len(sorts) <= 4 * 4
     assert all(names and names[0].endswith("glt.sample.induce/sort")
                for names in sorts), sorts
+
+
+@pytest.mark.parametrize("program", sorted(LOWER))
+def test_no_cell_program_holds_an_id_map(compiled_text, program):
+    """Every chain of the cells' programs is sorted: no ``s32[N + 2]``
+    array, the id map of ``dense_induce``, is left in the compiled HLO."""
+    text, _ = compiled_text(program)
+    nodes = _config({"dist": "tiny-sage-dist4"}.get(
+        program, "tiny-sage"))["data"]["num_nodes"]
+    # the dist sampler's id space: equal shards of the padded node count
+    for n in {nodes, -(-nodes // 4) * 4}:
+        assert f"s32[{n + 2}]" not in text, n
+
+
+@pytest.mark.parametrize("chain", ["sorted", "map"])
+def test_the_engagement_gauge_is_set_for_every_hop(chain, sorted_slots):
+    """``glt.sample.induce_sorted_slots{hop}`` reads ``known_k + m_k`` for
+    the seeds (hop 0) and every hop of a sorted chain, 0 for every hop of
+    a chain that keeps the id map (a capacity under the bound on known
+    nodes).  tests/test_dist_train.py reads it for the dist sampler."""
+    from glt_tpu.sampler import NeighborSampler
+    from tests.test_neighbor_sampler import hop_graph
+
+    # batch 8, fanout [3, 3, 2], frontier cap 16: candidates 24, 48, 32;
+    # 8 + 24 + 48 = 80 nodes known at most before the last hop.
+    widths = [8, 24, 48, 32]
+    s = NeighborSampler(hop_graph(), [3, 3, 2], batch_size=8,
+                        frontier_cap=16, with_edge=False,
+                        node_capacity={"sorted": 80, "map": 79}[chain])
+    g = s.graph
+    jax.jit(s._sample_impl).lower(
+        g.indptr, g.indices, g.gather_edge_ids, jnp.zeros((8,), jnp.int32),
+        jax.random.PRNGKey(0))
+    assert [sorted_slots(k) for k in range(4)] == [
+        sum(widths[:k + 1]) if chain == "sorted" else 0 for k in range(4)]
 
 
 def _without_debug_info(text):
