@@ -195,6 +195,7 @@ class NodeLoader:
         # plan has reached the host by the time the batch is collated.
         plan_of = (feat.plan_gather
                    if getattr(feat, "plans_gathers", False) else None)
+        live = getattr(self.sampler, "live", None)  # any sampler may come
         try:
             while True:
                 while len(pending) < self.prefetch:
@@ -218,6 +219,12 @@ class NodeLoader:
                     # overlapped the prefetch window instead of paying a
                     # blocking round trip per batch.
                     self._prime_overflow_flag(out)
+                    # The sample's live counts ride the same pattern, on
+                    # the registry's carrier: copied behind the program,
+                    # counted once landed, never waited for here.
+                    if live is not None:
+                        _metrics.defer(live.counters, out.live_counts,
+                                       live.per_row)
                     pending.append((out, seeds.shape[0], None if plan_of
                                     is None else plan_of(out.node)))
                 if not pending:

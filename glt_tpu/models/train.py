@@ -283,8 +283,9 @@ def _batch_flags(out):
 
 
 def _scanned_supervised(model, tx, loss, dropout_seed: int,
-                        hops, batch_of, arrays_of, label: str,
-                        feature_cache=None):
+                        hops, batch_of, arrays_of, label: str, live,
+                        feature_cache=None,
+                        flag_counters=(None,)):
     """The wrapper of the scanned steps over sampled seed blocks: ONE
     jitted ``lax.scan`` of the body over ``seeds_blk [G, B]`` (seed
     edges: ``[G, 2, q]``), as ``step(state, seeds_blk, key) -> (state,
@@ -304,8 +305,19 @@ def _scanned_supervised(model, tx, loss, dropout_seed: int,
     (:func:`~glt_tpu.models.step.gated_update`); ``overflows`` is each
     batch's capacity-overflow flag (zeros for an uncapped sampler): a
     flagged batch trained with its excess nodes' edges masked.
+
+    The flags and the sampler's ``live_counts`` leave the scan as one
+    ``[G, C]`` array, which ``step`` hands to
+    :func:`~glt_tpu.obs.metrics.defer`: the flag columns count into
+    ``flag_counters`` (one a column of :func:`_batch_flags`, ``None`` for
+    a column nobody counts; the named ones are ``step.flag_counters``),
+    the rest into ``live`` (the sampler's
+    :class:`~glt_tpu.sampler.base.LiveCounters`) — with metrics off
+    nothing of it is copied or read.
     """
     grads_of = loss_and_grads(model, loss, hops)
+    columns = tuple(flag_counters) + live.counters
+    flag_cols = len(flag_counters)
     update = gated_update(tx)
 
     @partial(jax.jit, donate_argnums=(2,))
@@ -320,22 +332,27 @@ def _scanned_supervised(model, tx, loss, dropout_seed: int,
             loss, acc, grads = grads_of(st.params, x, edge_index,
                                         edge_mask, y, aux, rng)
             st = update(st, grads, jnp.any(seeds >= 0))
-            return (st, cache), (loss, acc, _batch_flags(out))
+            counts = jnp.concatenate([_batch_flags(out).reshape(-1),
+                                      out.live_counts])
+            return (st, cache), (loss, acc, counts)
 
         keys = jax.random.split(key, seeds_blk.shape[0])
-        (state, cache), (losses, accs, ovfs) = jax.lax.scan(
+        (state, cache), (losses, accs, counts) = jax.lax.scan(
             body, (state, cache), (seeds_blk, keys))
-        return state, cache, losses, accs, ovfs
+        ovfs = counts[:, 0] if flag_cols == 1 else counts[:, :flag_cols]
+        return state, cache, losses, accs, ovfs, counts
 
     holder = {"cache": feature_cache}
 
     def step(state: TrainState, seeds_blk, key):
         with _compilewatch.label(label):
-            state, holder["cache"], losses, accs, ovfs = run(
+            state, holder["cache"], losses, accs, ovfs, counts = run(
                 arrays_of(), state, holder["cache"],
                 jnp.asarray(seeds_blk, jnp.int32), key)
+        _metrics.defer(columns, counts, live.per_row)
         return state, losses, accs, ovfs
 
+    step.flag_counters = tuple(c for c in flag_counters if c is not None)
     step.feature_cache = lambda: holder["cache"]
     step.set_feature_cache = lambda cache: holder.update(cache=cache)
     return step
@@ -390,7 +407,7 @@ def make_scanned_node_train_step(model, tx, sampler, rows, labels,
         model, tx, seed_loss(batch_size), dropout_seed, sampler.hop_bounds,
         batch_of,
         lambda: (g.indptr, g.indices, g.gather_edge_ids, hot_rows, labels),
-        "scanned_node_step", feature_cache)
+        "scanned_node_step", sampler.live, feature_cache)
 
 
 def node_seed_blocks(train_idx, batch_size: int, group: int, rng):
@@ -423,9 +440,13 @@ def run_scanned_epoch(step, state, train_idx, batch_size: int,
     path.  Returns ``(state, losses [n_real], accs [n_real],
     overflow_count)`` as host numpy (the fetch is the epoch's sync
     point); ``overflow_count`` is 0 for steps without an overflow
-    channel.  A step whose flags have further columns (the link step's
-    padded negative slots) names a counter for each in
-    ``step.flag_counters``; they are summed in the same fetch.
+    channel.  What else a step counts (``step.flag_counters``, the
+    sampler's live counts) it hands to
+    :func:`~glt_tpu.obs.metrics.defer` itself; nothing of it is read here.
+    The host's own parts carry spans: ``glt.train.seed_stage`` (shuffle
+    and the blocks' ``device_put``), ``glt.train.scanned_block_dispatch``
+    a block, ``glt.train.epoch_fetch`` (the concatenations and the three
+    fetches), all but the first inside ``glt.train.scanned_epoch``.
 
     ``start_block``/``on_block`` are the resume seam
     (:class:`~glt_tpu.ckpt.driver.TrainLoop`): the first ``start_block``
@@ -443,8 +464,9 @@ def run_scanned_epoch(step, state, train_idx, batch_size: int,
 
     seed_blocks = (link_seed_blocks if np.ndim(train_idx) == 2
                    else node_seed_blocks)
-    blocks = [jax.device_put(jnp.asarray(b.astype(np.int32)))
-              for b in seed_blocks(train_idx, batch_size, group, rng)]
+    with _span("train.seed_stage"):
+        blocks = [jax.device_put(jnp.asarray(b.astype(np.int32)))
+                  for b in seed_blocks(train_idx, batch_size, group, rng)]
     n_real = -(-np.shape(train_idx)[-1] // batch_size)
     # Real batches already consumed before the resume point: the loss
     # trim below only accounts for the blocks this call actually runs.
@@ -488,22 +510,23 @@ def run_scanned_epoch(step, state, train_idx, batch_size: int,
                        blocks=len(blocks) - int(start_block),
                        start_block=int(start_block),
                        duration_ms=(time.perf_counter() - t_epoch0) * 1e3)
-        # The epoch's own host fetch below is the sync; the span closes
-        # around it so the scanned epoch's trace duration is truthful.
-        losses = (np.asarray(jax.device_get(
-            jnp.concatenate(losses)))[:n_real] if losses
-            else np.zeros((0,), np.float32))
-    accs = (np.asarray(jax.device_get(jnp.concatenate(accs)))[:n_real]
-            if accs else np.zeros((0,), np.float32))
-    if not ovfs:
-        return state, losses, accs, 0
-    # [batches] overflow flags, or [batches, k] with the overflow flag in
-    # column 0: one fetch, one sum a column.
-    flags = np.asarray(jax.device_get(jnp.concatenate(ovfs)))
-    sums = flags.reshape(flags.shape[0], -1).sum(axis=0).tolist()
-    for counter, n in zip(getattr(step, "flag_counters", ()), sums):
-        counter.inc(n)
-    return state, losses, accs, sums[0]
+        # The epoch's own host fetches are the sync; both spans close
+        # around the last of them so the scanned epoch's trace duration
+        # is truthful.
+        with _span("train.epoch_fetch"):
+            losses = (np.asarray(jax.device_get(
+                jnp.concatenate(losses)))[:n_real] if losses
+                else np.zeros((0,), np.float32))
+            accs = (np.asarray(jax.device_get(
+                jnp.concatenate(accs)))[:n_real] if accs
+                else np.zeros((0,), np.float32))
+            if not ovfs:
+                return state, losses, accs, 0
+            # [batches] overflow flags, or [batches, k] with the overflow
+            # flag in column 0: one fetch.
+            flags = np.asarray(jax.device_get(jnp.concatenate(ovfs)))
+    return state, losses, accs, int(
+        flags.reshape(flags.shape[0], -1)[:, 0].sum())
 
 
 def _resident_rows(f, whole: bool = True):
@@ -570,7 +593,7 @@ def hetero_gather_xy(rows, labels, out, batch_size: int):
 _M_HETERO_OVF = _metrics.counter(
     "glt.hetero.overflowed_batches",
     "scanned hetero batches that overflowed a node or frontier capacity "
-    "(counted by run_scanned_epoch at its loss fetch)")
+    "(a column of the step's deferred counts)")
 
 
 def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
@@ -627,12 +650,11 @@ def make_scanned_hetero_train_step(model, tx, sampler, feats, labels,
         return (cache, out) + hetero_gather_xy(rows_args, labels_arg, out,
                                                batch_size)
 
-    step = _scanned_supervised(
+    return _scanned_supervised(
         model, tx, seed_loss(batch_size), dropout_seed,
         hops if seed_hops else None, batch_of,
-        lambda: (graph_arrays, rows, labels_tgt), "scanned_hetero_step")
-    step.flag_counters = (_M_HETERO_OVF,)
-    return step
+        lambda: (graph_arrays, rows, labels_tgt), "scanned_hetero_step",
+        live=sampler.live, flag_counters=(_M_HETERO_OVF,))
 
 
 def _take_rows(rows_arg, id2index, node):
@@ -689,11 +711,11 @@ def init_train_state(model, tx, feature_dim: int, rng,
 _M_LINK_OVF = _metrics.counter(
     "glt.link.overflowed_batches",
     "scanned link batches whose seed-union sample overflowed its node "
-    "capacity (counted by run_scanned_epoch at its loss fetch)")
+    "capacity (a column of the step's deferred counts)")
 _M_LINK_PADDED = _metrics.counter(
     "glt.link.neg_padded_slots",
     "negative slots of scanned link batches that no strict trial filled "
-    "(the non-strict padding pass; same fetch)")
+    "(the non-strict padding pass; same deferred counts)")
 
 
 def make_scanned_link_train_step(model, tx, sampler, rows,
@@ -768,10 +790,11 @@ def make_scanned_link_train_step(model, tx, sampler, rows,
         return (g.indptr, g.indices, g.gather_edge_ids, sorted_ix, hot_rows,
                 cdf_arg)
 
-    step = _scanned_supervised(model, tx, loss, 0, union.hop_bounds,
-                               batch_of, arrays_of, "scanned_link_step")
-    step.flag_counters = (_M_LINK_OVF, _M_LINK_PADDED)
-    return step
+    return _scanned_supervised(
+        model, tx, loss, 0, union.hop_bounds, batch_of, arrays_of,
+        "scanned_link_step", live=sampler.live_counters(union),
+        flag_counters=((_M_LINK_OVF, _M_LINK_PADDED) if mode == "binary"
+                       else (_M_LINK_OVF,)))
 
 
 def make_scanned_subgraph_train_step(model, tx, sampler, rows, loss_fn,

@@ -15,7 +15,8 @@ reconnect/lease/replay-window counters — under dotted ``glt.*`` names
      compiled program (gltlint GLT010 ``span-in-traced-code`` flags it).
      Device-side quantities ride as device scalars (the feature cache's
      hit/miss counters) and are *published* here from host code after a
-     sync point.
+     sync point, or handed to :func:`defer`, which counts a program's
+     output once it has reached the host and never waits for it.
   3. **Stdlib only.**  No jax/numpy imports — usable from the analysis
      CI image and from pure-host tooling.
 
@@ -27,10 +28,11 @@ Prometheus-style text exposition (:func:`render_prometheus`) backs the
 """
 from __future__ import annotations
 
+import collections
 import re
 import threading
 import time
-from typing import Dict, List, Mapping, Optional, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 _enabled = False
 
@@ -97,6 +99,11 @@ class Counter(_Instrument):
     def inc(self, n: float = 1.0) -> None:
         if not _enabled:
             return
+        self._add(n)
+
+    def _add(self, n: float) -> None:
+        # What :func:`defer` took while metrics were on is counted when it
+        # lands, on or off by then.
         with self._lock:
             self._value += n
 
@@ -278,8 +285,10 @@ class Registry:
         Module-level instruments are created once at import and held by
         the hot paths forever; dropping the table would silently detach
         those live handles from every later snapshot, so reset clears
-        values, not registrations.
+        values, not registrations.  Deferred device counts still pending
+        are dropped with them.
         """
+        _take_pending(all_of_them=True)
         for inst in self.instruments():
             with inst._lock:
                 if isinstance(inst, Histogram):
@@ -294,7 +303,9 @@ class Registry:
         ``<name>.count`` and ``<name>.sum`` plus derived
         ``.p50/.p95/.p99`` latency quantiles once they hold samples —
         the SLO read ``bench_serving``-class consumers want without
-        re-deriving from buckets."""
+        re-deriving from buckets.  Folds in every deferred device count
+        first (:func:`defer`), waiting for those still in flight."""
+        flush_deferred()
         out: Dict[str, float] = {}
         for inst in self.instruments():
             if isinstance(inst, Histogram):
@@ -309,7 +320,9 @@ class Registry:
         return out
 
     def render_prometheus(self) -> str:
-        """Prometheus text exposition format 0.0.4."""
+        """Prometheus text exposition format 0.0.4 (deferred device
+        counts folded in first, as :meth:`snapshot` does)."""
+        flush_deferred()
         by_name: Dict[str, List[_Instrument]] = {}
         for inst in self.instruments():
             by_name.setdefault(inst.name, []).append(inst)
@@ -391,6 +404,88 @@ def histogram(name: str, help: str = "",
               buckets: Tuple[float, ...] = DEFAULT_BUCKETS_MS) -> Histogram:
     return REGISTRY.histogram(name, help=help, labels=labels,
                               buckets=buckets)
+
+
+# -- counts produced on the device ------------------------------------------
+#: Deferred entries kept before the oldest are folded in, waiting.
+DEFER_BOUND = 64
+_pending: "collections.deque" = collections.deque()
+_pending_lock = threading.Lock()
+
+
+def defer(counters: Sequence[Optional[Counter]], values,
+          per_row: Sequence[Tuple[Counter, float]] = ()) -> None:
+    """Count what a compiled program counted, without waiting for it.
+
+    ``values`` is a device array (an OUTPUT of the program; nothing is
+    computed here) whose last axis lines up with ``counters``; every
+    leading axis is summed (``[G, C]`` out of a scan, ``[S, C]`` off a
+    mesh), and entry ``i`` of the sum goes to ``counters[i].inc`` (``None``
+    skips a column).  ``per_row`` pairs a counter with a static number
+    that is counted once a row of ``values``, by the same fold: a slot
+    count beside its live count, so that a ratio of the two needs no
+    gauge that was set while metrics were off.
+
+    The contract, on every step's critical path: **no host sync and no
+    blocking round trip, on or off.**  Disabled, this returns after one
+    read of the module's flag; no method of ``values`` is called and
+    nothing is kept.  Enabled, it starts ``copy_to_host_async()``, puts
+    the array on a pending list, and folds in only the entries whose
+    ``is_ready()`` is already true.  What is still in flight becomes
+    visible at :func:`snapshot` / :func:`render_prometheus`
+    (:func:`flush_deferred`), which may wait, or once the device has
+    finished and ``defer`` is called again.  The list holds at most
+    :data:`DEFER_BOUND` entries: past that the oldest are folded in,
+    waiting for them (an entry that old has long landed).  A caller
+    that is itself being traced (a step wrapped in an outer ``jax.jit``)
+    hands over a tracer, which holds no count and is left alone.
+    """
+    if not _enabled:
+        return
+    start_copy = getattr(values, "copy_to_host_async", None)
+    if start_copy is None:
+        return
+    start_copy()
+    with _pending_lock:
+        _pending.append((tuple(counters), values, tuple(per_row)))
+    _fold(_take_pending())
+
+
+def flush_deferred() -> None:
+    """Fold in every pending :func:`defer` entry, waiting for the ones
+    still on their way to the host."""
+    _fold(_take_pending(all_of_them=True))
+
+
+def _take_pending(all_of_them: bool = False) -> list:
+    """Pop, oldest first, the entries to fold now: those already on the
+    host, and whatever the bound pushes out."""
+    taken = []
+    with _pending_lock:
+        while _pending and (all_of_them or len(_pending) > DEFER_BOUND
+                            or _pending[0][1].is_ready()):
+            taken.append(_pending.popleft())
+    return taken
+
+
+def _fold(entries) -> None:
+    if not entries:
+        return
+    import numpy as np      # with a device array in hand, numpy is there
+
+    for counters, values, per_row in entries:
+        if getattr(values, "is_fully_addressable", True):
+            host = np.asarray(values)
+        else:               # a mesh over several processes: our shards
+            host = np.concatenate([
+                np.asarray(s.data).reshape(-1, len(counters))
+                for s in values.addressable_shards if s.replica_id == 0])
+        rows = host.reshape(-1, len(counters))
+        for c, n in zip(counters, rows.sum(axis=0, dtype=np.int64).tolist()):
+            if c is not None:
+                c._add(n)
+        for c, n in per_row:
+            c._add(n * rows.shape[0])
 
 
 def snapshot() -> Dict[str, float]:

@@ -44,7 +44,8 @@ from ..ops.unique import (
     sorted_slots,
     unique_first_occurrence,
 )
-from ..sampler.base import NegativeSampling, SamplerOutput
+from ..sampler.base import (NegativeSampling, SamplerOutput, live_counters,
+                            live_counts)
 from ..sampler.neighbor_sampler import (hop_bounds, hop_widths,
                                         max_sampled_nodes)
 from ..typing import PADDING_ID
@@ -1000,6 +1001,27 @@ def exchange_one_hop_ring(
     return nbrs, eids, nbrs >= 0, routing.dropped
 
 
+def dist_live_counters(batch_size: int, num_neighbors: Sequence[int],
+                       num_shards: int, frontier_cap: Optional[int] = None,
+                       exact: bool = True):
+    """Where one shard's ``live_counts`` of
+    :func:`dist_sample_multi_hop` are counted.  The frontier and edge
+    slots are what the shard's reads PROCESS, not the width it asks
+    with: under the exact flat exchange (``exact``: no
+    ``exchange_load_factor``, no hierarchical plan) every shard serves
+    ``S`` requesters' whole frontiers, ``S x width`` rows a hop.  Any
+    other exchange serves a matrix of its own shape that nothing here
+    re-derives: its slot counters stay where they are, so a share over
+    them reads nothing until the change that runs that exchange counts
+    them from its own shapes."""
+    fanouts = list(num_neighbors)
+    rows = [num_shards * w if exact else None
+            for w in hop_widths(batch_size, fanouts, frontier_cap)]
+    return live_counters(
+        rows, [r * f if exact else None for r, f in zip(rows, fanouts)],
+        max_sampled_nodes(batch_size, fanouts, frontier_cap))
+
+
 def dist_sample_multi_hop(
     indptr: jnp.ndarray,
     indices: jnp.ndarray,
@@ -1180,6 +1202,7 @@ def dist_sample_multi_hop(
         [counts_per_hop[0]]
         + [counts_per_hop[i + 1] - counts_per_hop[i]
            for i in range(len(fanouts))])
+    num_sampled_edges = jnp.stack(edges_per_hop)
     return SamplerOutput(
         node=node_buf,
         row=jnp.concatenate(rows),
@@ -1189,11 +1212,13 @@ def dist_sample_multi_hop(
         node_mask=node_mask,
         edge_mask=jnp.concatenate(emasks),
         num_sampled_nodes=num_sampled_nodes,
-        num_sampled_edges=jnp.stack(edges_per_hop),
+        num_sampled_edges=num_sampled_edges,
         metadata=(None
                   if exchange_load_factor is None
                   and hier_load_factor is None
                   else {"exchange_dropped": dropped_total}),
+        live_counts=live_counts(num_sampled_nodes, num_sampled_edges,
+                                widths, cap),
     )
 
 

@@ -36,7 +36,8 @@ from .dist_feature import (
 )
 from ..obs import metrics as _metrics
 from .dist_sampler import (DistNeighborSampler, _topology_choice,
-                           dist_sample_multi_hop, exchange_byte_model,
+                           dist_live_counters, dist_sample_multi_hop,
+                           exchange_byte_model,
                            hier_request_cap, mesh_axis_sizes,
                            resolve_mesh_axes)
 from .sharding import ShardedFeature, ShardedGraph
@@ -99,7 +100,8 @@ def _byte_counters(byte_model):
     return record
 
 
-def _sharded_step(tx, mesh, axis_name, local_grads, any_valid):
+def _sharded_step(tx, mesh, axis_name, local_grads, any_valid,
+                  per_shard: int = 0):
     """The wrapper of every step that takes its gradients inside a
     ``shard_map`` and updates outside it: ``step(arrays, state, batch,
     key) -> (state, loss, acc)``, one jit.
@@ -108,6 +110,9 @@ def _sharded_step(tx, mesh, axis_name, local_grads, any_valid):
     one shard's blocks (leading axis stripped) and ``key`` folded with
     the shard's index, and returns mesh means; ``any_valid(batch)`` gates
     the replicated update (:func:`~glt_tpu.models.step.gated_update`).
+    With ``per_shard`` it returns that many arrays more, each shard's
+    own (its counts), and the step returns them stacked ``[S, ...]``
+    behind ``acc``.
     The sharded ``arrays`` ride as jit ARGUMENTS, not closure captures:
     multi-host global arrays span non-addressable devices and may not be
     closed over.
@@ -117,18 +122,20 @@ def _sharded_step(tx, mesh, axis_name, local_grads, any_valid):
     def local_body(arrays, batch, params, key):
         arrays, batch = jax.tree.map(lambda a: a[0], (arrays, batch))
         key = jax.random.fold_in(key, lax.axis_index(axis_name))
-        return local_grads(arrays, batch, params, key)
+        loss, acc, grads, *mine = local_grads(arrays, batch, params, key)
+        return (loss, acc, grads) + tuple(m[None] for m in mine)
 
     shard_fn = jax.shard_map(
         local_body, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(), P()),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P()) + (P(axis_name),) * per_shard,
         check_vma=False)
 
     @jax.jit
     def _step(arrays, state: TrainState, batch, key: jax.Array):
-        loss, acc, grads = shard_fn(arrays, batch, state.params, key)
-        return update(state, grads, any_valid(batch)), loss, acc
+        loss, acc, grads, *mine = shard_fn(arrays, batch, state.params,
+                                           key)
+        return (update(state, grads, any_valid(batch)), loss, acc, *mine)
 
     return _step
 
@@ -206,10 +213,13 @@ def _dist_local_grads(model, g, f, mesh, num_neighbors, batch_size,
                       exchange_load_factor, dedup_gather, route, fused,
                       fused_frontier, hier_load_factor):
     """What :func:`make_dist_train_step` and its scanned twin share:
-    ``(axis_name, byte_model, local_grads)`` with ``local_grads(arrays,
-    seeds, params, key) -> (loss, acc, grads)`` one shard's sample,
-    gather, forward and backward, meaned over the mesh.  ``key`` draws
-    the sample AND the dropout mask."""
+    ``(axis_name, byte_model, live, local_grads)`` with
+    ``local_grads(arrays, seeds, params, key) -> (loss, acc, grads,
+    live_counts)`` one shard's sample, gather, forward and backward,
+    meaned over the mesh, and the sample's ``live_counts``, which the
+    step defers into ``live``
+    (:func:`~glt_tpu.parallel.dist_sampler.dist_live_counters`).  ``key``
+    draws the sample AND the dropout mask."""
     axis_name = resolve_mesh_axes(mesh, axis_name)
     mesh_shape = mesh_axis_sizes(mesh, axis_name)
     _, gather_xy = _exchange_xy(
@@ -225,6 +235,10 @@ def _dist_local_grads(model, g, f, mesh, num_neighbors, batch_size,
         model, seed_loss(batch_size),
         hop_bounds(batch_size, num_neighbors, frontier_cap),
         mean_over=axis_name)
+    live = dist_live_counters(
+        batch_size, num_neighbors, g.num_shards, frontier_cap,
+        exact=exchange_load_factor is None and _topology_choice(
+            route, axis_name, mesh_shape) == "flat")
 
     def local_grads(arrays, seeds, params, key):
         indptr, indices, edge_ids, rows, labels_blk = arrays
@@ -237,9 +251,10 @@ def _dist_local_grads(model, g, f, mesh, num_neighbors, batch_size,
             hier_load_factor=hier_load_factor)
         x, y = gather_xy(out.node, rows, labels_blk, space, None)
         edge_index, edge_mask, aux = graph_inputs(out)
-        return grads_of(params, x, edge_index, edge_mask, y, aux, key)
+        return grads_of(params, x, edge_index, edge_mask, y, aux, key) + (
+            out.live_counts,)
 
-    return axis_name, byte_model, local_grads
+    return axis_name, byte_model, live, local_grads
 
 
 def make_dist_train_step(
@@ -290,17 +305,21 @@ def make_dist_train_step(
     ICI/DCN byte model and feeds the ``glt.dist.collective_bytes{axis=}``
     counters per call.
     """
-    axis_name, byte_model, local_grads = _dist_local_grads(
+    axis_name, byte_model, live, local_grads = _dist_local_grads(
         model, g, f, mesh, num_neighbors, batch_size, axis_name,
         frontier_cap, last_hop_dedup, exchange_load_factor, dedup_gather,
         route, fused, fused_frontier, hier_load_factor)
     record_bytes = _byte_counters(byte_model)
-    _step = _sharded_step(tx, mesh, axis_name, local_grads, _any_seed)
+    _step = _sharded_step(tx, mesh, axis_name, local_grads, _any_seed,
+                          per_shard=1)
 
     def step(state: TrainState, seeds: jnp.ndarray, key: jax.Array):
         record_bytes()
-        return _step((g.indptr, g.indices, g.edge_ids, f.rows, labels),
-                     state, seeds, key)
+        state, loss, acc, counts = _step(
+            (g.indptr, g.indices, g.edge_ids, f.rows, labels), state,
+            seeds, key)
+        _metrics.defer(live.counters, counts, live.per_row)   # [S, C]
+        return state, loss, acc
 
     step.collective_bytes = byte_model
     return step
@@ -341,7 +360,7 @@ def make_scanned_dist_train_step(
     topology choice is static, so scanning over ``dist_seed_blocks``
     recompiles nothing.
     """
-    axis_name, byte_model, local_grads = _dist_local_grads(
+    axis_name, byte_model, live, local_grads = _dist_local_grads(
         model, g, f, mesh, num_neighbors, batch_size, axis_name,
         frontier_cap, last_hop_dedup, exchange_load_factor, dedup_gather,
         route, fused, fused_frontier, hier_load_factor)
@@ -359,21 +378,21 @@ def make_scanned_dist_train_step(
         def body(carry, inp):
             st, = carry
             seeds, k = inp
-            loss, acc, grads = local_grads(arrays, seeds, st.params,
-                                           jax.random.fold_in(k, me))
+            loss, acc, grads, counts = local_grads(
+                arrays, seeds, st.params, jax.random.fold_in(k, me))
             nvalid = lax.psum(jnp.sum((seeds >= 0).astype(jnp.int32)),
                               axis_name)
-            return (update(st, grads, nvalid > 0),), (loss, acc)
+            return (update(st, grads, nvalid > 0),), (loss, acc, counts)
 
-        (state,), (losses, accs) = lax.scan(body, (state,),
-                                            (seeds_blk, keys))
-        return state, losses, accs
+        (state,), (losses, accs, counts) = lax.scan(body, (state,),
+                                                    (seeds_blk, keys))
+        return state, losses, accs, counts[:, None]
 
     shard_fn = jax.shard_map(
         local_body, mesh=mesh,
         in_specs=(gspec, gspec, gspec, gspec, gspec, P(None, axis_name),
                   P(), P()),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P(), P(None, axis_name)),
         check_vma=False)
 
     # Global arrays as jit arguments (multi-host: no closure capture).
@@ -387,8 +406,11 @@ def make_scanned_dist_train_step(
     def step(state: TrainState, seeds_blk: jnp.ndarray, key: jax.Array):
         seeds_blk = jnp.asarray(seeds_blk, jnp.int32)
         record_bytes(int(seeds_blk.shape[0]))
-        return _step(g.indptr, g.indices, g.edge_ids, f.rows, labels,
-                     state, seeds_blk, key)
+        state, losses, accs, counts = _step(
+            g.indptr, g.indices, g.edge_ids, f.rows, labels, state,
+            seeds_blk, key)
+        _metrics.defer(live.counters, counts, live.per_row)   # [G, S, C]
+        return state, losses, accs
 
     step.collective_bytes = byte_model
     return step

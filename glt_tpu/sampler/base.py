@@ -13,12 +13,13 @@ from __future__ import annotations
 
 import dataclasses
 from abc import ABC, abstractmethod
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import metrics as _metrics
 from ..typing import EdgeType, NodeType
 
 
@@ -119,6 +120,63 @@ class EdgeSamplerInput:
         )
 
 
+class LiveCounters(NamedTuple):
+    """What :func:`~glt_tpu.obs.metrics.defer` needs beside a batch's
+    ``live_counts``: the counters its columns go to, and the static slot
+    counts of one batch (:func:`live_counters`)."""
+    counters: Tuple[Any, ...]
+    per_row: Tuple[Tuple[Any, int], ...]
+
+
+def live_counters(frontier_slots: Sequence[int], edge_slots: Sequence[int],
+                  node_slots: int) -> LiveCounters:
+    """The ``glt.sample.*`` counters of a sampler with these static sizes
+    (docs/observability.md).  Live, in the order of a batch's
+    ``live_counts``: ``frontier_nodes{hop=k}`` (frontier rows of hop ``k``
+    that hold a node), ``edges{hop=k}`` (sampled edges of hop ``k``),
+    ``nodes`` (valid rows of the node buffer).  Static, once a batch:
+    ``frontier_slots{hop=k}`` and ``edge_slots{hop=k}`` (the rows and the
+    edge slots hop ``k``'s neighbour read PROCESSES, whoever asked for
+    them; ``None`` where the caller cannot say: that counter is not
+    counted), ``node_slots`` (rows of the node buffer) and ``batches``."""
+    hops = [{"hop": str(k + 1)} for k in range(len(frontier_slots))]
+
+    def per_hop(name, help):
+        return [_metrics.counter("glt.sample." + name, help, h)
+                for h in hops]
+
+    live = (per_hop("frontier_nodes", "frontier rows of the hop that held "
+                    "a node, over sampled batches")
+            + per_hop("edges", "edges the hop sampled, over sampled batches")
+            + [_metrics.counter("glt.sample.nodes", "valid rows of the "
+                                "node buffer, over sampled batches")])
+    static = (list(zip(per_hop("frontier_slots", "frontier rows the hop's "
+                               "neighbour read processed, live or not"),
+                       frontier_slots))
+              + list(zip(per_hop("edge_slots", "edge slots the hop's "
+                                 "neighbour read processed, live or not"),
+                         edge_slots))
+              + [(_metrics.counter("glt.sample.node_slots", "rows of the "
+                                   "node buffer, live or not"), node_slots),
+                 (_metrics.counter("glt.sample.batches", "sampled batches "
+                                   "whose counts were deferred"), 1)])
+    return LiveCounters(tuple(live), tuple((c, int(n)) for c, n in static
+                                           if n is not None))
+
+
+def live_counts(num_sampled_nodes, num_sampled_edges,
+                frontier_widths: Sequence[int], node_capacity: int):
+    """A batch's ``live_counts`` from what a homogeneous sampler already
+    counts: the frontier of hop ``k`` is the nodes first seen at hop
+    ``k - 1`` as far as its static width holds them, and the node buffer
+    holds every node seen as far as its capacity does."""
+    frontier = jnp.minimum(num_sampled_nodes[:-1],
+                           jnp.asarray(frontier_widths, jnp.int32))
+    nodes = jnp.minimum(jnp.sum(num_sampled_nodes), node_capacity)
+    return jnp.concatenate([frontier, num_sampled_edges,
+                            nodes[None]]).astype(jnp.int32)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class SamplerOutput:
@@ -136,6 +194,10 @@ class SamplerOutput:
     * ``batch``: ``[batch_size]`` the seed ids this batch was sampled for.
     * ``num_sampled_nodes`` / ``num_sampled_edges``: per-hop valid counts
       (device int32 vectors, lengths num_hops+1 / num_hops).
+    * ``live_counts``: ``[2 * num_hops + 1]`` int32, the useful work of
+      the batch in the order of :func:`live_counters`: frontier slots
+      that held a node at each hop, sampled edges of each hop, valid
+      rows of ``node``.
     * ``metadata``: dict of extra arrays (edge_label_index, labels, ...).
 
     Leaf-block layout caveat: with ``last_hop_dedup=False`` (see
@@ -157,19 +219,20 @@ class SamplerOutput:
     num_sampled_edges: Optional[jnp.ndarray] = None
     input_type: Optional[Any] = None
     metadata: Optional[Dict[str, Any]] = None
+    live_counts: Optional[jnp.ndarray] = None
 
     def tree_flatten(self):
         children = (self.node, self.row, self.col, self.edge, self.batch,
                     self.node_mask, self.edge_mask, self.num_sampled_nodes,
-                    self.num_sampled_edges, self.metadata)
+                    self.num_sampled_edges, self.metadata, self.live_counts)
         return children, (self.input_type,)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         (node, row, col, edge, batch, node_mask, edge_mask, nsn, nse,
-         metadata) = children
+         metadata, live) = children
         return cls(node, row, col, edge, batch, node_mask, edge_mask, nsn,
-                   nse, aux[0], metadata)
+                   nse, aux[0], metadata, live)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -193,19 +256,21 @@ class HeteroSamplerOutput:
     num_sampled_edges: Optional[Dict[EdgeType, jnp.ndarray]] = None
     input_type: Optional[Any] = None
     metadata: Optional[Dict[str, Any]] = None
+    # As SamplerOutput's, summed over node types and relations per hop.
+    live_counts: Optional[jnp.ndarray] = None
 
     def tree_flatten(self):
         children = (self.node, self.row, self.col, self.edge, self.batch,
                     self.node_mask, self.edge_mask, self.num_sampled_nodes,
-                    self.num_sampled_edges, self.metadata)
+                    self.num_sampled_edges, self.metadata, self.live_counts)
         return children, (self.input_type,)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         (node, row, col, edge, batch, node_mask, edge_mask, nsn, nse,
-         metadata) = children
+         metadata, live) = children
         return cls(node, row, col, edge, batch, node_mask, edge_mask, nsn,
-                   nse, aux[0], metadata)
+                   nse, aux[0], metadata, live)
 
 
 @dataclasses.dataclass(frozen=True)

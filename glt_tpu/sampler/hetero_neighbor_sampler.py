@@ -31,7 +31,8 @@ from ..ops.unique import (
 )
 from ..typing import EdgeType, NodeType, PADDING_ID, reverse_edge_type
 from ..ops.unique import relabel_by_reference
-from .base import BaseSampler, HeteroSamplerOutput, NodeSamplerInput
+from .base import (BaseSampler, HeteroSamplerOutput, NodeSamplerInput,
+                   live_counters)
 from .neighbor_sampler import _pad_ids
 
 
@@ -291,6 +292,7 @@ class HeteroNeighborSampler(BaseSampler):
         self.hop_bounds = hetero_hop_bounds(
             self.edge_types, self.num_neighbors, self._widths,
             self._capacity, self._num_nodes_by_type)
+        self.live = self.live_counters(self._widths, self._capacity)
         self._sample_jit = jax.jit(
             partial(self._sample_impl, self._widths, self._capacity))
         self._edges_jit = {}
@@ -305,6 +307,22 @@ class HeteroNeighborSampler(BaseSampler):
     def hop_widths(self) -> List[Dict[NodeType, int]]:
         """Per-hop per-node-type frontier widths (static trace shapes)."""
         return [dict(w) for w in self._widths]
+
+    def live_counters(self, widths, cap):
+        """Where a batch's ``live_counts`` are counted
+        (:func:`~glt_tpu.sampler.base.live_counters`), summed over the
+        hop's neighbour reads, one a relation: ``frontier_slots{hop}``
+        counts a source type's frontier once for every relation read
+        from it, as ``frontier_nodes{hop}`` counts its live rows."""
+        reads = [[(widths[hop][et[0]], self.num_neighbors[et][hop])
+                  for et in self.edge_types
+                  if hop < len(self.num_neighbors[et])
+                  and self.num_neighbors[et][hop] > 0
+                  and widths[hop][et[0]] > 0]
+                 for hop in range(self.num_hops)]
+        return live_counters([sum(w for w, _ in r) for r in reads],
+                             [sum(w * f for w, f in r) for r in reads],
+                             sum(max(n, 1) for n in cap.values()))
 
     def _next_key(self) -> jax.Array:
         key = jax.random.fold_in(self._base_key, self._call_count)
@@ -363,6 +381,10 @@ class HeteroNeighborSampler(BaseSampler):
         frontier = {t: None for t in node_types}
         frontier_start = {t: jnp.zeros((), jnp.int32)
                           for t in node_types}
+        # Live rows of each type's current frontier, and the hop's sums
+        # over its neighbour reads (``live_counters``).
+        frontier_live = dict(frontier_start)
+        frontier_nodes, hop_edges = [], []
 
         for t0, seeds in seeds_dict.items():
             if t0 in dense_state:
@@ -380,11 +402,13 @@ class HeteroNeighborSampler(BaseSampler):
                                 .set(u0.uniques))
                 count[t0] = u0.count
                 frontier[t0] = u0.uniques
+            frontier_live[t0] = count[t0]
 
         rows = {et: [] for et in self.edge_types}
         cols = {et: [] for et in self.edge_types}
         eids = {et: [] for et in self.edge_types}
         emasks = {et: [] for et in self.edge_types}
+        edge_counts = {et: [] for et in self.edge_types}
         counts_hist = {t: [count[t]] for t in node_types}
         # t -> (leaf_off, full-leaf-region validity mask, interior count)
         # for types whose final hop used the no-dedup leaf block.
@@ -416,6 +440,9 @@ class HeteroNeighborSampler(BaseSampler):
                 src_local = jnp.where(frontier[et[0]] >= 0, src_local,
                                       PADDING_ID)
                 hop_out[et] = (out, src_local, w, f)
+            frontier_nodes.append(sum(
+                (frontier_live[et[0]] for et in hop_out),
+                jnp.zeros((), jnp.int32)))
 
             # 2) per dst type: merge all candidates into the unique buffer
             new_frontier = {}
@@ -486,6 +513,7 @@ class HeteroNeighborSampler(BaseSampler):
                         jnp.broadcast_to(src_local[:, None], (w, f)).ravel())
                     eids[et].append(out.eids.ravel())
                     emasks[et].append(ok.ravel())
+                    edge_counts[et].append(jnp.sum(ok, dtype=jnp.int32))
 
                 old_count = count[t]
                 nw = widths[hop + 1][t]
@@ -507,7 +535,14 @@ class HeteroNeighborSampler(BaseSampler):
                         merged_count - old_count > widths[hop + 1][t])
                 count[t] = jnp.minimum(merged_count, buflen)
                 frontier_start[t] = old_count
+                frontier_live[t] = jnp.minimum(count[t] - old_count, nw)
 
+            zero = jnp.zeros((), jnp.int32)
+            for et in self.edge_types:
+                if len(edge_counts[et]) <= hop:     # no read of it this hop
+                    edge_counts[et].append(zero)
+            hop_edges.append(sum((edge_counts[et][hop]
+                                  for et in self.edge_types), zero))
             for t in node_types:
                 counts_hist[t].append(count[t])
                 # the hop frontier is consumed; only newly discovered
@@ -540,8 +575,13 @@ class HeteroNeighborSampler(BaseSampler):
                     + [counts_hist[t][i + 1] - counts_hist[t][i]
                        for i in range(len(counts_hist[t]) - 1)])
                 for t in node_types},
+            num_sampled_edges={rev[et]: jnp.stack(edge_counts[et])
+                               for et in self.edge_types},
             input_type=self.input_type,
             metadata={"overflow": overflow} if self.capped else None,
+            live_counts=jnp.stack(
+                frontier_nodes + hop_edges
+                + [sum(count.values(), jnp.zeros((), jnp.int32))]),
         )
         return out
 

@@ -55,6 +55,8 @@ from .base import (
     NegativeSampling,
     NodeSamplerInput,
     SamplerOutput,
+    live_counters,
+    live_counts,
 )
 
 
@@ -308,6 +310,9 @@ class NeighborSampler(BaseSampler):
         self.capped = mine.capped
         self.edge_capacity = mine.edge_capacity
         self.hop_bounds = mine.hop_bounds
+        # Where a node batch's ``live_counts`` are counted (a link batch's:
+        # ``live_counters(self.seed_union(neg_sampling))``).
+        self.live = self.live_counters(mine)
 
         self._sample_jit = jax.jit(self._sample_impl)
         self._sample_many_jit = {}
@@ -358,6 +363,14 @@ class NeighborSampler(BaseSampler):
             hop_bounds=hop_bounds(width, fanouts, fcap, cap))
         self._sizes[key] = sizes
         return sizes
+
+    def live_counters(self, sizes: SampleSizes):
+        """The ``glt.sample.*`` counters of a batch sampled at ``sizes``
+        (:func:`~glt_tpu.sampler.base.live_counters`)."""
+        return live_counters(
+            sizes.widths,
+            [w * f for w, f in zip(sizes.widths, self.num_neighbors)],
+            sizes.node_capacity)
 
     def seed_union(self, neg_sampling: Optional[NegativeSampling] = None
                    ) -> SampleSizes:
@@ -558,6 +571,7 @@ class NeighborSampler(BaseSampler):
             [counts_per_hop[0]]
             + [counts_per_hop[i + 1] - counts_per_hop[i]
                for i in range(len(fanouts))])
+        num_sampled_edges = jnp.stack(edges_per_hop)
         metadata = None
         if capped:
             # `count` keeps counting uniques past the cap (dense_induce's
@@ -584,8 +598,10 @@ class NeighborSampler(BaseSampler):
             node_mask=node_mask,
             edge_mask=jnp.concatenate(emasks),
             num_sampled_nodes=num_sampled_nodes,
-            num_sampled_edges=jnp.stack(edges_per_hop),
+            num_sampled_edges=num_sampled_edges,
             metadata=metadata,
+            live_counts=live_counts(num_sampled_nodes, num_sampled_edges,
+                                    widths, cap),
         )
 
     # -- public API (cf. sampler/neighbor_sampler.py:138) ------------------

@@ -26,7 +26,8 @@ bound runs it now (no map).  ``same_as_map`` says whether ``local``,
 ``node_buf`` and ``count`` of every hop agree bit for bit.
 
 It is the go / no-go of ``ops/unique.py::induce`` (PERF.md §6, PRs 29 and
-33, has its tables): no benchmark cell runs it.  Times are host clock
+33, keeps the whole-chain numbers; the full tables are in ``git show
+4efd33c:PERF.md``): no benchmark cell runs it.  Times are host clock
 over ``--reps`` back-to-back calls ended by one ``block_until_ready``; a
 program under 0.2 ms reads about 0.2 ms, the host's dispatch.  A last-hop
 shape takes three to five minutes, most of it compiling (``--shapes``
