@@ -33,6 +33,29 @@ correctness oracle.  ``force='auto'`` serves the winner memoized by
 ``GLT_SAMPLE_FORCE`` env var overrides (``pallas``/``xla``/
 ``interpret`` — the last runs the Pallas path in interpret mode so the
 seam is exercisable end to end on CPU).
+
+**The chunk rule** (the XLA arm; PERF.md §6, PR 35).  A frontier is a
+static ``[w]`` buffer whose tail holds no node: 38-58 % of the widest
+read's rows in the one-chip GraphSAGE cells, 89 % of the rows a shard of
+the dist step serves.  XLA's scalar gather costs a dead slot what it
+costs a live one, so the hop is split into what is arithmetic and what is
+a random read.  The random reads (the two row-pointer reads, then
+``indices[flat]`` and ``edge_ids[flat]``) run :data:`CHUNK_ROWS` frontier
+rows at a time, in a loop whose trip count is the number of chunks in
+which some row holds an id, a fact of the input: one prefix of live rows
+(the one-chip samplers), ``S`` prefixes (the dist served matrix) and a
+scattered frontier fall out of the same rule, and nothing is compacted,
+sorted by id or calibrated.  A skipped row reads as a padding row does
+(degree 0, every slot ``PADDING_ID``).  **The draw stays one call over
+the whole width**: it is elementwise, and ``jax.random`` defines the
+per-(key, slot) stream by the shape it is asked for, so a draw made a
+chunk at a time would be another stream; kept whole, ``nbrs``, ``eids``
+and ``mask`` are the whole read's bit for bit.  Whether the loop exists
+is a fact of the static shape: a frontier of at most one chunk lowers to
+the single fusion it always was.  On the chip a dead row costs more than
+a live one (every padding slot reads ``indices[0]``, and reads of one
+address serialise: 17.6 ns a slot with every row live, 20.2 at 10 %), so
+the skipped chunks give back more than their share of the rows.
 """
 from __future__ import annotations
 
@@ -41,8 +64,18 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..typing import PADDING_ID
+
+#: Frontier rows in one chunk of the hop's random reads (module docstring,
+#: "The chunk rule").  One constant for every caller, chosen on the chip
+#: (``scripts/hop_read_micro.py``, whose docstring keeps the table; TPU
+#: v5e, libtpu 0.0.34): at the widest reads (153,600 and 614,400 rows) the
+#: loop at 2,560 rows costs 0.90-1.03 of the whole read with every row
+#: live and 0.26-0.41 of it at 40 % live; 1,024 pays 19 % at a full
+#: frontier (150 trips), 5,120 and 10,240 pay 8-15 %.
+CHUNK_ROWS = 2560
 
 
 class NeighborOutput(NamedTuple):
@@ -60,6 +93,48 @@ def _row_offsets_and_degrees(indptr, seeds):
     deg = indptr[safe + 1] - start
     deg = jnp.where(valid, deg, 0)
     return start, deg.astype(jnp.int32)
+
+
+def _live_chunks(seeds: jnp.ndarray):
+    """The chunks of :data:`CHUNK_ROWS` frontier rows in which some row
+    holds an id: ``(order, n)``, every chunk's index with the ``n`` live
+    ones first.  The last chunk of a width that is no multiple of the
+    chunk is judged by its own rows alone."""
+    n_chunks = -(-seeds.shape[0] // CHUNK_ROWS)
+    live = jnp.pad(seeds >= 0, (0, n_chunks * CHUNK_ROWS - seeds.shape[0])
+                   ).reshape(n_chunks, CHUNK_ROWS).any(axis=1)
+    return (jnp.argsort(~live, stable=True).astype(jnp.int32),
+            jnp.sum(live.astype(jnp.int32)))
+
+
+def _read_live_chunks(order, n, width: int, per_row: int, read, fill):
+    """``read(offset)`` over the ``n`` live chunks, written into ``[width
+    * per_row]`` buffers pre-filled with ``fill``: ``read`` takes the
+    first element of a chunk and returns one ``[CHUNK_ROWS * per_row]``
+    block for each buffer.  The trip count is the traced ``n``.  A last
+    chunk that would run past the width starts ``CHUNK_ROWS`` rows before
+    its end instead (what ``dynamic_slice`` does to such a start anyway):
+    the rows it shares with the chunk before are read to the same values.
+    """
+    last = (width - CHUNK_ROWS) * per_row
+
+    def body(i, bufs):
+        off = jnp.minimum(order[i] * (CHUNK_ROWS * per_row), last)
+        return tuple(lax.dynamic_update_slice_in_dim(b, v, off, 0)
+                     for b, v in zip(bufs, read(off)))
+
+    return lax.fori_loop(0, n, body, fill)
+
+
+def read_rows(seeds: jnp.ndarray) -> jnp.ndarray:
+    """Frontier rows whose random reads :func:`sample_neighbors` issues
+    for ``seeds`` (its XLA arm): the live chunks' rows, and the whole
+    width where it is at most one chunk and no loop exists.  What the
+    counter ``glt.sample.read_rows{hop}`` counts."""
+    width = seeds.shape[0]
+    if width <= CHUNK_ROWS:
+        return jnp.full((), width, jnp.int32)
+    return _live_chunks(seeds)[1] * CHUNK_ROWS
 
 
 def _draw_positions(deg: jnp.ndarray, fanout: int, key: jax.Array,
@@ -220,17 +295,47 @@ def sample_neighbors(
                 with_replacement=with_replacement, with_edge=with_edge,
                 params=params, interpret=(force == "interpret"),
                 key_by=key_by)
-    start, deg = _row_offsets_and_degrees(indptr, seeds)
+    width = seeds.shape[0]
+    chunked = width > CHUNK_ROWS
+    if chunked:
+        order, n = _live_chunks(seeds)
+        start, deg = _read_live_chunks(
+            order, n, width, 1,
+            lambda off: _row_offsets_and_degrees(
+                indptr, lax.dynamic_slice_in_dim(seeds, off, CHUNK_ROWS)),
+            (jnp.zeros((width,), indptr.dtype),
+             jnp.zeros((width,), jnp.int32)))
+    else:
+        start, deg = _row_offsets_and_degrees(indptr, seeds)
     pos, mask = draw_positions(deg, fanout, key, with_replacement, seeds,
                                key_by=key_by)
     flat = start[:, None] + jnp.where(mask, pos, 0)
-    nbrs = jnp.where(mask, indices[flat], PADDING_ID).astype(jnp.int32)
+    tables = ((indices, edge_ids) if with_edge and edge_ids is not None
+              else (indices,))
+    if chunked:
+        flat_1d = flat.reshape(-1)
+
+        def read(off):
+            at = lax.dynamic_slice_in_dim(flat_1d, off, CHUNK_ROWS * fanout)
+            return tuple(t[at] for t in tables)
+
+        blocks = _read_live_chunks(
+            order, n, width, fanout, read,
+            tuple(jnp.full((width * fanout,), PADDING_ID, t.dtype)
+                  for t in tables))
+
+        def gathered(k):
+            return blocks[k].reshape(width, fanout)
+    else:
+        def gathered(k):
+            return tables[k][flat]
+    nbrs = jnp.where(mask, gathered(0), PADDING_ID).astype(jnp.int32)
     if not with_edge:
         eids = None
     elif edge_ids is None:
         eids = jnp.where(mask, flat, PADDING_ID).astype(jnp.int32)
     else:
-        eids = jnp.where(mask, edge_ids[flat], PADDING_ID).astype(jnp.int32)
+        eids = jnp.where(mask, gathered(1), PADDING_ID).astype(jnp.int32)
     return NeighborOutput(nbrs=nbrs, eids=eids, mask=mask)
 
 

@@ -147,7 +147,7 @@ class DistHeteroNeighborSampler:
                       else bounded_remote_cap(frontier.shape[0],
                                               self.exchange_load_factor,
                                               g.num_shards))
-        nbrs, eids, mask, dropped = exchange_one_hop(
+        nbrs, eids, mask, dropped, _ = exchange_one_hop(
             frontier, indptr, indices, edge_ids, g.nodes_per_shard,
             g.num_shards, fanout, key, self.axis_name,
             remote_cap=remote_cap, route=self.route, fused=self.fused,

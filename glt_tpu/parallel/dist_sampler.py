@@ -34,7 +34,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..obs import metrics as _metrics
 from ..obs.scopes import scoped
 from ..obs.trace import span as _span
-from ..ops.neighbor_sample import _row_offsets_and_degrees, sample_neighbors
+from ..ops.neighbor_sample import (_row_offsets_and_degrees, read_rows,
+                                   sample_neighbors)
 from ..ops.unique import (
     dense_map_fits,
     induce,
@@ -750,9 +751,14 @@ def exchange_one_hop(
         and nothing else.
 
     Returns:
-      ``(nbrs, eids, mask, dropped)``; first three ``[B, fanout]`` in seed
-      order, ``dropped`` a scalar int32 (always 0 when ``remote_cap`` is
-      None and the hier DCN buffer is lossless).
+      ``(nbrs, eids, mask, dropped, rows_read)``; first three ``[B,
+      fanout]`` in seed order, ``dropped`` a scalar int32 (always 0 when
+      ``remote_cap`` is None and the hier DCN buffer is lossless),
+      ``rows_read`` a scalar int32: the rows whose random reads THIS
+      shard's neighbour reads issued, for whoever asked
+      (:func:`~glt_tpu.ops.neighbor_sample.read_rows` of the served
+      request matrix, and of the local split's frontier where there is
+      one).
     """
     b = seeds.shape[0]
     my_rank = lax.axis_index(axis_name)
@@ -770,6 +776,7 @@ def exchange_one_hop(
     # historical per-slot stream.
     key_by = "slot" if isinstance(axis_name, str) else "id"
 
+    rows_read = jnp.zeros((), jnp.int32)
     if remote_cap is None:
         cap = b
         local_nbrs = local_eids = None
@@ -791,6 +798,7 @@ def exchange_one_hop(
                 is_local, seeds - my_rank * nodes_per_shard, -1)
             lout = sample_neighbors(indptr, indices, local_ids, fanout,
                                     key, edge_ids=edge_ids, key_by=key_by)
+            rows_read = read_rows(local_ids)
         local_nbrs, local_eids = lout.nbrs, lout.eids
         remote_ids = jnp.where(is_local, PADDING_ID, seeds)
         if hier:
@@ -824,6 +832,7 @@ def exchange_one_hop(
         out = sample_neighbors(indptr, indices, local, fanout,
                                jax.random.fold_in(key, 1),
                                edge_ids=edge_ids, key_by=key_by)
+        rows_read = rows_read + read_rows(local)
 
     # Response exchange + unscatter (the stitch, stitch_sample_results.cu:57).
     fuse = _use_fused(fused)
@@ -864,7 +873,7 @@ def exchange_one_hop(
             eids = jnp.where(sel, local_eids, eids)
     dropped = (flat_plan.dropped + plan.hier_dropped if hier
                else plan.dropped)
-    return nbrs, eids, nbrs >= 0, dropped
+    return nbrs, eids, nbrs >= 0, dropped, rows_read
 
 
 def exchange_one_hop_ring(
@@ -902,19 +911,22 @@ def exchange_one_hop_ring(
     :func:`exchange_one_hop` and ignored (on a 2-D mesh the ring rotates
     the combined axis; draws keep the 2-D per-id keying so it stays
     comparable with the all-to-all paths).  ``hop`` names the device
-    scope of the local neighbour read, as in :func:`exchange_one_hop`.
+    scope of the local neighbour read, and the fifth result counts the
+    rows this shard's reads issued, as in :func:`exchange_one_hop`.
     """
     del mesh_shape, hier_load_factor  # flat-only transport
     b = seeds.shape[0]
     my = lax.axis_index(axis_name)
     owner = jnp.where(seeds >= 0, seeds // nodes_per_shard, -1)
     key_by = "slot" if isinstance(axis_name, str) else "id"
+    rows_read = []
 
     def local_sample(ids, k):
         with jax.named_scope(f"glt.sample.hop{hop}"):
             local = jnp.where(ids >= 0, ids - my * nodes_per_shard, -1)
             local = jnp.where((local >= 0) & (local < nodes_per_shard),
                               local, -1)
+            rows_read.append(read_rows(local))
             return sample_neighbors(indptr, indices, local, fanout,
                                     jax.random.fold_in(key, k),
                                     edge_ids=edge_ids, key_by=key_by)
@@ -998,18 +1010,20 @@ def exchange_one_hop_ring(
             sel = is_local[:, None]
             nbrs = jnp.where(sel, local_nbrs, nbrs)
             eids = jnp.where(sel, local_eids, eids)
-    return nbrs, eids, nbrs >= 0, routing.dropped
+    return nbrs, eids, nbrs >= 0, routing.dropped, sum(rows_read)
 
 
 def dist_live_counters(batch_size: int, num_neighbors: Sequence[int],
                        num_shards: int, frontier_cap: Optional[int] = None,
                        exact: bool = True):
     """Where one shard's ``live_counts`` of
-    :func:`dist_sample_multi_hop` are counted.  The frontier and edge
-    slots are what the shard's reads PROCESS, not the width it asks
-    with: under the exact flat exchange (``exact``: no
-    ``exchange_load_factor``, no hierarchical plan) every shard serves
-    ``S`` requesters' whole frontiers, ``S x width`` rows a hop.  Any
+    :func:`dist_sample_multi_hop` are counted.  ``read_rows{hop}`` is what
+    the shard's own reads issued, whatever the exchange (its fifth
+    result).  The frontier and edge slots are what the shard's reads
+    PROCESS, not the width it asks with: under the exact flat exchange
+    (``exact``: no ``exchange_load_factor``, no hierarchical plan) every
+    shard serves ``S`` requesters' whole frontiers, ``S x width`` rows a
+    hop.  Any
     other exchange serves a matrix of its own shape that nothing here
     re-derives: its slot counters stay where they are, so a share over
     them reads nothing until the change that runs that exchange counts
@@ -1105,7 +1119,7 @@ def dist_sample_multi_hop(
 
     rows, cols, eids_out, emasks = [], [], [], []
     counts_per_hop = [count]
-    edges_per_hop = []
+    edges_per_hop, rows_read = [], []
     keys = jax.random.split(key, len(fanouts))
     leaf_off = cap - widths[-1] * fanouts[-1]
     leaf_mask = None
@@ -1130,13 +1144,14 @@ def dist_sample_multi_hop(
         else:
             hop_routing = build_routing(frontier, nodes_per_shard,
                                         num_shards, route=route)
-        nbrs, eids, mask, dropped = exchange(
+        nbrs, eids, mask, dropped, read = exchange(
             frontier, indptr, indices, edge_ids, nodes_per_shard,
             num_shards, f, keys[i], axis_name, remote_cap=remote_cap,
             route=route, fused=fused, routing=hop_routing,
             mesh_shape=mesh_shape, hier_load_factor=hier_load_factor,
             hop=i + 1)
         dropped_total = dropped_total + dropped
+        rows_read.append(read)
 
         src_local = frontier_start + jnp.arange(w, dtype=jnp.int32)
         src_local = jnp.where(frontier >= 0, src_local, PADDING_ID)
@@ -1218,7 +1233,7 @@ def dist_sample_multi_hop(
                   and hier_load_factor is None
                   else {"exchange_dropped": dropped_total}),
         live_counts=live_counts(num_sampled_nodes, num_sampled_edges,
-                                widths, cap),
+                                widths, cap, rows_read),
     )
 
 

@@ -133,8 +133,11 @@ def live_counters(frontier_slots: Sequence[int], edge_slots: Sequence[int],
     """The ``glt.sample.*`` counters of a sampler with these static sizes
     (docs/observability.md).  Live, in the order of a batch's
     ``live_counts``: ``frontier_nodes{hop=k}`` (frontier rows of hop ``k``
-    that hold a node), ``edges{hop=k}`` (sampled edges of hop ``k``),
-    ``nodes`` (valid rows of the node buffer).  Static, once a batch:
+    that hold a node), ``read_rows{hop=k}`` (frontier rows whose random
+    reads hop ``k`` issued: its live chunks' rows, the static width where
+    the read is one chunk; :func:`~glt_tpu.ops.neighbor_sample.read_rows`),
+    ``edges{hop=k}`` (sampled edges of hop ``k``), ``nodes`` (valid rows
+    of the node buffer).  Static, once a batch:
     ``frontier_slots{hop=k}`` and ``edge_slots{hop=k}`` (the rows and the
     edge slots hop ``k``'s neighbour read PROCESSES, whoever asked for
     them; ``None`` where the caller cannot say: that counter is not
@@ -147,6 +150,8 @@ def live_counters(frontier_slots: Sequence[int], edge_slots: Sequence[int],
 
     live = (per_hop("frontier_nodes", "frontier rows of the hop that held "
                     "a node, over sampled batches")
+            + per_hop("read_rows", "frontier rows whose neighbour reads "
+                      "the hop issued, over sampled batches")
             + per_hop("edges", "edges the hop sampled, over sampled batches")
             + [_metrics.counter("glt.sample.nodes", "valid rows of the "
                                 "node buffer, over sampled batches")])
@@ -165,15 +170,18 @@ def live_counters(frontier_slots: Sequence[int], edge_slots: Sequence[int],
 
 
 def live_counts(num_sampled_nodes, num_sampled_edges,
-                frontier_widths: Sequence[int], node_capacity: int):
+                frontier_widths: Sequence[int], node_capacity: int,
+                read_rows: Sequence[jnp.ndarray]):
     """A batch's ``live_counts`` from what a homogeneous sampler already
     counts: the frontier of hop ``k`` is the nodes first seen at hop
     ``k - 1`` as far as its static width holds them, and the node buffer
-    holds every node seen as far as its capacity does."""
+    holds every node seen as far as its capacity does.  ``read_rows`` is
+    each hop's own count of the rows it read."""
     frontier = jnp.minimum(num_sampled_nodes[:-1],
                            jnp.asarray(frontier_widths, jnp.int32))
     nodes = jnp.minimum(jnp.sum(num_sampled_nodes), node_capacity)
-    return jnp.concatenate([frontier, num_sampled_edges,
+    return jnp.concatenate([frontier, jnp.stack(read_rows),
+                            num_sampled_edges,
                             nodes[None]]).astype(jnp.int32)
 
 
@@ -194,10 +202,10 @@ class SamplerOutput:
     * ``batch``: ``[batch_size]`` the seed ids this batch was sampled for.
     * ``num_sampled_nodes`` / ``num_sampled_edges``: per-hop valid counts
       (device int32 vectors, lengths num_hops+1 / num_hops).
-    * ``live_counts``: ``[2 * num_hops + 1]`` int32, the useful work of
+    * ``live_counts``: ``[3 * num_hops + 1]`` int32, the useful work of
       the batch in the order of :func:`live_counters`: frontier slots
-      that held a node at each hop, sampled edges of each hop, valid
-      rows of ``node``.
+      that held a node at each hop, frontier rows each hop read, sampled
+      edges of each hop, valid rows of ``node``.
     * ``metadata``: dict of extra arrays (edge_label_index, labels, ...).
 
     Leaf-block layout caveat: with ``last_hop_dedup=False`` (see

@@ -20,7 +20,7 @@ import numpy as np
 
 from ..data.graph import Graph
 from ..ops.negative_sample import sample_negative_edges, weighted_draw
-from ..ops.neighbor_sample import sample_neighbors
+from ..ops.neighbor_sample import read_rows, sample_neighbors
 from ..ops.unique import (
     dense_map_fits,
     induce,
@@ -313,7 +313,8 @@ class HeteroNeighborSampler(BaseSampler):
         (:func:`~glt_tpu.sampler.base.live_counters`), summed over the
         hop's neighbour reads, one a relation: ``frontier_slots{hop}``
         counts a source type's frontier once for every relation read
-        from it, as ``frontier_nodes{hop}`` counts its live rows."""
+        from it, as ``frontier_nodes{hop}`` counts its live rows and
+        ``read_rows{hop}`` the rows each of those reads issued."""
         reads = [[(widths[hop][et[0]], self.num_neighbors[et][hop])
                   for et in self.edge_types
                   if hop < len(self.num_neighbors[et])
@@ -384,7 +385,7 @@ class HeteroNeighborSampler(BaseSampler):
         # Live rows of each type's current frontier, and the hop's sums
         # over its neighbour reads (``live_counters``).
         frontier_live = dict(frontier_start)
-        frontier_nodes, hop_edges = [], []
+        frontier_nodes, rows_read, hop_edges = [], [], []
 
         for t0, seeds in seeds_dict.items():
             if t0 in dense_state:
@@ -442,6 +443,9 @@ class HeteroNeighborSampler(BaseSampler):
                 hop_out[et] = (out, src_local, w, f)
             frontier_nodes.append(sum(
                 (frontier_live[et[0]] for et in hop_out),
+                jnp.zeros((), jnp.int32)))
+            rows_read.append(sum(
+                (read_rows(frontier[et[0]]) for et in hop_out),
                 jnp.zeros((), jnp.int32)))
 
             # 2) per dst type: merge all candidates into the unique buffer
@@ -580,7 +584,7 @@ class HeteroNeighborSampler(BaseSampler):
             input_type=self.input_type,
             metadata={"overflow": overflow} if self.capped else None,
             live_counts=jnp.stack(
-                frontier_nodes + hop_edges
+                frontier_nodes + rows_read + hop_edges
                 + [sum(count.values(), jnp.zeros((), jnp.int32))]),
         )
         return out

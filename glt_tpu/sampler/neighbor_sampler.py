@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data.graph import Graph
-from ..ops.neighbor_sample import sample_neighbors
+from ..ops.neighbor_sample import read_rows, sample_neighbors
 from ..ops.negative_sample import sample_negative_edges, weighted_draw
 from ..ops.subgraph import node_subgraph
 from ..ops.unique import (
@@ -451,7 +451,7 @@ class NeighborSampler(BaseSampler):
 
         rows, cols, eids, emasks = [], [], [], []
         counts_per_hop = [count]
-        edges_per_hop = []
+        edges_per_hop, rows_read = [], []
         keys = jax.random.split(key, len(fanouts))
         # Static interior capacity: where the no-dedup leaf block starts.
         leaf_off = cap - widths[-1] * fanouts[-1]
@@ -472,6 +472,7 @@ class NeighborSampler(BaseSampler):
                                        keys[i], edge_ids=edge_ids,
                                        with_edge=self.with_edge,
                                        force=self.sample_force)
+            rows_read.append(read_rows(frontier))
             # Seed-side local indices (position of frontier nodes in node_buf).
             src_local = frontier_start + jnp.arange(w, dtype=jnp.int32)
             src_local = jnp.where(frontier >= 0, src_local, PADDING_ID)
@@ -601,7 +602,7 @@ class NeighborSampler(BaseSampler):
             num_sampled_edges=num_sampled_edges,
             metadata=metadata,
             live_counts=live_counts(num_sampled_nodes, num_sampled_edges,
-                                    widths, cap),
+                                    widths, cap, rows_read),
         )
 
     # -- public API (cf. sampler/neighbor_sampler.py:138) ------------------

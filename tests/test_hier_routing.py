@@ -245,7 +245,7 @@ def test_exchange_one_hop_flat_hier_bit_identity(num_hosts, remote_cap):
     def run(route):
         def body(ip, ix, ei, s):
             k = jax.random.fold_in(key, lax.axis_index(axis_name))
-            nbrs, eids, mask, dropped = exchange_one_hop(
+            nbrs, eids, mask, dropped, _ = exchange_one_hop(
                 s, ip, ix, ei, g.nodes_per_shard, g.num_shards, 3, k,
                 axis_name, remote_cap=remote_cap, route=route,
                 mesh_shape=ms)

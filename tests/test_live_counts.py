@@ -12,6 +12,7 @@ import optax
 import pytest
 
 from glt_tpu.obs import compilewatch, metrics
+from glt_tpu.ops import neighbor_sample
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -30,6 +31,17 @@ def registry():
     finally:
         metrics.disable()
         metrics.reset()
+
+
+@pytest.fixture(params=[None, 16], ids=["one-chunk", "chunks-of-16"])
+def chunk(request, monkeypatch):
+    """The hop's read as every tiny shape compiles it (at most one chunk:
+    no loop, ``read_rows`` the static width) and with the module's chunk
+    cut to 16 rows, so that the same shapes run the loop over their live
+    chunks.  The constant is read when a program is traced."""
+    if request.param:
+        monkeypatch.setattr(neighbor_sample, "CHUNK_ROWS", request.param)
+    return request.param
 
 
 # -- (a) the carrier ---------------------------------------------------------
@@ -160,9 +172,25 @@ def _sample_counters(snap):
             if k.startswith("glt.sample.") and "induce_sorted" not in k}
 
 
-def _expected(batches, edge_bounds, widths, node_slots, frontier_slots=None):
+def _rows_read(live, chunk):
+    """numpy's word for ``ops.neighbor_sample.read_rows``: the rows of
+    the chunks of ``chunk`` frontier rows in which ``live`` (a bool a
+    row) is set anywhere; the width where it is at most one chunk."""
+    w = live.shape[0]
+    if chunk is None or w <= chunk:
+        return w
+    n = -(-w // chunk)
+    padded = np.zeros(n * chunk, bool)
+    padded[:w] = live
+    return int(padded.reshape(n, chunk).any(axis=1).sum()) * chunk
+
+
+def _expected(batches, edge_bounds, widths, node_slots, frontier_slots=None,
+              chunk=None):
     """The ``glt.sample.*`` counters after ``batches`` (dicts of numpy
-    ``edge_mask``, ``num_sampled_nodes``, ``node_mask``)."""
+    ``edge_mask``, ``num_sampled_nodes``, ``node_mask`` and, where the
+    rows a hop's read sees are no prefix of its own frontier,
+    ``frontier_live``: a bool a row, a hop)."""
     hops = len(edge_bounds) - 1
     frontier_slots = frontier_slots or widths
     n = len(batches)
@@ -181,6 +209,11 @@ def _expected(batches, edge_bounds, widths, node_slots, frontier_slots=None):
             n * frontier_slots[k - 1]
         want[f"glt.sample.edge_slots{{hop={k}}}"] = \
             n * frontier_slots[k - 1] * fanout
+        want[f"glt.sample.read_rows{{hop={k}}}"] = sum(
+            _rows_read(b["frontier_live"][k - 1] if "frontier_live" in b
+                       else np.arange(widths[k - 1])
+                       < b["num_sampled_nodes"][k - 1], chunk)
+            for b in batches)
     return want
 
 
@@ -221,7 +254,7 @@ def _node_step(cfg, d, sampler):
     return step, state
 
 
-def test_scanned_node_step_counts_its_batches(tiny, registry):
+def test_scanned_node_step_counts_its_batches(tiny, registry, chunk):
     cfg, d = tiny
     sampler = _node_sampler(cfg, d)
     step, state = _node_step(cfg, d, sampler)
@@ -242,12 +275,19 @@ def test_scanned_node_step_counts_its_batches(tiny, registry):
     hb = sampler.hop_bounds
     got = _sample_counters(registry.snapshot())
     assert got == _expected(batches, hb.edge_bounds, sampler._widths,
-                            sampler.node_capacity)
+                            sampler.node_capacity, chunk=chunk)
     assert 0 < got["glt.sample.edges{hop=3}"] \
         < got["glt.sample.edge_slots{hop=3}"]
+    # what is still read beside what holds a node: the chunk's granularity
+    nodes, read, slots = (got[f"glt.sample.{name}{{hop=3}}"] for name in
+                          ("frontier_nodes", "read_rows", "frontier_slots"))
+    if chunk:
+        assert nodes <= read < min(slots, nodes + chunk * len(batches))
+    else:
+        assert read == slots
 
 
-def test_node_loader_counts_its_batches(tiny, registry):
+def test_node_loader_counts_its_batches(tiny, registry, chunk):
     from glt_tpu.loader import NeighborLoader
 
     cfg, d = tiny
@@ -261,7 +301,8 @@ def test_node_loader_counts_its_batches(tiny, registry):
     assert len(batches) == 5
     got = _sample_counters(registry.snapshot())
     hb = sampler.hop_bounds
-    frontier = {k: v for k, v in got.items() if "frontier_nodes" in k}
+    frontier = {k: v for k, v in got.items()
+                if "frontier_nodes" in k or "read_rows" in k}
     # A loader's Batch carries no per-hop node counts: the frontier of
     # hop k holds the rows first seen at hop k - 1, which are the rows
     # the valid edges of hop block k point from.
@@ -277,6 +318,14 @@ def test_node_loader_counts_its_batches(tiny, registry):
     assert all(0 < frontier[f"glt.sample.frontier_nodes{{hop={k}}}"]
                <= want[f"glt.sample.frontier_slots{{hop={k}}}"]
                for k in (2, 3))
+    for k in (1, 2, 3):                 # a prefix a batch: under a chunk over
+        nodes, read = (frontier[f"glt.sample.{name}{{hop={k}}}"]
+                       for name in ("frontier_nodes", "read_rows"))
+        if chunk:
+            assert nodes <= read < nodes + chunk * len(batches)
+            assert read % chunk == 0
+        else:
+            assert read == want[f"glt.sample.frontier_slots{{hop={k}}}"]
 
 
 def _link_world():
@@ -297,7 +346,7 @@ def _link_world():
     return cfg, d, sampler, neg, np.stack([src, topo.indices[pos]])
 
 
-def test_scanned_link_step_counts_the_seed_unions_chain(registry):
+def test_scanned_link_step_counts_the_seed_unions_chain(registry, chunk):
     from chipbench.drivers.link_scan_train import make_model
     from glt_tpu.models import (init_train_state, link_seed_blocks,
                                 make_scanned_link_train_step,
@@ -327,7 +376,7 @@ def test_scanned_link_step_counts_the_seed_unions_chain(registry):
     snap = registry.snapshot()
     assert _sample_counters(snap) == _expected(
         batches, union.hop_bounds.edge_bounds, union.widths,
-        union.node_capacity)
+        union.node_capacity, chunk=chunk)
     # (c) the flag columns ride the same carrier, counted once: what the
     # parent summed from the flags it fetched.
     flags = np.concatenate(flags)
@@ -382,7 +431,8 @@ def test_link_padded_slots_are_counted_once(registry):
     assert registry.snapshot()["glt.link.neg_padded_slots"] == 2 * total
 
 
-def test_scanned_typed_step_counts_sums_over_types_and_relations(registry):
+def test_scanned_typed_step_counts_sums_over_types_and_relations(registry,
+                                                                  chunk):
     from chipbench import data_hetero
     from glt_tpu.models import (init_hetero_state,
                                 make_scanned_hetero_train_step,
@@ -422,7 +472,7 @@ def test_scanned_typed_step_counts_sums_over_types_and_relations(registry):
                                     for o in outs
                                     for m in o.node_mask.values())}
     for k in range(1, hops + 1):
-        edges = slots = rows = live = 0
+        edges = slots = rows = live = read = 0
         for rel, bounds in hb.edge_bounds.items():
             lo, hi = bounds[k - 1], bounds[k]
             if hi == lo:
@@ -436,10 +486,14 @@ def test_scanned_typed_step_counts_sums_over_types_and_relations(registry):
                     int(np.asarray(o.edge_mask[rel])[lo:hi].sum())
                 new = np.asarray(o.num_sampled_nodes[src_type])
                 live += int(min(new[k - 1], widths[k - 1][src_type]))
+                read += _rows_read(np.arange(widths[k - 1][src_type])
+                                   < new[k - 1], chunk)
         want[f"glt.sample.edges{{hop={k}}}"] = edges
         want[f"glt.sample.edge_slots{{hop={k}}}"] = g * slots
         want[f"glt.sample.frontier_slots{{hop={k}}}"] = g * rows
         want[f"glt.sample.frontier_nodes{{hop={k}}}"] = live
+        want[f"glt.sample.read_rows{{hop={k}}}"] = read
+        assert (live <= read <= g * rows) if chunk else read == g * rows
     assert _sample_counters(snap) == want
     assert snap["glt.hetero.overflowed_batches"] == np.asarray(flags).sum()
     registry.reset()
@@ -449,7 +503,7 @@ def test_scanned_typed_step_counts_sums_over_types_and_relations(registry):
 
 
 @pytest.mark.parametrize("scanned", [False, True])
-def test_dist_step_counts_every_shards_batch(registry, scanned):
+def test_dist_step_counts_every_shards_batch(registry, scanned, chunk):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
@@ -476,12 +530,32 @@ def test_dist_step_counts_every_shards_batch(registry, scanned):
         out = dist_sample_multi_hop(indptr[0], indices[0], eids[0],
                                     seeds[0], key, fanout, c, s, "shard")
         return tuple(a[None] for a in (out.edge_mask, out.node_mask,
-                                       out.num_sampled_nodes))
+                                       out.num_sampled_nodes, out.node))
 
     sp = P("shard")
     probe = jax.jit(jax.shard_map(local, mesh=d.mesh,
                                   in_specs=(sp,) * 4 + (P(),),
-                                  out_specs=(sp,) * 3, check_vma=False))
+                                  out_specs=(sp,) * 4, check_vma=False))
+    widths = hop_widths(b, fanout)
+
+    def served(nsn, node):
+        """What each shard's read of hop k sees: one row a requester of
+        the request matrix, the ids that requester's frontier (the nodes
+        it first saw a hop earlier) holds of this owner leading it."""
+        nsn, node = np.asarray(nsn), np.asarray(node)
+        first = np.concatenate([np.zeros((s, 1), int),
+                                np.cumsum(nsn, axis=1)], axis=1)
+        live = [[] for _ in range(s)]
+        for k, w in enumerate(widths):
+            asked = np.zeros((s, s), int)          # [requester, owner]
+            for p_ in range(s):
+                ids = node[p_, first[p_, k]: first[p_, k]
+                           + min(nsn[p_, k], w)]
+                asked[p_] = np.bincount(ids // c, minlength=s)
+            for q in range(s):
+                live[q].append((np.arange(w)[None, :]
+                                < asked[:, q, None]).reshape(-1))
+        return live
     batches, n_steps, g = [], 2, 2
     for it in range(n_steps):
         key = jax.random.PRNGKey(50 + it)
@@ -497,20 +571,26 @@ def test_dist_step_counts_every_shards_batch(registry, scanned):
         state = out[0]
         assert len(out) == 3
         for sd, k in zip(seeds, keys):
-            em, nm, nsn = probe(d.graph.indptr, d.graph.indices,
-                                d.graph.edge_ids, jnp.asarray(sd), k)
+            em, nm, nsn, node = probe(d.graph.indptr, d.graph.indices,
+                                      d.graph.edge_ids, jnp.asarray(sd), k)
+            live = served(nsn, node)
             batches += [{"edge_mask": np.asarray(em[i]),
                          "node_mask": np.asarray(nm[i]),
-                         "num_sampled_nodes": np.asarray(nsn[i])}
+                         "num_sampled_nodes": np.asarray(nsn[i]),
+                         "frontier_live": live[i]}
                         for i in range(s)]
-    widths = hop_widths(b, fanout)
     hb = hop_bounds(b, fanout)
     got = _sample_counters(registry.snapshot())
     # the slots are what each shard's read serves: S requesters' widths
     assert got == _expected(batches, hb.edge_bounds, widths,
                             hb.node_bounds[-1],
-                            frontier_slots=[s * w for w in widths])
+                            frontier_slots=[s * w for w in widths],
+                            chunk=chunk)
     assert got["glt.sample.batches"] == n_steps * s * (g if scanned else 1)
+    if chunk:       # four prefixes a shard's read, and most of it skipped
+        assert got["glt.sample.frontier_nodes{hop=3}"] \
+            <= got["glt.sample.read_rows{hop=3}"] \
+            < got["glt.sample.frontier_slots{hop=3}"] / 2
 
 
 def test_a_bounded_exchange_counts_live_work_and_no_read_slots(registry):
@@ -542,6 +622,11 @@ def test_a_bounded_exchange_counts_live_work_and_no_read_slots(registry):
     assert not any(v for k, v in got.items()
                    if k.startswith(("glt.sample.frontier_slots",
                                     "glt.sample.edge_slots")))
+    # ... and what its own reads issued (the local split's, the served
+    # matrix's), which needs no shape derived here
+    assert all(got[f"glt.sample.read_rows{{hop={k}}}"]
+               >= got[f"glt.sample.frontier_nodes{{hop={k}}}"] > 0
+               for k in range(1, len(fanout) + 1))
 
 
 # -- (d) one compile, the same bits ------------------------------------------
@@ -596,8 +681,9 @@ def test_counts_change_no_bit_of_the_batch(tiny):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
     live = np.asarray(out.live_counts)
     hops = len(sampler.num_neighbors)
-    np.testing.assert_array_equal(live[hops: 2 * hops],
+    np.testing.assert_array_equal(live[2 * hops: 3 * hops],
                                   np.asarray(out.num_sampled_edges))
+    np.testing.assert_array_equal(live[hops: 2 * hops], sampler._widths)
     assert live[-1] == int(np.asarray(out.node_mask).sum())
     assert len(metrics._pending) == 0        # off: nothing was kept
 
