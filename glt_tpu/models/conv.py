@@ -170,6 +170,11 @@ class SAGEConv(nn.Module):
     (their static ``(width, fanout)``, ``HopBounds.blocks``): the mean
     is then :func:`block_mean`, a contiguous sum a block.  Without it
     ``edge_index`` is any COO and the mean is :func:`scatter_mean`.
+
+    ``x`` may be a pair ``(x_src, x_dst)``, PyG's bipartite form for a
+    relation between two node types: messages are gathered from
+    ``x_src`` (``edge_index[0]``), summed into the rows of ``x_dst``
+    (``edge_index[1]``), and ``lin_self`` runs on ``x_dst``.
     """
     out_features: int
     use_bias: bool = True
@@ -179,9 +184,10 @@ class SAGEConv(nn.Module):
     def __call__(self, x, edge_index, edge_mask,
                  num_dst: Optional[int] = None,
                  blocks: Optional[Sequence[Tuple[int, int]]] = None):
+        x, x_dst = x if isinstance(x, (tuple, list)) else (x, x)
         num_src = x.shape[0]
         if num_dst is None:
-            num_dst = num_src
+            num_dst = x_dst.shape[0]
         src, dst = edge_index[0], edge_index[1]
         if blocks is None:
             with jax.named_scope("glt.model.msg"):
@@ -192,7 +198,7 @@ class SAGEConv(nn.Module):
         dt = _mm_dtype(self.dtype)
         with jax.named_scope("glt.model.dense"):
             out = (nn.Dense(self.out_features, use_bias=self.use_bias,
-                            dtype=dt, name="lin_self")(x[:num_dst])
+                            dtype=dt, name="lin_self")(x_dst[:num_dst])
                    + nn.Dense(self.out_features, use_bias=False,
                               dtype=dt, name="lin_nbr")(agg))
             return out if dt is None else out.astype(jnp.float32)

@@ -70,17 +70,32 @@ def pair_bce_loss(z, meta):
     ``meta['edge_label_index']`` (rows of ``z``) whose ``edge_label`` is
     not padding.  Returns ``(loss, acc)``, ``acc`` the share of pairs
     whose logit has the label's sign."""
+    last = z.shape[0] - 1
+    return _pair_bce(meta, lambda eli: (z[jnp.clip(eli[0], 0, last)]
+                                        * z[jnp.clip(eli[1], 0, last)]
+                                        ).sum(-1))
+
+
+def _pair_bce(meta, logits_of):
+    """Masked ``binary_cross_entropy_with_logits(logits, edge_label)``
+    and the share of signs right, over the pairs that are not padding;
+    ``logits_of(edge_label_index)`` scores the pairs."""
     eli, label = meta["edge_label_index"], meta["edge_label"]
     valid = (eli[0] >= 0) & (eli[1] >= 0) & (label >= 0)
-    last = z.shape[0] - 1
-    logits = (z[jnp.clip(eli[0], 0, last)]
-              * z[jnp.clip(eli[1], 0, last)]).sum(-1)
+    logits = logits_of(eli)
     ce = optax.sigmoid_binary_cross_entropy(
         logits, (label > 0).astype(logits.dtype))
     n = jnp.maximum(valid.sum(), 1)
     loss = jnp.where(valid, ce, 0).sum() / n
     acc = jnp.where(valid, (logits > 0) == (label > 0), False).sum() / n
     return loss, acc
+
+
+@scoped("glt.step.loss")
+def logit_bce_loss(logits, meta):
+    """:func:`pair_bce_loss` for a model that scores its pairs itself (a
+    decoder over ``meta['edge_label_index']``): ``logits`` is ``[Q]``."""
+    return _pair_bce(meta, lambda eli: logits)
 
 
 def graph_inputs(out):
@@ -180,9 +195,22 @@ def gated_update(tx):
     dropout key — a padded trailing batch then equals the serial loop
     over the real batches only.  Inside a ``shard_map`` ``any_valid`` has
     to be the same on every shard.
+
+    A model with embedding tables (leaves named as
+    :data:`~glt_tpu.models.bipartite.TABLE`) has its update split by
+    leaf: the tables' under ``glt.embed.update``, the rest under
+    ``glt.step.update`` -- the same ``tx``, the same count and the same
+    arithmetic, applied to two halves of the tree
+    (:func:`_split_update`).  Without a table the program is the one it
+    always was.
     """
     def run(state: TrainState, grads, any_valid) -> TrainState:
         def apply(s):
+            tables = _table_leaves(s.params)
+            if any(tables):
+                params, opt_state = _split_update(tx, grads, s.opt_state,
+                                                  s.params, tables)
+                return TrainState(params, opt_state, s.step + 1)
             with jax.named_scope("glt.step.update"):
                 updates, opt_state = tx.update(grads, s.opt_state,
                                                s.params)
@@ -192,3 +220,50 @@ def gated_update(tx):
         return lax.cond(any_valid, apply, lambda s: s, state)
 
     return run
+
+
+def _table_leaves(params):
+    """Per leaf of ``params``, in flattening order: is it a table."""
+    from .bipartite import is_table
+
+    return [is_table(path) for path, _ in
+            jax.tree_util.tree_flatten_with_path(params)[0]]
+
+
+def _split_update(tx, grads, opt_state, params, tables):
+    """``tx``'s update applied to the leaves that are not tables (under
+    ``glt.step.update``) and to the tables (under ``glt.embed.update``),
+    each half a list of leaves.  Every node of ``opt_state`` shaped like
+    ``params`` (Adam's moments) is split the same way, every other node
+    (the count) is handed to both halves as it is; the halves' states are
+    joined back into ``opt_state``'s structure, the count taken from the
+    first (both advanced it alike)."""
+    pdef = jax.tree_util.tree_structure(params)
+
+    def like_params(node):
+        return jax.tree_util.tree_structure(node) == pdef
+
+    def half(tree, side):
+        return [leaf for leaf, t in zip(pdef.flatten_up_to(tree), tables)
+                if t == side]
+
+    def join(rest, tabs):
+        rest, tabs = iter(rest), iter(tabs)
+        return pdef.unflatten([next(tabs) if t else next(rest)
+                               for t in tables])
+
+    new_params, states = [], []
+    for side, scope in ((False, "glt.step.update"),
+                        (True, "glt.embed.update")):
+        state = jax.tree_util.tree_map(
+            lambda n: half(n, side) if like_params(n) else n, opt_state,
+            is_leaf=like_params)
+        with jax.named_scope(scope):
+            p = half(params, side)
+            updates, state = tx.update(half(grads, side), state, p)
+            new_params.append(optax.apply_updates(p, updates))
+        states.append(state)
+    opt_state = jax.tree_util.tree_map(
+        lambda n, a, b: join(a, b) if like_params(n) else a,
+        opt_state, *states, is_leaf=like_params)
+    return join(*new_params), opt_state

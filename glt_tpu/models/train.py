@@ -29,8 +29,8 @@ from ..obs import profiler as _profiler
 from ..obs.trace import span as _span
 from ..typing import PADDING_ID
 from .step import (TrainState, gated_update, graph_inputs,  # noqa: F401
-                   hop_trimming, loss_and_grads, pair_bce_loss,
-                   seed_cross_entropy, seed_loss)
+                   hop_trimming, logit_bce_loss, loss_and_grads,
+                   pair_bce_loss, seed_cross_entropy, seed_loss)
 
 # Epoch-driver instrumentation (docs/observability.md).  Only the HOST
 # loops are instrumented — the jitted step bodies must stay span-free
@@ -285,7 +285,7 @@ def _batch_flags(out):
 def _scanned_supervised(model, tx, loss, dropout_seed: int,
                         hops, batch_of, arrays_of, label: str, live,
                         feature_cache=None,
-                        flag_counters=(None,)):
+                        flag_counters=(None,), donate_state=False):
     """The wrapper of the scanned steps over sampled seed blocks: ONE
     jitted ``lax.scan`` of the body over ``seeds_blk [G, B]`` (seed
     edges: ``[G, 2, q]``), as ``step(state, seeds_blk, key) -> (state,
@@ -314,13 +314,17 @@ def _scanned_supervised(model, tx, loss, dropout_seed: int,
     the rest into ``live`` (the sampler's
     :class:`~glt_tpu.sampler.base.LiveCounters`) — with metrics off
     nothing of it is copied or read.
+
+    ``donate_state`` donates the ``TrainState`` too, for a state too
+    large to hold twice (a model with embedding tables): the caller's
+    ``state`` is then consumed by the call.
     """
     grads_of = loss_and_grads(model, loss, hops)
     columns = tuple(flag_counters) + live.counters
     flag_cols = len(flag_counters)
     update = gated_update(tx)
 
-    @partial(jax.jit, donate_argnums=(2,))
+    @partial(jax.jit, donate_argnums=(1, 2) if donate_state else (2,))
     def run(arrays, state: TrainState, cache, seeds_blk, key):
         def body(carry, inp):
             st, cache = carry
@@ -904,3 +908,83 @@ def link_seed_blocks(edge_index, batch_size: int, group: int, rng):
         blk = np.full((2, per_block), -1, np.int64)
         blk[:, : pos.shape[0]] = e[:, pos]
         yield blk.reshape(2, group, batch_size).transpose(1, 0, 2)
+
+
+
+def make_scanned_hetero_link_train_step(model, tx, sampler, edge_type,
+                                        neg_sampling):
+    """ONE jitted program trains ``G`` consecutive batches of typed seed
+    edges of relation ``edge_type`` with binary negatives: the strict
+    negative draw, the typed sample from the two-type seed union
+    (:meth:`HeteroNeighborSampler.edges_program`), the model's own
+    lookups of its node-embedding tables, forward, the pair loss
+    (:func:`~glt_tpu.models.step.logit_bce_loss` of the model's pair
+    logits), backward and the update, under ``lax.scan``
+    (:func:`_scanned_supervised`) -- upstream's
+    ``examples/hetero/bipartite_sage_unsup.py`` training loop.
+
+    The batch's ``x`` is ``(node ids {type: [N_t]} (-1 on padding),
+    pair index [2, 2q])``: ``model``
+    (:class:`~glt_tpu.models.bipartite.BipartiteSAGE`, or any model
+    with ``table_rows`` that takes such an ``x`` and returns pair
+    logits) reads its inputs out of its tables, whose dense gradient the
+    optimiser applies to every row (``glt.embed.update``).  The
+    ``TrainState`` is donated through the scan and across calls: the
+    caller's ``state`` is consumed.  The model runs whole (no hop
+    trimming).
+
+    Returns ``step(state, edges_blk [G, 2, q], key) -> (state, losses
+    [G], accs [G], flags [G, 2])``, the shape :func:`run_scanned_epoch`
+    drives; ``flags[:, 1]`` is the negative slots of the non-strict
+    padding pass (``glt.link.neg_padded_slots``; column 0 is 0: the
+    union runs under the exact clamp and never overflows).  Gauge
+    ``glt.embed.table_rows{type}`` holds each table's rows, counter
+    ``glt.embed.rows{type}`` each batch's looked-up rows.
+    """
+    from ..sampler.base import LiveCounters
+
+    if neg_sampling is None or neg_sampling.mode != "binary":
+        raise ValueError("the typed link step trains binary negatives")
+    amount = int(round(neg_sampling.amount))
+    cdf = neg_sampling.cdf()
+    impl, widths, cap = sampler.edges_program(
+        edge_type, "binary", amount, cdf is not None)
+    q = sampler.batch_size
+    types = sorted(model.table_rows)
+    for t, n in model.table_rows.items():
+        _metrics.gauge("glt.embed.table_rows", "rows of one node type's "
+                       "embedding table in the last step built over it",
+                       {"type": t}).set(n)
+    union = sampler.live_counters(widths, cap)
+    rows_read = tuple(_metrics.counter(
+        "glt.embed.rows", "rows of one node type that batches looked up "
+        "in its embedding table (a column of the step's deferred counts)",
+        {"type": t}) for t in types)
+    live = LiveCounters(rows_read + union.counters, union.per_row)
+    graph_arrays = {et: (g.indptr, g.indices, g.gather_edge_ids)
+                    for et, g in sampler.graphs.items()}
+    seed_graph = sampler.graphs[edge_type]
+
+    def batch_of(arrays, cache, edges, k):
+        graph_args, sorted_arg, cdf_arg = arrays
+        s, d = edges[0], edges[1]
+        out = impl(graph_args, sorted_arg, s, d, cdf_arg, k)
+        meta = dict(out.metadata)
+        meta["edge_label"] = jnp.concatenate(
+            [jnp.where(s >= 0, 1, PADDING_ID),
+             jnp.zeros((q * amount,), jnp.int32)])
+        rows = jnp.stack([jnp.sum(out.node_mask[t], dtype=jnp.int32)
+                          for t in types])
+        out = dataclasses.replace(
+            out, live_counts=jnp.concatenate([rows, out.live_counts]))
+        x = ({t: out.node[t] for t in types}, meta["edge_label_index"])
+        return cache, out, x, meta
+
+    def arrays_of():
+        return (graph_arrays, seed_graph.sorted_indices,
+                jnp.zeros((1,), jnp.float32) if cdf is None else cdf)
+
+    return _scanned_supervised(
+        model, tx, lambda z, meta, aux: logit_bce_loss(z, meta),
+        0, None, batch_of, arrays_of, "scanned_hetero_link_step", live,
+        flag_counters=(None, _M_LINK_PADDED), donate_state=True)

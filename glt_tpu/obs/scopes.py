@@ -26,6 +26,9 @@ call, and no flag turns them off.  The whole taxonomy:
 ``glt.gather.label``   label rows
 ``glt.gather.merge``   a tiered gather's bookkeeping and the placement of
                        the rows the host sent among the hot ones
+``glt.embed.lookup``   a learned node-embedding table's rows read by id
+                       (``models/bipartite.py::NodeEmbedding``), and the
+                       backward scatter of their gradient into the table
 ``glt.route.bucket``   owner bucketing of ids (``build_routing``)
 ``glt.route.payload``  assembling exchange payloads, un-permuting replies
 ``glt.route.exchange`` the ``all_to_all``/``ppermute`` calls themselves
@@ -36,7 +39,14 @@ call, and no flag turns them off.  The whole taxonomy:
 ``glt.model.dense``    matmuls, with their bias, activation and dropout
 ``glt.step.loss``      the loss
 ``glt.step.update``    optimiser update (and the gradient all-reduce)
+``glt.embed.update``   the same optimiser's update of the embedding tables'
+                       leaves (``models/step.py::gated_update``)
 =====================  ====================================================
+
+Beside them, two metrics of the tables (docs/observability.md): the gauge
+``glt.embed.table_rows{type}`` (a table's rows, set when a step over it
+is built) and the counter ``glt.embed.rows{type}`` (the rows a batch
+looked up, a column of the step's deferred counts).
 
 Where scopes nest, a reader takes the outermost ``glt.*`` name: the
 unique pass inside a dedup gather is gather work.

@@ -655,78 +655,99 @@ class HeteroNeighborSampler(BaseSampler):
     def _get_edges_jit(self, et, mode, amount, weighted: bool = False):
         k = (et, mode, amount, weighted)
         if k not in self._edges_jit:
-            src_t, _, dst_t = et
-            q = self.batch_size
+            self._edges_jit[k] = jax.jit(
+                self.edges_program(et, mode, amount, weighted)[0])
+        return self._edges_jit[k]
+
+    def edges_program(self, et, mode, amount: int, weighted: bool = False):
+        """``(impl, widths, capacity)`` of the seed-edge path of relation
+        ``et``: ``impl(graph_arrays, sorted_idx, src, dst, cdf, key) ->
+        HeteroSamplerOutput``, unjitted, so that a traced step can call
+        it (``make_scanned_hetero_link_train_step``), and the static
+        sizes it samples at.  ``graph_arrays`` is ``et -> (indptr,
+        indices, edge_ids)``; ``sorted_idx`` the seed relation's
+        column-sorted view (binary) or anything (otherwise).
+
+        The sample runs from the seed union (binary: ``[src, neg_src]``
+        of the source type and ``[dst, neg_dst]`` of the destination
+        type, ``q (1 + amount)`` slots each) at its own widths, under the
+        exact clamp: an occupancy capacity of the node path does not
+        transfer (another seed width, another occupancy).  Binary
+        negatives are drawn strict under ``glt.sample.negative`` and
+        ``metadata['neg_strict']`` says which slots passed a trial; the
+        pair index is found under ``glt.sample.relabel``."""
+        src_t, _, dst_t = et
+        q = self.batch_size
+        if mode == "binary":
+            sw, dw = q * (1 + amount), q * (1 + amount)
+        elif mode == "triplet":
+            sw, dw = q, q * (1 + amount)
+        else:
+            sw, dw = q, q
+        seed_widths = ({src_t: sw + dw} if src_t == dst_t
+                       else {src_t: sw, dst_t: dw})
+        widths, cap = hetero_hop_widths(
+            self.edge_types, self.num_neighbors, seed_widths,
+            self.num_hops, frontier_cap=self.frontier_cap,
+            num_nodes=self._num_nodes_by_type)
+
+        # Node counts are static: an edge type's CSR rows are its
+        # source type's nodes.
+        n_src = self.graphs[et].num_nodes
+        dst_rows = [e for e in self.edge_types if e[0] == dst_t]
+        if not dst_rows:
+            raise ValueError(
+                f"cannot size negatives: no edge type has source type "
+                f"{dst_t!r} (needed for its node count)")
+        n_dst = self.graphs[dst_rows[0]].num_nodes
+
+        def impl(graph_arrays, sorted_idx, src, dst, cdf, key):
+            kneg, ksample = jax.random.split(key)
+            dst_cdf = cdf if weighted else None
             if mode == "binary":
-                sw, dw = q * (1 + amount), q * (1 + amount)
-            elif mode == "triplet":
-                sw, dw = q, q * (1 + amount)
-            else:
-                sw, dw = q, q
-            seed_widths = ({src_t: sw + dw} if src_t == dst_t
-                           else {src_t: sw, dst_t: dw})
-            # The seed union runs at its own widths: the exact clamp
-            # holds, an occupancy capacity of the node path does not
-            # transfer (another seed width, another occupancy).
-            widths, cap = hetero_hop_widths(
-                self.edge_types, self.num_neighbors, seed_widths,
-                self.num_hops, frontier_cap=self.frontier_cap,
-                num_nodes=self._num_nodes_by_type)
-
-            # Node counts are static: an edge type's CSR rows are its
-            # source type's nodes.
-            n_src = self.graphs[et].num_nodes
-            dst_rows = [e for e in self.edge_types if e[0] == dst_t]
-            if not dst_rows:
-                raise ValueError(
-                    f"cannot size negatives: no edge type has source type "
-                    f"{dst_t!r} (needed for its node count)")
-            n_dst = self.graphs[dst_rows[0]].num_nodes
-
-            def impl(graph_arrays, sorted_idx, src, dst, cdf, key):
-                kneg, ksample = jax.random.split(key)
-                dst_cdf = cdf if weighted else None
-                if mode == "binary":
-                    # Strict rejection against the seed edge type's CSR
-                    # (sorted-column binary search), weighted dst draws
-                    # when NegativeSampling.weight is set.
-                    et_indptr = graph_arrays[et][0]
+                # Strict rejection against the seed edge type's CSR
+                # (sorted-column binary search), weighted dst draws
+                # when NegativeSampling.weight is set.
+                with jax.named_scope("glt.sample.negative"):
                     negs = sample_negative_edges(
-                        et_indptr, sorted_idx, q * amount, kneg, n_src,
-                        num_dst_nodes=n_dst, dst_cdf=dst_cdf)
-                    srcs = jnp.concatenate([src, negs.src])
-                    dsts = jnp.concatenate([dst, negs.dst])
-                elif mode == "triplet":
+                        graph_arrays[et][0], sorted_idx, q * amount, kneg,
+                        n_src, num_dst_nodes=n_dst, dst_cdf=dst_cdf)
+                srcs = jnp.concatenate([src, negs.src])
+                dsts = jnp.concatenate([dst, negs.dst])
+            elif mode == "triplet":
+                with jax.named_scope("glt.sample.negative"):
                     if weighted:
                         neg_dst = weighted_draw(kneg, cdf, (q * amount,))
                     else:
-                        neg_dst = jax.random.randint(kneg, (q * amount,), 0,
-                                                     n_dst, dtype=jnp.int32)
+                        neg_dst = jax.random.randint(
+                            kneg, (q * amount,), 0, n_dst, dtype=jnp.int32)
                     neg_dst = jnp.where(jnp.repeat(src >= 0, amount),
                                         neg_dst, PADDING_ID)
-                    srcs, dsts = src, jnp.concatenate([dst, neg_dst])
-                else:
-                    srcs, dsts = src, dst
+                srcs, dsts = src, jnp.concatenate([dst, neg_dst])
+            else:
+                srcs, dsts = src, dst
 
-                if src_t == dst_t:
-                    seeds_dict = {src_t: jnp.concatenate([srcs, dsts])}
-                else:
-                    seeds_dict = {src_t: srcs, dst_t: dsts}
-                out = self._sample_impl(widths, cap, graph_arrays,
-                                        seeds_dict, ksample)
-                # Seed ids first-occur within the hop-0 prefix of their
-                # type's node list; relabel against that slice only (the
-                # no-dedup leaf block may hold duplicate seed copies).
-                if src_t == dst_t:
-                    src_ref = dst_ref = out.node[src_t][: sw + dw]
-                else:
-                    src_ref = out.node[src_t][:sw]
-                    dst_ref = out.node[dst_t][:dw]
-                meta = {}
+            if src_t == dst_t:
+                seeds_dict = {src_t: jnp.concatenate([srcs, dsts])}
+            else:
+                seeds_dict = {src_t: srcs, dst_t: dsts}
+            out = self._sample_impl(widths, cap, graph_arrays, seeds_dict,
+                                    ksample)
+            # Seed ids first-occur within the hop-0 prefix of their
+            # type's node list; relabel against that slice only (the
+            # no-dedup leaf block may hold duplicate seed copies).
+            if src_t == dst_t:
+                src_ref = dst_ref = out.node[src_t][: sw + dw]
+            else:
+                src_ref = out.node[src_t][:sw]
+                dst_ref = out.node[dst_t][:dw]
+            meta = {}
+            with jax.named_scope("glt.sample.relabel"):
                 if mode == "binary":
                     meta["edge_label_index"] = jnp.stack([
                         relabel_by_reference(src_ref, srcs),
                         relabel_by_reference(dst_ref, dsts)])
+                    meta["neg_strict"] = negs.strict
                 elif mode == "triplet":
                     meta["src_index"] = relabel_by_reference(src_ref, src)
                     meta["dst_pos_index"] = relabel_by_reference(
@@ -737,8 +758,7 @@ class HeteroNeighborSampler(BaseSampler):
                     meta["edge_label_index"] = jnp.stack([
                         relabel_by_reference(src_ref, src),
                         relabel_by_reference(dst_ref, dst)])
-                out.metadata = meta
-                return out
+            out.metadata = meta
+            return out
 
-            self._edges_jit[k] = jax.jit(impl)
-        return self._edges_jit[k]
+        return impl, widths, cap
