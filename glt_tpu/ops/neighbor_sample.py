@@ -50,12 +50,12 @@ sorted by id or calibrated.  A skipped row reads as a padding row does
 the whole width**: it is elementwise, and ``jax.random`` defines the
 per-(key, slot) stream by the shape it is asked for, so a draw made a
 chunk at a time would be another stream; kept whole, ``nbrs``, ``eids``
-and ``mask`` are the whole read's bit for bit.  Whether the loop exists
-is a fact of the static shape: a frontier of at most one chunk lowers to
-the single fusion it always was.  On the chip a dead row costs more than
-a live one (every padding slot reads ``indices[0]``, and reads of one
-address serialise: 17.6 ns a slot with every row live, 20.2 at 10 %), so
-the skipped chunks give back more than their share of the rows.
+and ``mask`` are the whole read's bit for bit.  A frontier of at most one
+chunk lowers to the single fusion it always was.  On the chip a dead row
+costs more than a live one (padding reads ``indices[0]``, and reads of one
+address serialise), so skipping its chunks gives back more than its share.
+The dist step's served feature and label read runs the same two helpers
+over the request matrix it serves (``parallel/dist_feature.py``).
 """
 from __future__ import annotations
 

@@ -9,9 +9,19 @@ owner shard (a :func:`~glt_tpu.parallel.dist_sampler.build_routing` plan,
 reusable across exchanges), ``all_to_all`` the id buckets, every shard
 gathers its rows from HBM, ``all_to_all`` the row blocks back, unscatter.
 :func:`exchange_gather_xy` fuses the feature AND label lookup of a
-frontier into ONE such round-trip (labels bitcast into a float32 payload
-column — bit-exact).  Payload rides ICI and overlaps with neighboring
-compute under XLA's scheduler.
+frontier into ONE such round-trip (one plan, one id collective, one
+served read).  Payload rides ICI and overlaps with neighboring compute
+under XLA's scheduler.
+
+**The served read** (:func:`_request_rows`, one for all three
+exchanges).  The request matrix a shard serves is ``S`` requesters'
+buckets, each a prefix of live ids then padding: in the dist cell about
+a tenth of its 3.75 M slots hold a node.  The read of the feature rows
+and the label column visits only the chunks of ``CHUNK_ROWS`` slots that
+hold a request of ours, the chunk rule of
+:mod:`~glt_tpu.ops.neighbor_sample` with a trip count known at run time;
+the response is the whole take's bit for bit, and how much it read is
+counted (``glt.gather.served_rows`` / ``glt.gather.read_rows``).
 
 **Host tiering** (:class:`TieredShardedFeature`): when the feature matrix
 exceeds mesh HBM (papers100M ≈ 200GB), each shard keeps only a hotness-
@@ -27,6 +37,7 @@ the two jitted stages so step time approaches
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import numpy as np
@@ -36,11 +47,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs.scopes import scoped
+from ..ops import neighbor_sample as _ns
 from ..ops.fused_frontier import fused_frontier as _fused_frontier
 from ..ops.unique import unique_first_occurrence
 from .dist_sampler import (HierarchicalRouting, Routing, _topology_choice,
-                           _use_fused, build_hier_routing, build_routing,
-                           hier_requests, hier_response)
+                           build_hier_routing, build_routing, hier_requests,
+                           hier_response)
 
 
 def _dedup_scatter_back(urows: jnp.ndarray, inv: jnp.ndarray) -> jnp.ndarray:
@@ -56,25 +68,75 @@ def _dedup_scatter_back_1d(uvals: jnp.ndarray, inv: jnp.ndarray
     return jnp.where(inv >= 0, out, 0)
 
 
-@scoped("glt.gather.feat")
-def _request_rows(rows: jnp.ndarray, local: jnp.ndarray, ok: jnp.ndarray,
-                  fused_frontier: str) -> jnp.ndarray:
-    """Serving-side row fetch of every exchange: rows for the id
-    requests landed on this shard (zeros where ``ok`` is False).
+def _take_served(table, local, ok, scope):
+    """``table``'s rows at ``local``, zeros where ``ok`` is False."""
+    with jax.named_scope(scope):
+        got = jnp.take(table, jnp.where(ok, local, 0), axis=0, mode="clip")
+        return jnp.where(ok.reshape(ok.shape + (1,) * (got.ndim - 1)),
+                         got, 0)
 
-    ``fused_frontier`` != 'off' serves the request block through the
+
+def _request_rows(local: jnp.ndarray, reads, fused_frontier: str = "off"):
+    """Serving-side read of every exchange: the rows the id requests
+    landed on this shard ask for.  ``reads`` is ``((table, ok, scope),
+    ...)``, the feature rows first: each table's rows at ``local`` come
+    back ``[R, ...]`` with zeros where its ``ok`` is False, read under its
+    own scope.  Returns ``(blocks, counts)``; ``counts`` is ``int32[2]``:
+    the request slots that hold a row of ours, and the slots the read
+    visited (the counters ``glt.gather.served_rows`` / ``.read_rows``).
+
+    The request matrix is ``S`` prefixes, one a requester, and most of it
+    is padding (about 10 % live in the dist cell).  Wider than one chunk,
+    the read runs :data:`~glt_tpu.ops.neighbor_sample.CHUNK_ROWS` slots
+    at a time over only the chunks in which some slot holds a request of
+    ours, a trip count known at run time (the chunk rule of
+    :mod:`~glt_tpu.ops.neighbor_sample`): a skipped slot stays the zero it
+    would have been made, a read chunk reads what the whole take read, so
+    the blocks are the whole take's bit for bit.  Every table rides the
+    one loop; its reads and zero fill keep the table's scope, the loop's
+    stores into the blocks carry none.  At most one chunk wide it is the
+    one take it always was.
+
+    ``fused_frontier`` != 'off' serves the feature rows through the
     one-dispatch dedup+gather kernel — the request list repeats hub rows
     across requesting shards, and the fused path reads each distinct row
-    from HBM once, out of VMEM thereafter.  Bit-identical to the naive
-    take (valid ``local`` needs no clip; invalid positions are -1-masked
-    into the kernel's padding path, which zeroes them exactly like the
-    ``where``).
+    from HBM once, out of VMEM thereafter; it visits every slot.
+    Bit-identical to the take (invalid positions are -1-masked into the
+    kernel's padding path, which zeroes them exactly like the ``where``).
     """
-    if fused_frontier != "off":
-        return _fused_frontier(rows, jnp.where(ok, local, -1),
-                               force=fused_frontier).features
-    got = jnp.take(rows, jnp.where(ok, local, 0), axis=0, mode="clip")
-    return jnp.where(ok[:, None], got, 0)
+    width, chunk = local.shape[0], _ns.CHUNK_ROWS
+    with jax.named_scope("glt.gather.feat"):
+        live = functools.reduce(jnp.logical_or, [ok for _, ok, _ in reads])
+        ids = jnp.where(live, local, -1)
+        first = ()
+        if fused_frontier != "off":
+            rows, ok, _ = reads[0]
+            first = (_fused_frontier(rows, jnp.where(ok, local, -1),
+                                     force=fused_frontier).features,)
+            reads = reads[1:]
+            visited = jnp.full((), width, jnp.int32)
+        else:
+            visited = _ns.read_rows(ids)
+        counts = jnp.stack([jnp.sum(live.astype(jnp.int32)), visited])
+    if width <= chunk or not reads:
+        return first + tuple(_take_served(t, local, ok, scope)
+                             for t, ok, scope in reads), counts
+
+    def read(off):
+        at = lax.dynamic_slice_in_dim(local, off, chunk)
+        return tuple(
+            _take_served(t, at, lax.dynamic_slice_in_dim(ok, off, chunk),
+                         scope)
+            for t, ok, scope in reads)
+
+    with jax.named_scope("glt.gather.feat"):
+        order, n = _ns._live_chunks(ids)
+    fill = []
+    for t, _, scope in reads:
+        with jax.named_scope(scope):
+            fill.append(jnp.zeros((width,) + t.shape[1:], t.dtype))
+    return first + _ns._read_live_chunks(order, n, width, 1, read,
+                                         tuple(fill)), counts
 
 
 @scoped("glt.route.exchange")
@@ -170,14 +232,34 @@ def exchange_gather(
 
     Returns: ``[B, d]`` rows in input order.
     """
+    return _exchange(ids, rows, None, nodes_per_shard, num_shards,
+                     axis_name, dedup=dedup, routing=routing, route=route,
+                     fused_frontier=fused_frontier, mesh_shape=mesh_shape,
+                     hier_load_factor=hier_load_factor)[0]
+
+
+def _exchange(ids, rows, labels_col, nodes_per_shard, num_shards, axis_name,
+              hot_per_shard=None, staged_rows=None, staged_slots=None,
+              staged_resp=None, dedup=False, routing=None, route="auto",
+              fused_frontier="off", mesh_shape=None, hier_load_factor=None):
+    """The one round trip behind :func:`exchange_gather`,
+    :func:`exchange_gather_hot` and :func:`exchange_gather_xy` (their
+    arguments; ``labels_col`` None: rows only).  Returns ``(x, y, counts)``:
+    ``y`` None without labels, ``counts`` the serving shard's
+    :func:`_request_rows` counts (of the unique ids' exchange under
+    ``dedup``)."""
     if dedup:
         uniq, inv, _ = unique_first_occurrence(ids)
-        urows = exchange_gather(uniq, rows, nodes_per_shard, num_shards,
-                                axis_name, route=route,
-                                fused_frontier=fused_frontier,
-                                mesh_shape=mesh_shape,
-                                hier_load_factor=hier_load_factor)
-        return _dedup_scatter_back(urows, inv)
+        ux, uy, counts = _exchange(
+            uniq, rows, labels_col, nodes_per_shard, num_shards, axis_name,
+            hot_per_shard=hot_per_shard, staged_rows=staged_rows,
+            staged_slots=staged_slots, staged_resp=staged_resp, route=route,
+            fused_frontier=fused_frontier, mesh_shape=mesh_shape,
+            hier_load_factor=hier_load_factor)
+        return (_dedup_scatter_back(ux, inv),
+                None if uy is None else _dedup_scatter_back_1d(uy, inv),
+                counts)
+
     b = ids.shape[0]
     routing, flat_plan, requests = _resolve_plan(
         ids, nodes_per_shard, num_shards, axis_name, routing, route,
@@ -185,13 +267,38 @@ def exchange_gather(
 
     my_rank = lax.axis_index(axis_name)
     local = requests - my_rank * nodes_per_shard
-    ok = (local >= 0) & (local < nodes_per_shard) & (requests >= 0)
-    got = _request_rows(rows, local, ok, fused_frontier)
+    h = nodes_per_shard if hot_per_shard is None else int(hot_per_shard)
+    okx = (local >= 0) & (local < h) & (requests >= 0)
+    reads = [(rows, okx, "glt.gather.feat")]
+    if labels_col is not None:
+        oky = (local >= 0) & (local < nodes_per_shard) & (requests >= 0)
+        with jax.named_scope("glt.gather.label"):
+            reads.append((labels_col.astype(jnp.int32), oky,
+                          "glt.gather.label"))
+    (gotx, *goty), counts = _request_rows(local, reads, fused_frontier)
+    if staged_rows is not None:
+        # Compact scatter: cold slots are disjoint from hot slots; -1
+        # pad slots are dropped as out-of-bounds (no copy, no trash row).
+        idx = jnp.where(staged_slots >= 0, staged_slots, gotx.shape[0])
+        gotx = gotx.at[idx].set(staged_rows.astype(gotx.dtype),
+                                mode="drop")
+    elif staged_resp is not None:
+        # Hot slots from HBM, cold slots from the staged host rows
+        # (disjoint by construction; padding slots are zero either way).
+        gotx = jnp.where(okx[:, None], gotx, staged_resp.astype(gotx.dtype))
 
-    resp = _return_payload(routing, got, num_shards, b, axis_name)
+    # The labels ride a payload collective of their own, as int32: no
+    # bitcast, and no copy of the rows to pack them into.
+    respx = _return_payload(routing, gotx, num_shards, b, axis_name)
+    respy = None if not goty else _return_payload(
+        routing, goty[0][:, None], num_shards, b, axis_name)[:, 0]
+
     with jax.named_scope("glt.route.payload"):
-        out = resp[jnp.clip(flat_plan.slot, 0, num_shards * b - 1)]
-        return jnp.where(flat_plan.valid[:, None], out, 0)
+        slot = jnp.clip(flat_plan.slot, 0, num_shards * b - 1)
+        x = jnp.where(flat_plan.valid[:, None], respx[slot], 0)
+        y = None if respy is None else jnp.where(flat_plan.valid,
+                                                 respy[slot], 0)
+    return x, y, counts
 
 
 class TieredShardedFeature(NamedTuple):
@@ -319,39 +426,12 @@ def exchange_gather_hot(
     same topology (``route``/``mesh_shape``) — or slot indices won't
     line up with the (possibly host-deduped) request layout.
     """
-    if dedup:
-        uniq, inv, _ = unique_first_occurrence(ids)
-        urows = exchange_gather_hot(
-            uniq, hot_rows, nodes_per_shard, hot_per_shard, num_shards,
-            axis_name, staged_resp=staged_resp, staged_rows=staged_rows,
-            staged_slots=staged_slots, route=route,
-            mesh_shape=mesh_shape, hier_load_factor=hier_load_factor)
-        return _dedup_scatter_back(urows, inv)
-    b = ids.shape[0]
-    routing, flat_plan, requests = _resolve_plan(
-        ids, nodes_per_shard, num_shards, axis_name, routing, route,
-        mesh_shape, hier_load_factor)
-
-    my_rank = lax.axis_index(axis_name)
-    local = requests - my_rank * nodes_per_shard
-    ok = (local >= 0) & (local < hot_per_shard) & (requests >= 0)
-    got = jnp.take(hot_rows, jnp.where(ok, local, 0), axis=0, mode="clip")
-    if staged_rows is not None:
-        # Compact scatter: cold slots are disjoint from hot slots; -1
-        # pad slots are dropped as out-of-bounds (no copy, no trash row).
-        got = jnp.where(ok[:, None], got, 0)
-        idx = jnp.where(staged_slots >= 0, staged_slots, got.shape[0])
-        got = got.at[idx].set(staged_rows.astype(got.dtype), mode="drop")
-    elif staged_resp is None:
-        got = jnp.where(ok[:, None], got, 0)
-    else:
-        # Hot slots from HBM, cold slots from the staged host rows
-        # (disjoint by construction; padding slots are zero either way).
-        got = jnp.where(ok[:, None], got, staged_resp.astype(got.dtype))
-
-    resp = _return_payload(routing, got, num_shards, b, axis_name)
-    out = resp[jnp.clip(flat_plan.slot, 0, num_shards * b - 1)]
-    return jnp.where(flat_plan.valid[:, None], out, 0)
+    return _exchange(ids, hot_rows, None, nodes_per_shard, num_shards,
+                     axis_name, hot_per_shard=hot_per_shard,
+                     staged_rows=staged_rows, staged_slots=staged_slots,
+                     staged_resp=staged_resp, dedup=dedup, routing=routing,
+                     route=route, mesh_shape=mesh_shape,
+                     hier_load_factor=hier_load_factor)[0]
 
 
 def exchange_gather_xy(
@@ -367,7 +447,6 @@ def exchange_gather_xy(
     dedup: bool = False,
     routing=None,
     route: str = "auto",
-    fused: Optional[bool] = None,
     fused_frontier: str = "off",
     mesh_shape: Optional[tuple] = None,
     hier_load_factor: Optional[float] = None,
@@ -378,12 +457,9 @@ def exchange_gather_xy(
     (or, tiered, three) independent exchanges over the SAME ids — each
     rebuilding the identical routing plan and launching its own id +
     payload collectives.  Here one :func:`build_routing` plan, one id
-    all-to-all, and one fused payload all-to-all carry both: the serving
-    shard's int32 label column is **bitcast** to a float32 payload column
-    and concatenated onto the feature rows (pure data movement end to
-    end, so the round trip is bit-exact for ANY label value), then split
-    and bitcast back on the requester.  Halves the collective launches of
-    the gather stage and removes two redundant routing prologues.
+    all-to-all, and the served read's one loop carry both; the response
+    leaves as two payload collectives on either topology, the rows' and
+    the int32 labels'.  Removes two redundant routing prologues.
 
     Args:
       ids: ``[B]`` global node ids (-1 padded -> zero rows/labels).
@@ -395,69 +471,21 @@ def exchange_gather_xy(
         :func:`exchange_gather_hot`.
       dedup: unique ids ride the exchange once; scatter-back is
         bit-identical (see :func:`exchange_gather`).
-      fused: collective-fusion seam; the split fallback still shares the
-        routing plan and id collective, paying one extra payload launch.
-        Value-fusion also requires a float32 feature block (the bitcast
-        target); other dtypes silently take the shared-routing split.
       fused_frontier: serving-side kernel seam for the feature-row fetch
         (see :func:`_request_rows`); bit-identical either way.
       mesh_shape / hier_load_factor: 2-D mesh hierarchical-topology
-        knobs (see :func:`exchange_gather`).  The fused x+y payload
-        rides the hier legs as one block, so the feature+label lookup
-        stays a single round trip on both topologies.
+        knobs (see :func:`exchange_gather`).
 
     Returns:
       ``(x [B, d], y [B] int32)`` in input order (zeros at invalid
       slots, exactly like the separate exchanges).
     """
-    if dedup:
-        uniq, inv, _ = unique_first_occurrence(ids)
-        ux, uy = exchange_gather_xy(
-            uniq, rows, labels_col, nodes_per_shard, num_shards,
-            axis_name, hot_per_shard=hot_per_shard,
-            staged_rows=staged_rows, staged_slots=staged_slots,
-            route=route, fused=fused, fused_frontier=fused_frontier,
-            mesh_shape=mesh_shape, hier_load_factor=hier_load_factor)
-        return _dedup_scatter_back(ux, inv), _dedup_scatter_back_1d(uy, inv)
-
-    b = ids.shape[0]
-    d = rows.shape[-1]
-    routing, flat_plan, requests = _resolve_plan(
-        ids, nodes_per_shard, num_shards, axis_name, routing, route,
-        mesh_shape, hier_load_factor)
-
-    my_rank = lax.axis_index(axis_name)
-    local = requests - my_rank * nodes_per_shard
-    h = nodes_per_shard if hot_per_shard is None else int(hot_per_shard)
-    okx = (local >= 0) & (local < h) & (requests >= 0)
-    oky = (local >= 0) & (local < nodes_per_shard) & (requests >= 0)
-    gotx = _request_rows(rows, local, okx, fused_frontier)
-    if staged_rows is not None:
-        idx = jnp.where(staged_slots >= 0, staged_slots, gotx.shape[0])
-        gotx = gotx.at[idx].set(staged_rows.astype(gotx.dtype),
-                                mode="drop")
-    with jax.named_scope("glt.gather.label"):
-        goty = jnp.take(labels_col.astype(jnp.int32),
-                        jnp.where(oky, local, 0), mode="clip")
-        goty = jnp.where(oky, goty, 0)
-
-    if _use_fused(fused) and rows.dtype == jnp.float32:
-        with jax.named_scope("glt.route.payload"):
-            ybits = lax.bitcast_convert_type(goty, jnp.float32)[:, None]
-            packed = jnp.concatenate([gotx, ybits], axis=-1)
-        resp = _return_payload(routing, packed, num_shards, b, axis_name)
-        with jax.named_scope("glt.route.payload"):
-            respx = resp[:, :d]
-            respy = lax.bitcast_convert_type(resp[:, d], jnp.int32)
-    else:
-        respx = _return_payload(routing, gotx, num_shards, b, axis_name)
-        respy = _return_payload(routing, goty[:, None], num_shards, b,
-                                axis_name)[:, 0]
-
-    with jax.named_scope("glt.route.payload"):
-        slot = jnp.clip(flat_plan.slot, 0, num_shards * b - 1)
-        x = jnp.where(flat_plan.valid[:, None], respx[slot], 0)
-        y = jnp.where(flat_plan.valid, respy[slot], 0)
+    x, y, _ = _exchange(
+        ids, rows, labels_col, nodes_per_shard, num_shards, axis_name,
+        hot_per_shard=hot_per_shard, staged_rows=staged_rows,
+        staged_slots=staged_slots, dedup=dedup, routing=routing, route=route,
+        fused_frontier=fused_frontier, mesh_shape=mesh_shape,
+        hier_load_factor=hier_load_factor)
     return x, y
 
 

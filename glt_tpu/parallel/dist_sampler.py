@@ -1016,24 +1016,35 @@ def exchange_one_hop_ring(
 def dist_live_counters(batch_size: int, num_neighbors: Sequence[int],
                        num_shards: int, frontier_cap: Optional[int] = None,
                        exact: bool = True):
-    """Where one shard's ``live_counts`` of
-    :func:`dist_sample_multi_hop` are counted.  ``read_rows{hop}`` is what
-    the shard's own reads issued, whatever the exchange (its fifth
-    result).  The frontier and edge slots are what the shard's reads
-    PROCESS, not the width it asks with: under the exact flat exchange
-    (``exact``: no ``exchange_load_factor``, no hierarchical plan) every
-    shard serves ``S`` requesters' whole frontiers, ``S x width`` rows a
-    hop.  Any
+    """Where one shard's counts of a dist step are counted: the
+    ``live_counts`` of :func:`dist_sample_multi_hop`, then the two of its
+    served feature read.  ``read_rows{hop}`` is what the shard's own reads
+    issued, whatever the exchange (its fifth result).  The frontier and
+    edge slots are what the shard's reads PROCESS, not the width it asks
+    with: under the exact flat exchange (``exact``: no
+    ``exchange_load_factor``, no hierarchical plan) every shard serves
+    ``S`` requesters' whole frontiers, ``S x width`` rows a hop.  Any
     other exchange serves a matrix of its own shape that nothing here
     re-derives: its slot counters stay where they are, so a share over
     them reads nothing until the change that runs that exchange counts
-    them from its own shapes."""
+    them from its own shapes.  ``glt.gather.served_rows`` (request slots
+    of the feature exchange that held a node of this shard) and
+    ``glt.gather.read_rows`` (the slots its read visited;
+    :func:`~glt_tpu.parallel.dist_feature._request_rows`) are counted
+    from the matrix served, whatever its shape."""
     fanouts = list(num_neighbors)
     rows = [num_shards * w if exact else None
             for w in hop_widths(batch_size, fanouts, frontier_cap)]
-    return live_counters(
+    live = live_counters(
         rows, [r * f if exact else None for r, f in zip(rows, fanouts)],
         max_sampled_nodes(batch_size, fanouts, frontier_cap))
+    return live._replace(counters=live.counters + (
+        _metrics.counter("glt.gather.served_rows", "request slots of the "
+                         "feature exchange that held a node of the serving "
+                         "shard, over dist steps' shards"),
+        _metrics.counter("glt.gather.read_rows", "request slots the "
+                         "serving shard's feature read visited, over dist "
+                         "steps' shards")))
 
 
 def dist_sample_multi_hop(

@@ -29,9 +29,8 @@ from ..typing import PADDING_ID
 from .dist_feature import (
     TieredShardedFeature,
     HostColdStore,
+    _exchange,
     exchange_gather,
-    exchange_gather_hot,
-    exchange_gather_xy,
     route_cold_requests,
 )
 from ..obs import metrics as _metrics
@@ -144,17 +143,20 @@ def _any_seed(seeds):
     return jnp.sum((seeds >= 0).astype(jnp.int32)) > 0
 
 
-def _exchange_xy(axis_name, mesh_shape, label_space, route, fused,
+def _exchange_xy(axis_name, mesh_shape, label_space, route,
                  hier_load_factor, dedup: bool = False,
                  fused_frontier: str = "off"):
     """The exchange gathers of the distributed steps, closed over the
-    routing options: ``gather_x(node, rows, space, staged) -> x`` and
-    ``gather_xy(node, rows, labels, space, staged) -> (x, y)``.
+    routing options: ``gather_x(node, rows, space, staged) -> (x,
+    counts)`` and ``gather_xy(node, rows, labels, space, staged) -> (x, y,
+    counts)``, ``counts`` the serving shard's ``[served, read]`` request
+    slots of the feature rows' exchange
+    (:func:`~glt_tpu.parallel.dist_feature._request_rows`).
 
     ``space = (nodes_per_shard, hot_per_shard, num_shards)`` of the
     table; ``staged = (rows, slots)`` is the compact host staging of its
     cold rows, ``None`` for a table whole in HBM.  Features and labels
-    ride ONE routing plan and ONE payload collective
+    ride ONE routing plan, id collective and served read
     (:func:`~glt_tpu.parallel.dist_feature.exchange_gather_xy`) when the
     table's id space is the labels' ``label_space = (per_shard,
     num_shards)`` (always true for shard_graph/shard_feature over the
@@ -169,27 +171,30 @@ def _exchange_xy(axis_name, mesh_shape, label_space, route, fused,
     def gather_x(node, rows, space, staged):
         c, h, s = space
         if staged is None:
-            return exchange_gather(node, rows, c, s, axis_name, dedup=dedup,
-                                   fused_frontier=fused_frontier, **kw)
-        return exchange_gather_hot(node, rows, c, h, s, axis_name,
-                                   staged_rows=staged[0],
-                                   staged_slots=staged[1], dedup=dedup,
-                                   **kw)
+            x, _, counts = _exchange(node, rows, None, c, s, axis_name,
+                                     dedup=dedup,
+                                     fused_frontier=fused_frontier, **kw)
+        else:
+            x, _, counts = _exchange(node, rows, None, c, s, axis_name,
+                                     hot_per_shard=h, staged_rows=staged[0],
+                                     staged_slots=staged[1], dedup=dedup,
+                                     **kw)
+        return x, counts
 
     def gather_xy(node, rows, labels, space, staged):
         c, h, s = space
         if (c, s) == label_space:
             srows, sslots = staged or (None, None)
-            x, y = exchange_gather_xy(
+            x, y, counts = _exchange(
                 node, rows, labels, c, s, axis_name, hot_per_shard=h,
                 staged_rows=srows, staged_slots=sslots, dedup=dedup,
-                fused=fused, fused_frontier=fused_frontier, **kw)
+                fused_frontier=fused_frontier, **kw)
         else:
-            x = gather_x(node, rows, space, staged)
+            x, counts = gather_x(node, rows, space, staged)
             y = exchange_gather(node, labels[:, None].astype(jnp.int32),
                                 *label_space, axis_name, dedup=dedup,
                                 **kw)[:, 0]
-        return x, jnp.where(node >= 0, y, PADDING_ID)
+        return x, jnp.where(node >= 0, y, PADDING_ID), counts
 
     return gather_x, gather_xy
 
@@ -224,7 +229,7 @@ def _dist_local_grads(model, g, f, mesh, num_neighbors, batch_size,
     mesh_shape = mesh_axis_sizes(mesh, axis_name)
     _, gather_xy = _exchange_xy(
         axis_name, mesh_shape, (g.nodes_per_shard, g.num_shards), route,
-        fused, hier_load_factor, dedup=dedup_gather,
+        hier_load_factor, dedup=dedup_gather,
         fused_frontier=fused_frontier)
     space = _feature_space(f)[1]
     byte_model = dist_step_byte_model(
@@ -249,10 +254,10 @@ def _dist_local_grads(model, g, f, mesh, num_neighbors, batch_size,
             exchange_load_factor=exchange_load_factor,
             route=route, fused=fused, mesh_shape=mesh_shape,
             hier_load_factor=hier_load_factor)
-        x, y = gather_xy(out.node, rows, labels_blk, space, None)
+        x, y, served = gather_xy(out.node, rows, labels_blk, space, None)
         edge_index, edge_mask, aux = graph_inputs(out)
         return grads_of(params, x, edge_index, edge_mask, y, aux, key) + (
-            out.live_counts,)
+            jnp.concatenate([out.live_counts, served]),)
 
     return axis_name, byte_model, live, local_grads
 
@@ -288,8 +293,9 @@ def make_dist_train_step(
     NeighborSampler) — seed rows stay in the compact interior prefix, so
     the objective is unchanged.  ``exchange_load_factor``, ``route``,
     ``fused`` and ``hier_load_factor`` are
-    :func:`~glt_tpu.parallel.dist_sampler.dist_sample_multi_hop`'s and
-    the gather's (:func:`_exchange_xy`); ``dedup_gather`` routes unique
+    :func:`~glt_tpu.parallel.dist_sampler.dist_sample_multi_hop`'s
+    (``route`` and ``hier_load_factor`` the gather's too,
+    :func:`_exchange_xy`); ``dedup_gather`` routes unique
     node ids through the feature/label exchange — pair it with
     ``last_hop_dedup=False``, whose leaf blocks repeat hub nodes;
     ``fused_frontier`` != 'off' serves each shard's landed feature
@@ -477,7 +483,6 @@ def make_tiered_train_step(
     axis_name: Optional[str] = None,
     dedup_gather: bool = False,
     route: str = "auto",
-    fused: Optional[bool] = None,
     hier_load_factor: Optional[float] = None,
 ):
     """Build the train half of the tiered two-stage pipeline.
@@ -502,7 +507,7 @@ def make_tiered_train_step(
     axis_name = resolve_mesh_axes(mesh, axis_name)
     _, gather_xy = _exchange_xy(
         axis_name, mesh_axis_sizes(mesh, axis_name),
-        (g.nodes_per_shard, g.num_shards), route, fused, hier_load_factor,
+        (g.nodes_per_shard, g.num_shards), route, hier_load_factor,
         dedup=dedup_gather)
     hot, space = _feature_space(f)
     grads_of = loss_and_grads(model, seed_loss(batch_size),
@@ -511,7 +516,7 @@ def make_tiered_train_step(
     def local_grads(arrays, batch, params, key):
         hot_rows, labels_blk = arrays
         out, staged = batch
-        x, y = gather_xy(out.node, hot_rows, labels_blk, space, staged)
+        x, y, _ = gather_xy(out.node, hot_rows, labels_blk, space, staged)
         edge_index, edge_mask, aux = graph_inputs(out)
         return grads_of(params, x, edge_index, edge_mask, y, aux, key)
 
@@ -871,7 +876,7 @@ def init_dist_state(model, tx, g: ShardedGraph, f,
                       step=jnp.zeros((), jnp.int32))
 
 
-def _typed_gather(sampler, feats, labels, axis_name, mesh, route, fused,
+def _typed_gather(sampler, feats, labels, axis_name, mesh, route,
                   hier_load_factor):
     """``(rows, gather)`` of the typed distributed steps: every type's
     device rows, and ``gather(out, rows_l, labels_l, staged) -> (x, y)``
@@ -882,15 +887,15 @@ def _typed_gather(sampler, feats, labels, axis_name, mesh, route, fused,
     num_shards = next(iter(sampler.sharded.values())).num_shards
     gather_x, gather_xy = _exchange_xy(
         axis_name, mesh_axis_sizes(mesh, axis_name),
-        (int(labels.shape[1]), num_shards), route, fused, hier_load_factor)
+        (int(labels.shape[1]), num_shards), route, hier_load_factor)
     spaces = {t: _feature_space(f) for t, f in feats.items()}
 
     def gather(out, rows_l, labels_l, staged):
         x = {t: gather_x(out.node[t], rows_l[t], spaces[t][1],
-                         staged.get(t))
+                         staged.get(t))[0]
              for t in rows_l if t != tgt}
-        x[tgt], y = gather_xy(out.node[tgt], rows_l[tgt], labels_l,
-                              spaces[tgt][1], staged.get(tgt))
+        x[tgt], y, _ = gather_xy(out.node[tgt], rows_l[tgt], labels_l,
+                                 spaces[tgt][1], staged.get(tgt))
         return x, y
 
     return {t: sp[0] for t, sp in spaces.items()}, gather
@@ -906,7 +911,6 @@ def make_hetero_dist_train_step(
     batch_size: int,
     axis_name: Optional[str] = None,
     route: str = "auto",
-    fused: Optional[bool] = None,
     hier_load_factor: Optional[float] = None,
 ):
     """Hetero analog of :func:`make_dist_train_step` (cf. the reference's
@@ -923,7 +927,7 @@ def make_hetero_dist_train_step(
     arrays = {et: (g.indptr, g.indices, g.edge_ids)
               for et, g in sampler.sharded.items()}
     rows, gather = _typed_gather(sampler, feats, labels, axis_name, mesh,
-                                 route, fused, hier_load_factor)
+                                 route, hier_load_factor)
     grads_of = loss_and_grads(model, seed_loss(batch_size),
                               mean_over=axis_name)
 
@@ -953,7 +957,6 @@ def make_hetero_tiered_train_step(
     batch_size: int,
     axis_name: Optional[str] = None,
     route: str = "auto",
-    fused: Optional[bool] = None,
     hier_load_factor: Optional[float] = None,
 ):
     """Hetero analog of :func:`make_tiered_train_step` (VERDICT r4 #4):
@@ -972,7 +975,7 @@ def make_hetero_tiered_train_step(
     tiered = sorted(t for t, f in feats.items()
                     if isinstance(f, TieredShardedFeature))
     hot_rows, gather = _typed_gather(sampler, feats, labels, axis_name,
-                                     mesh, route, fused, hier_load_factor)
+                                     mesh, route, hier_load_factor)
     grads_of = loss_and_grads(model, seed_loss(batch_size),
                               mean_over=axis_name)
 

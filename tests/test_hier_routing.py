@@ -288,8 +288,8 @@ def test_exchange_gather_flat_hier_bit_identity(dedup):
     np.testing.assert_array_equal(hier, ref.astype(np.float32))
 
 
-@pytest.mark.parametrize("fused", [True, False])
-def test_exchange_gather_xy_flat_hier_bit_identity(fused):
+@pytest.mark.parametrize("dedup", [True, False])
+def test_exchange_gather_xy_flat_hier_bit_identity(dedup):
     _, feat, labels = _cluster()
     mesh = _mesh2d(2)
     axis_name = resolve_mesh_axes(mesh)
@@ -302,7 +302,7 @@ def test_exchange_gather_xy_flat_hier_bit_identity(fused):
         def body(i, rows, lcol):
             x, y = exchange_gather_xy(
                 i, rows, lcol, f.nodes_per_shard, f.num_shards,
-                axis_name, fused=fused, route=route, mesh_shape=ms)
+                axis_name, dedup=dedup, route=route, mesh_shape=ms)
             return x, y
 
         return _shard_call(mesh, body, ids, f.rows, lab)
@@ -311,7 +311,7 @@ def test_exchange_gather_xy_flat_hier_bit_identity(fused):
     xh, yh = run("hier")
     np.testing.assert_array_equal(xf, xh)
     np.testing.assert_array_equal(yf, yh)
-    # Label round trip is exact int32 (bitcast ride on the fused payload).
+    # Label round trip is exact int32 (a payload collective of its own).
     idn = np.asarray(ids)
     ref_y = np.where(idn >= 0, labels[np.maximum(idn, 0)], 0)
     np.testing.assert_array_equal(yh, ref_y.astype(np.int32))

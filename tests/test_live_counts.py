@@ -556,6 +556,11 @@ def test_dist_step_counts_every_shards_batch(registry, scanned, chunk):
                 live[q].append((np.arange(w)[None, :]
                                 < asked[:, q, None]).reshape(-1))
         return live
+    # the feature exchange's matrix: each requester's bucket for an owner
+    # holds that owner's ids of its node list, a prefix of ``cap`` slots
+    cap = hop_bounds(b, fanout).node_bounds[-1]
+    gather_chunk = chunk or neighbor_sample.CHUNK_ROWS
+    gather = {"glt.gather.served_rows": 0, "glt.gather.read_rows": 0}
     batches, n_steps, g = [], 2, 2
     for it in range(n_steps):
         key = jax.random.PRNGKey(50 + it)
@@ -574,6 +579,14 @@ def test_dist_step_counts_every_shards_batch(registry, scanned, chunk):
             em, nm, nsn, node = probe(d.graph.indptr, d.graph.indices,
                                       d.graph.edge_ids, jnp.asarray(sd), k)
             live = served(nsn, node)
+            node = np.asarray(node)
+            asked = np.stack([np.bincount(p_[p_ >= 0] // c, minlength=s)
+                              for p_ in node])         # [requester, owner]
+            gather["glt.gather.served_rows"] += int(asked.sum())
+            for q in range(s):
+                gather["glt.gather.read_rows"] += _rows_read(
+                    (np.arange(cap)[None, :] < asked[:, q, None]).reshape(-1),
+                    gather_chunk)
             batches += [{"edge_mask": np.asarray(em[i]),
                          "node_mask": np.asarray(nm[i]),
                          "num_sampled_nodes": np.asarray(nsn[i]),
@@ -591,6 +604,15 @@ def test_dist_step_counts_every_shards_batch(registry, scanned, chunk):
         assert got["glt.sample.frontier_nodes{hop=3}"] \
             <= got["glt.sample.read_rows{hop=3}"] \
             < got["glt.sample.frontier_slots{hop=3}"] / 2
+    # ... and the served feature read's two, counted on the same carrier:
+    # every valid node of every shard's list is served once, by its owner
+    snap = registry.snapshot()
+    assert {k: snap[k] for k in gather} == gather
+    assert gather["glt.gather.served_rows"] == sum(
+        int(bt["node_mask"].sum()) for bt in batches)
+    assert gather["glt.gather.served_rows"] <= gather["glt.gather.read_rows"]
+    if chunk:
+        assert gather["glt.gather.read_rows"] < len(batches) * s * cap / 2
 
 
 def test_a_bounded_exchange_counts_live_work_and_no_read_slots(registry):

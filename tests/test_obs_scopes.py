@@ -161,6 +161,13 @@ def test_the_sorted_last_hop_stays_under_the_inducers_scope(
     text, _ = compiled_text(program)
     sorts = [re.findall(r'op_name="([^"]*)"', line)
              for line in text.splitlines() if re.search(r"\bsort\(", line)]
+    # The dist step's served feature read (4 x 656 request slots at the
+    # tiny shape: past one chunk) orders its chunks by a sort of its own,
+    # under the gather's scope.
+    read = [names for names in sorts
+            if names and "glt.gather.feat/" in names[0]]
+    assert len(read) == (program == "dist"), read
+    sorts = [names for names in sorts if names not in read]
     assert 4 * 3 < len(sorts) <= 4 * 4
     assert all(names and names[0].endswith("glt.sample.induce/sort")
                for names in sorts), sorts

@@ -256,17 +256,22 @@ class TestSharedRouting:
         a, b = fn(sf.rows, ids)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    @pytest.mark.parametrize("fused", [True, False])
+    @pytest.mark.parametrize("shared_plan", [True, False])
     @pytest.mark.parametrize("dedup", [False, True])
-    def test_exchange_gather_xy_matches_separate(self, mesh, fused, dedup):
+    def test_exchange_gather_xy_matches_separate(self, mesh, shared_plan,
+                                                 dedup):
+        """With a plan built outside or not (a passed plan is ignored
+        under ``dedup``), dedup on or off."""
         sf, lab, ids = self._fixture()
         gspec = P("shard")
 
         def body(rows_blk, lab_blk, ids_blk):
             ids_l, rows_l, lab_l = ids_blk[0], rows_blk[0], lab_blk[0]
+            plan = (build_routing(ids_l, sf.nodes_per_shard, N_DEV)
+                    if shared_plan else None)
             x, y = exchange_gather_xy(ids_l, rows_l, lab_l,
                                       sf.nodes_per_shard, N_DEV, "shard",
-                                      dedup=dedup, fused=fused)
+                                      dedup=dedup, routing=plan)
             xs = exchange_gather(ids_l, rows_l, sf.nodes_per_shard, N_DEV,
                                  "shard")
             ys = exchange_gather(ids_l, lab_l[:, None].astype(jnp.int32),

@@ -217,7 +217,7 @@ class TestFront:
         front = make_front(eng, max_wait_ms=20.0)
         try:
             front.submit([0])
-            time.sleep(0.02)
+            time.sleep(0.04)    # past batch 1's 20 ms window, inside its run
             a = front.submit(list(range(1, 7)))    # 6 seeds
             b = front.submit(list(range(10, 14)))  # 4 seeds: 10 > bucket 8
             assert a.done.wait(5.0) and b.done.wait(5.0)
